@@ -126,9 +126,7 @@ class DiagnosticsCollector:
         # whose deltaBytes stays tiny next to fullRefreshBytes is keeping
         # its HBM caches warm through writes; the inverse means writes are
         # forcing full plane re-uploads (journal overflow / bulk ingest).
-        # Peek the lazy engine slot only — gathering diagnostics must never
-        # be what first opens the device backend.
-        engine = getattr(getattr(self.server, "executor", None), "_engine", None)
+        engine = getattr(getattr(self.server, "executor", None), "engine", None)
         if engine is not None:
             # Locked snapshot, not a live dict read — same rule the
             # /debug/vars handler follows (engine counters mutate under

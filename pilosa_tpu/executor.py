@@ -186,7 +186,8 @@ class Executor:
 
         self.holder = holder
         # Device-engine knobs (parallel.EngineConfig); held here because
-        # the engine itself is constructed lazily on first device use.
+        # the engine is constructed on first use (the server forces that
+        # at open(), library users on their first query).
         self.engine_config = engine_config
         # [tier] residency budgets (tier.TierConfig) + the scheduler's
         # per-index traffic signal for the tier prefetcher; the server
@@ -252,6 +253,11 @@ class Executor:
                 # health registry already holds the resolved config, so the
                 # lazily-built engine needs no extra plumbing.
                 resilience_config=self.cluster.health.config)
+            info = self._engine.device_info()
+            self.logger.info(
+                "device engine: platform=%s device_kind=%s n_devices=%d "
+                "mesh=%s", info["platform"], info["device_kind"],
+                info["n_devices"], info["mesh_shape"])
         return self._engine
 
     def close(self) -> None:
@@ -1446,7 +1452,7 @@ class Executor:
             # host rank cache (cheap), but the src intersections for the
             # UNION of candidates across all local shards run as ONE device
             # program — the per-fragment fallback pays a device round trip
-            # per plane chunk per shard (seconds through a remote runtime).
+            # per plane chunk per shard.
             # Heap semantics stay exact: Fragment.top replays them from the
             # precomputed per-shard counts (fragment.go:899-990). Tanimoto
             # (the ChEMBL workload, docs/examples.md:321-328) and attr

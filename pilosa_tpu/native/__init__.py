@@ -1,8 +1,11 @@
 """ctypes bindings for the native host kernels (bitmap_ops.cpp).
 
-Loads libbitmap_ops.so, building it with `make` on first use if the
-toolchain is available. All entry points have numpy fallbacks — the
-framework works without the native library, just slower on host paths.
+Loads libbitmap_ops.so after running `make`, whose dependency rule
+decides whether the library is current with bitmap_ops.cpp — the library
+is git-ignored, so a copied working tree can carry a stale one and a
+fresh checkout has none. All entry points have numpy fallbacks — the
+framework works without the native library, just slower on host paths;
+/debug/vars `native` says which it is.
 """
 
 from __future__ import annotations
@@ -23,12 +26,16 @@ _tried = False
 
 
 def _build() -> bool:
+    """True when `make` says the library is current (building it if it
+    was not), or when there is no `make` to ask and a library is there."""
     try:
         subprocess.run(
             ["make", "-C", _DIR], check=True, capture_output=True, timeout=120
         )
         return True
-    except (subprocess.SubprocessError, FileNotFoundError, OSError):
+    except FileNotFoundError:
+        return os.path.exists(_LIB_PATH)
+    except (subprocess.SubprocessError, OSError):
         return False
 
 
@@ -38,7 +45,7 @@ def load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_LIB_PATH) and not _build():
+        if not _build():
             return None
         try:
             lib = ctypes.CDLL(_LIB_PATH)

@@ -37,7 +37,8 @@ class EngineConfig:
     # deployments that want the COLLECTIVE plane on the full device set
     # pin the engine to mesh-devices=1 — per-node programs then carry no
     # collectives at all and only the (runner-serialized) collective
-    # plane uses the full mesh. docs/multichip.md.
+    # plane uses the full mesh. On four real chips the hazard did not
+    # show (docs/multichip.md, "One process, every local chip").
     mesh_devices: int = 0
     # Cache budgets (0 = auto). Auto means: the legacy env override
     # (PILOSA_LEAF_CACHE_BYTES / PILOSA_STACK_CACHE_BYTES /

@@ -16,7 +16,7 @@ breakers modeled on the per-peer circuit breaker in ``cluster/health.py``:
                     a half-open probe after an exponential backoff.
 
   plane-wide        consecutive dispatch failures across signatures mean
-                    the DEVICE is sick (dead tunnel, wedged runtime), not
+                    the DEVICE is sick (dead or wedged runtime), not
                     one program: the whole engine demotes to host
                     execution (executor answers popcounts from host-tier
                     compressed bytes / live containers, no device work at
@@ -86,7 +86,15 @@ _OOM_RE = re.compile(
     r"resource_exhausted|out of memory|out_of_memory|\boom\b"
     r"|while trying to allocate|failed to allocate")
 _COMPILE_RE = re.compile(
-    r"compil|invalid_argument|unimplemented|lowering|unsupported|mosaic")
+    r"compil|invalid_argument|unimplemented|lowering|unsupported")
+# A kernel that does not fit the chip's vector memory is refused by the
+# compiler with the SAME status word an HBM allocation failure carries
+# ("RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem ...
+# Scoped allocation with size 32.00M and limit 16.00M"). It is a property
+# of the program, not of memory pressure — no eviction can make it fit —
+# so a VMEM or Mosaic refusal is tested for BEFORE the OOM spellings.
+_KERNEL_REFUSAL_RE = re.compile(
+    r"memory space vmem|scoped allocation|scoped vmem|mosaic")
 
 
 def classify_device_error(e: BaseException) -> str:
@@ -98,16 +106,12 @@ def classify_device_error(e: BaseException) -> str:
     ``XlaRuntimeError``, and the injected-fault failpoints deliberately
     use the same spellings so a fault test classifies exactly like the
     real error would."""
-    if isinstance(e, DeviceDispatchTimeout) or isinstance(e, TimeoutError):
+    # concurrent.futures.TimeoutError is the builtin since Python 3.11.
+    if isinstance(e, (DeviceDispatchTimeout, TimeoutError)):
         return TIMEOUT
-    try:
-        from concurrent.futures import TimeoutError as _FutTimeout
-
-        if isinstance(e, _FutTimeout):
-            return TIMEOUT
-    except ImportError:  # pragma: no cover - stdlib always has it
-        pass
     text = f"{type(e).__name__}: {e}".lower()
+    if _KERNEL_REFUSAL_RE.search(text):
+        return COMPILE
     if _OOM_RE.search(text):
         return OOM
     if _COMPILE_RE.search(text):

@@ -450,6 +450,12 @@ class Server:
         self.collective = CollectiveBackend(self, self.collective_config)
         self.executor.collective = self.collective
         self.executor.logger = self.logger
+        # Build the device engine now, before the listener answers
+        # anything: the log names the device this server runs on from its
+        # first lines, /debug/vars has its `device` group from the first
+        # scrape, and a backend that cannot come up fails open() instead
+        # of the first query.
+        self.executor.engine
         self.translate_store.open()
         self._httpd, self._http_thread, actual_port = serve(
             self.handler, self.host, self.port, ssl_context=self._ssl_context()

@@ -2,27 +2,17 @@
 
 Multi-chip TPU hardware is unavailable in CI; shardings are validated on an
 8-device CPU mesh (the driver separately dry-run-compiles multi-chip via
-__graft_entry__.dryrun_multichip). jax is pre-imported by the environment,
-so platform selection must go through jax.config (env vars are too late) —
-this works as long as no backend has been initialized yet.
+__graft_entry__.dryrun_multichip). JAX_PLATFORMS is set before jax is first
+imported, which is when jax reads it; child processes inherit it.
 """
 
 import os
 
 os.environ["JAX_PLATFORMS"] = "cpu"
-flags = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in flags:
-    os.environ["XLA_FLAGS"] = (
-        flags + " --xla_force_host_platform_device_count=8"
-    ).strip()
 
 import jax
 
-jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    pass  # older jax: XLA_FLAGS above covers it
+jax.config.update("jax_num_cpu_devices", 8)
 
 
 import subprocess

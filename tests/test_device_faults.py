@@ -59,6 +59,21 @@ def call(q):
     return parse(q).calls[0]
 
 
+# Verbatim from libtpu 0.0.34 compiling a Pallas kernel for a v5e
+# topology (jax.experimental.topologies, no chip needed).
+VMEM_REFUSAL = (
+    "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem while "
+    "allocating on stack for %f.1 = u32[64,32768]{1,0:T(8,128)} "
+    "custom-call(%x.1, %y.1), custom_call_target=\"tpu_custom_call\", "
+    "operand_layout_constraints={u32[64,32768]{1,0}, u32[64,32768]{1,0}}, "
+    "frontend_attributes={kernel_metadata={}}, "
+    "metadata={op_name=\"jit(f)/pallas_call\" stack_frame_id=2}. Scoped "
+    "allocation with size 24.00M and limit 16.00M exceeded scoped vmem "
+    "limit by 8.00M. It should not be possible to run out of scoped vmem "
+    "-  see go/compile-time-vmem-oom#kernel-vmem-stack-oom for more "
+    "information.")
+
+
 # ------------------------------------------------------ classification
 
 
@@ -74,6 +89,15 @@ class TestClassify:
                     "Compilation failure: unsupported op",
                     "Mosaic lowering failed"):
             assert classify_device_error(RuntimeError(msg)) == COMPILE
+
+    def test_vmem_refusal_is_compile_not_oom(self):
+        # The v5e compiler's own words for a kernel that does not fit
+        # vector memory. The status word is the OOM one; the failure is a
+        # property of the program, and must never reach backpressure.
+        assert classify_device_error(RuntimeError(VMEM_REFUSAL)) == COMPILE
+        assert classify_device_error(RuntimeError(
+            "RESOURCE_EXHAUSTED: Mosaic failed to compile TPU kernel: "
+            "out of memory")) == COMPILE
 
     def test_timeout_by_type(self):
         assert classify_device_error(DeviceDispatchTimeout("x")) == TIMEOUT
@@ -428,6 +452,31 @@ class TestEngineFaults:
             failpoints.reset()
             eng.close()
 
+    def test_vmem_refusal_leaves_budgets_untouched(self, holder):
+        # A kernel the chip's compiler refuses fails the SAME way on the
+        # retry and on both halves of a split batch: treating it as an
+        # HBM OOM halved the cache budgets for the life of the process
+        # and evicted resident planes for nothing.
+        eng = self._engine(holder)
+        try:
+            eng.count("i", call("Row(f=0)"), SHARDS)  # a resident plane
+            budgets = dict(eng.budgets)
+            failpoints.configure("device-dispatch", "error",
+                                 message=VMEM_REFUSAL)
+            calls = [call(f"Row(f={r})") for r in range(1, 5)]
+            with pytest.raises(DeviceDispatchError) as ei:
+                eng.count_batch("i", calls, SHARDS)
+            assert ei.value.kind == COMPILE
+            assert eng.budgets == budgets
+            assert eng.counters["oom_backpressure"] == 0
+            assert eng.counters["oom_batch_splits"] == 0
+            assert eng.counters["leaf_evictions"] == 0
+            assert eng.device_health.snapshot()["failures_compile"] == 1
+            assert eng.device_health.snapshot()["failures_oom"] == 0
+        finally:
+            failpoints.reset()
+            eng.close()
+
     def test_transfer_stage_failure_engages_breaker(self, holder,
                                                     monkeypatch):
         # A device that dies at the TRANSFER stage (device_put raising,
@@ -438,11 +487,11 @@ class TestEngineFaults:
 
         eng = self._engine(holder)
 
-        def dead_tunnel(*a, **kw):
-            raise RuntimeError("UNAVAILABLE: tunnel closed")
+        def dead_device(*a, **kw):
+            raise RuntimeError("UNAVAILABLE: device connection closed")
 
         try:
-            monkeypatch.setattr(_jax, "device_put", dead_tunnel)
+            monkeypatch.setattr(_jax, "device_put", dead_device)
             with pytest.raises(DeviceDispatchError) as ei:
                 eng.count("i", call("Row(f=0)"), SHARDS)
             assert ei.value.kind == RUNTIME

@@ -333,3 +333,29 @@ def test_gather_kernel_multi_device_shard_map(holder, ex, monkeypatch):
     kernel_engine = ShardedQueryEngine(holder)
     got = kernel_engine.count_batch("i", calls, shards)
     assert got.tolist() == singles
+
+
+def test_compile_cache_is_placed_from_outside_or_in_the_checkout(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR is the interface: unset, the cache lives
+    at <checkout>/.jax_cache (a fixed path — it is part of the cache key);
+    set, jax has already read it and the engine sets nothing."""
+    import os
+
+    import pilosa_tpu
+    from pilosa_tpu.parallel import engine as engine_mod
+
+    checkout = os.path.dirname(os.path.dirname(
+        os.path.abspath(pilosa_tpu.__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        jax.config.update("jax_compilation_cache_dir", None)
+        engine_mod._place_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            checkout, ".jax_cache")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/outside")
+        jax.config.update("jax_compilation_cache_dir", "/placed/outside")
+        engine_mod._place_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == "/placed/outside"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

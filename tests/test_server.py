@@ -221,6 +221,15 @@ def test_debug_vars_and_diagnostics(server, client):
     with urllib.request.urlopen(f"http://{host(server)}/debug/vars") as resp:
         snap = json.loads(resp.read())
     assert "counters" in snap and snap["counters"].get("setBit", 0) >= 1
+    # The engine is built at open(), so a server says what it runs on
+    # before it has served a single device query.
+    dev = snap["device"]
+    assert dev["platform"] == "cpu" and dev["device_kind"] == "cpu"
+    assert dev["n_devices"] == len(dev["devices"]) == 8
+    assert dev["mesh_shape"] == {"shards": 8}
+    assert set(dev["devices"][0]) == {"id", "bytes_in_use",
+                                      "peak_bytes_in_use"}
+    assert snap["native"] == {"loaded": True}
     with urllib.request.urlopen(f"http://{host(server)}/internal/diagnostics") as resp:
         diag = json.loads(resp.read())
     assert diag["numIndexes"] >= 1 and diag["version"]
