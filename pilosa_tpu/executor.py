@@ -27,7 +27,10 @@ from .errors import (
     QueryError,
     TooManyWritesError,
 )
-from .obs import NOP_SPAN, current as obs_current, span as obs_span
+from .obs import (
+    NOP_SPAN, current as obs_current, current_span as obs_current_span,
+    span as obs_span,
+)
 from .parallel.device_health import DeviceDispatchError
 from .pql import parser as pql_parser
 from .pql.ast import BETWEEN, Call, Condition, GT, GTE, LT, LTE, NEQ
@@ -547,8 +550,6 @@ class Executor:
                    for n in self.cluster.shard_nodes(index, shard))
 
     def _fan_out(self, index, shards, c, opt, local_runner, reduce_fn):
-        from .server.client import ClientError
-
         # A remote (forwarded) execution runs EXACTLY the shards it was
         # handed — no ownership re-check (executor.go:1476-1480). The
         # coordinator chose them; re-deriving placement here would silently
@@ -585,19 +586,34 @@ class Executor:
             return v
 
         trace = obs_current()
+        if trace is None:
+            return self._fan_out_rounds(index, shards, c, opt, local_runner,
+                                        reduce_fn)
+        # One "reduce" span per fan-out (accumulated merge cost), not
+        # one span per reduce_fn call — merges interleave with
+        # gathers and per-merge spans would be noise.
         reduce_acc = [0.0]
-        if trace is not None:
-            # One "reduce" span per fan-out (accumulated merge cost), not
-            # one span per reduce_fn call — merges interleave with
-            # gathers and per-merge spans would be noise.
-            t_fanout = _time.monotonic()
-            inner_reduce = reduce_fn
+        inner_reduce = reduce_fn
 
-            def reduce_fn(a, b, _f=inner_reduce):
-                t0 = _time.monotonic()
-                r = _f(a, b)
-                reduce_acc[0] += _time.monotonic() - t0
-                return r
+        def reduce_fn(a, b, _f=inner_reduce):
+            t0 = _time.monotonic()
+            r = _f(a, b)
+            reduce_acc[0] += _time.monotonic() - t0
+            return r
+
+        # An open span, so that every dispatch of the fan-out is its
+        # child and its self time is the fan-out's own bookkeeping.
+        with obs_span("executor.fanout", shards=len(shards)):
+            result = self._fan_out_rounds(index, shards, c, opt,
+                                          local_runner, reduce_fn)
+            trace.record("reduce", reduce_acc[0] * 1000.0)
+        return result
+
+    def _fan_out_rounds(self, index, shards, c, opt, local_runner, reduce_fn):
+        """The fan-out proper: assign shards to owners, run the local
+        batch, forward the rest, re-route what failed or moved, until
+        nothing is pending. Returns the reduced result."""
+        from .server.client import ClientError
 
         result = None
         failed: set = set()
@@ -723,11 +739,6 @@ class Executor:
                     pending.extend(node_shards)
                     continue
                 result = v if result is None else reduce_fn(result, v)
-        if trace is not None:
-            trace.record(
-                "executor.fanout",
-                (_time.monotonic() - t_fanout) * 1000.0, shards=len(shards))
-            trace.record("reduce", reduce_acc[0] * 1000.0)
         return result
 
     def _remote_dispatch(self, node, index: str, c: Call, node_shards, kw):
@@ -741,8 +752,10 @@ class Executor:
         # Captured HERE (the request thread): hedge legs run on pool
         # threads where the obs contextvar is not set, so the trace
         # object travels by closure and each leg records its own
-        # remote:<peer> span (two legs = two spans, honestly).
+        # remote:<peer> span (two legs = two spans, honestly), under the
+        # span that is open here.
         trace = obs_current()
+        above = obs_current_span()
 
         def call(target):
             """One request with health accounting — success AND transport
@@ -750,7 +763,8 @@ class Executor:
             losing hedge leg (or an abandoned primary) still drives its
             peer's breaker even when its exception is never re-raised."""
             t0 = _time.monotonic()
-            sp = (trace.span(f"remote:{target.id}", shards=len(node_shards))
+            sp = (trace.span(f"remote:{target.id}", parent=above,
+                             shards=len(node_shards))
                   if trace is not None else NOP_SPAN)
             call_kw = kw if trace is None else {**kw, "trace": sp}
             with sp:

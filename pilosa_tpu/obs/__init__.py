@@ -4,9 +4,10 @@ The third observability leg next to /debug/vars (process-wide counters)
 and /debug/profile (whole-process JAX traces): a sampling per-request
 trace recorder threaded through the serving path. A trace starts at
 handler ingress (or is adopted from the X-Pilosa-Trace header a
-coordinator stamped), accumulates named stage spans — parse, sched.wait,
-batch.hold, executor.fanout, gather, device.dispatch, tier.promote,
-remote:<peer>, reduce — and lands in a bounded ring served by
+coordinator stamped), accumulates named stage spans — request, parse,
+sched.wait, batch.hold, executor.fanout, gather, device.dispatch,
+tier.promote, remote:<peer>, reduce — as a tree (each span names its
+parent and carries its self time) and lands in a bounded ring served by
 GET /debug/traces. Remote hops return the peer's own stage summary in a
 size-bounded X-Pilosa-Trace-Summary response header, spliced as child
 spans so a fan-out query yields ONE tree across nodes.
@@ -34,6 +35,7 @@ from .trace import (
     TraceRecorder,
     activate,
     current,
+    current_span,
     deactivate,
     record,
     span,
@@ -77,6 +79,7 @@ __all__ = [
     "TraceRecorder",
     "activate",
     "current",
+    "current_span",
     "deactivate",
     "record",
     "span",

@@ -14,11 +14,23 @@ JSON summary in X-Pilosa-Trace-Summary. The caller splices that summary
 as CHILD spans of its remote:<peer> span. Child offsets stay relative to
 the hop (the peer's own trace start), never converted through wall
 clocks, so peer clock skew cannot corrupt the tree.
+
+The tree: every span has an `id` (unique in its trace) and a `parent`, the
+span that was open on the same context when it began; the open span rides
+a second contextvar. A landed trace reckons each span's `self_ms` when it
+is first read: its length less what its direct children cover, so a
+layer's own cost can be told from what it waited for below it.
+
+The profiler's clock: while a /debug/profile capture runs (`capturing`),
+every span also opens an annotation of its name in the profiler's trace,
+through the factory the server handed in at start-up (obs/ stays jax-free).
+Outside a capture that costs one flag read per span.
 """
 
 from __future__ import annotations
 
 import contextvars
+import itertools
 import json
 import random
 import threading
@@ -31,6 +43,49 @@ from ..stats import Histogram
 _current: contextvars.ContextVar[Optional["Trace"]] = contextvars.ContextVar(
     "pilosa_tpu_trace", default=None
 )
+# The innermost span open on this context: the parent of the next one.
+_open: contextvars.ContextVar[Optional["Span"]] = contextvars.ContextVar(
+    "pilosa_tpu_span", default=None
+)
+
+# jax.profiler.TraceAnnotation, handed in by the server at start-up; None
+# in a process that never starts one (obs/ imports no jax).
+_annotation = None
+# True while a /debug/profile capture runs: the one flag a span reads.
+capturing = False
+# Spans that only park their thread. In the profiler's trace their name
+# ends in `wait`, which is how a reader of idle gaps tells a thread that
+# waited from the one that worked (`engine.device_wait` says so itself).
+PARKED_SPANS = frozenset({"batch.hold", "cdc.tail"})
+
+
+def set_annotation(factory) -> None:
+    """The profiler's annotation class: `factory(name, **stats)` gives a
+    context manager. Called once by the server; None switches it off."""
+    global _annotation
+    _annotation = factory
+
+
+def mark(name: str, **stats) -> None:
+    """One instantaneous annotation in the profiler's trace; nothing
+    where no factory was handed in."""
+    if _annotation is not None:
+        with _annotation(name, **stats):
+            pass
+
+
+def capture_began() -> None:
+    """A profiler capture runs from here on: spans annotate themselves,
+    and an `obs.clock` mark lays the trace on the host's two clocks."""
+    global capturing
+    capturing = _annotation is not None
+    mark("obs.clock", wall_ns=time.time_ns(), mono_ns=time.monotonic_ns())
+
+
+def capture_ended() -> None:
+    global capturing
+    mark("obs.clock", wall_ns=time.time_ns(), mono_ns=time.monotonic_ns())
+    capturing = False
 
 # Spans kept per trace; a runaway query (thousands of shards) truncates
 # its own trace rather than growing without bound.
@@ -44,6 +99,12 @@ SUMMARY_MAX_BYTES = 4096
 def current() -> Optional["Trace"]:
     """The trace active on this thread/context, or None."""
     return _current.get()
+
+
+def current_span() -> Optional["Span"]:
+    """The innermost span open on this context, or None. Code that hops
+    threads captures it beside current() and hands it on as `parent`."""
+    return _open.get()
 
 
 def activate(trace: Optional["Trace"]):
@@ -88,7 +149,7 @@ def span(name: str, **tags):
     t = _current.get()
     if t is None:
         return NOP_SPAN
-    return t.span(name, **tags)
+    return Span(t, name, tags or None)
 
 
 def record(name: str, dur_ms: float, **tags) -> None:
@@ -101,35 +162,76 @@ def record(name: str, dur_ms: float, **tags) -> None:
 
 class Span:
     """One named stage interval. Use as a context manager; completes into
-    its trace on exit (from whichever thread ran it)."""
+    its trace on exit (from whichever thread ran it). `parent` is the id
+    of the span that was open on the same context when this one began, or
+    the one its creator passed in (a span opened on another thread)."""
 
     __slots__ = ("_trace", "name", "start_ms", "dur_ms", "tags", "children",
-                 "_t0")
+                 "_t0", "id", "parent", "self_ms", "amount", "_above",
+                 "_ann", "_closed")
 
     def __init__(self, trace: "Trace", name: str,
-                 tags: Optional[Dict[str, Any]] = None):
+                 tags: Optional[Dict[str, Any]] = None,
+                 parent: Optional["Span"] = None):
         self._trace = trace
         self.name = name
         self.tags = tags or None
         self.children: Optional[List] = None
         self.start_ms = 0.0
         self.dur_ms = 0.0
+        self.self_ms = 0.0
+        self.id = next(trace._ids)
+        self.parent = parent.id if parent is not None else None
+        # True for a span whose dur_ms is an amount and no interval
+        # (`qos.charge`, a bill): it has no self time and covers nothing.
+        self.amount = False
         self._t0 = None
+        self._above = None
+        self._ann = None
+        self._closed = False
 
     def __enter__(self) -> "Span":
+        # The span open on this context until now is open again when this
+        # one ends; it is this one's parent unless the creator named one
+        # (or it belongs to another trace).
+        above = self._above = _open.get()
+        if self.parent is None and above is not None \
+                and above._trace is self._trace:
+            self.parent = above.id
+        _open.set(self)
+        if capturing:
+            name = self.name + ".wait" if self.name in PARKED_SPANS \
+                else self.name
+            self._ann = _annotation(name, trace=self._trace.trace_id,
+                                    span=self.id)
+            self._ann.__enter__()
         self._t0 = self._trace._clock()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._closed:
+            return False
+        self._closed = True
         t = self._trace
         now = t._clock()
         t0 = self._t0 if self._t0 is not None else now
         self.start_ms = (t0 - t._start) * 1000.0
         self.dur_ms = (now - t0) * 1000.0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        if _open.get() is self:
+            # (Not so where a span is closed on another context than it
+            # was opened on: that one's open span is none of its business.)
+            _open.set(self._above)
         if exc_type is not None:
             self.tag(error=exc_type.__name__)
         t._append(self)
         return False
+
+    def close(self) -> None:
+        """End the span now; a later __exit__ does nothing. For the
+        handler's root span, which must end before its trace is landed."""
+        self.__exit__(None, None, None)
 
     def tag(self, **kw) -> None:
         if self.tags is None:
@@ -174,8 +276,11 @@ class Span:
     def to_dict(self) -> dict:
         out: Dict[str, Any] = {
             "name": self.name,
+            "id": self.id,
+            "parent": self.parent,
             "start_ms": round(self.start_ms, 3),
             "dur_ms": round(self.dur_ms, 3),
+            "self_ms": round(self.self_ms, 3),
         }
         if self.tags:
             out["tags"] = dict(self.tags)
@@ -194,7 +299,8 @@ class Trace:
 
     __slots__ = ("trace_id", "index", "pql", "adopted", "start_wall",
                  "_start", "_clock", "spans", "duration_ms", "status",
-                 "finished", "spans_dropped", "tags", "_lock")
+                 "finished", "spans_dropped", "tags", "_lock", "_ids",
+                 "_reckoned")
 
     def __init__(self, trace_id: str, index: str = "", pql: str = "",
                  adopted: bool = False, clock=time.monotonic):
@@ -212,11 +318,16 @@ class Trace:
         self.spans_dropped = 0
         self.tags: Optional[Dict[str, Any]] = None
         self._lock = threading.Lock()
+        # Span ids: next() on a count is atomic under the interpreter lock.
+        self._ids = itertools.count(1)
+        self._reckoned = False
 
     # ----------------------------------------------------------- recording
 
-    def span(self, name: str, **tags) -> Span:
-        return Span(self, name, tags or None)
+    def span(self, name: str, parent: Optional[Span] = None, **tags) -> Span:
+        """A span of this trace. `parent` is for code that opens it on
+        another thread than the request's: there no open span is found."""
+        return Span(self, name, tags or None, parent)
 
     def tag(self, **kw) -> None:
         """Trace-level tags (e.g. the QoS tenant): request attributes
@@ -228,15 +339,30 @@ class Trace:
                 self.tags = {}
             self.tags.update(kw)
 
-    def record(self, name: str, dur_ms: float, **tags) -> None:
-        """Append a pre-measured span ending now."""
-        sp = Span(self, name, tags or None)
+    def record(self, name: str, dur_ms: float, parent: Optional[Span] = None,
+               amount: bool = False, **tags) -> None:
+        """Append a pre-measured span ending now, under the span open on
+        this context (or `parent`, for a caller on another thread).
+        `amount` marks a dur_ms that is a quantity and no interval."""
+        if parent is None:
+            parent = _open.get()
+            if parent is not None and parent._trace is not self:
+                parent = None
+        sp = Span(self, name, tags or None, parent)
+        sp.amount = amount
         now = self._clock()
         sp.dur_ms = float(dur_ms)
         sp.start_ms = max(0.0, (now - self._start) * 1000.0 - sp.dur_ms)
         self._append(sp)
 
     def _append(self, sp: Span) -> None:
+        # A finished span lets go of its trace and of the span above it:
+        # the trace holds its spans, and a reference back would make every
+        # landed trace a cycle that only the garbage collector frees. With
+        # thousands of traces a minute that is most of what the collector
+        # has to look at in a serving process; without it a trace that
+        # leaves the ring is freed at once.
+        sp._trace = sp._above = None
         with self._lock:
             if self.finished or len(self.spans) >= SPANS_MAX:
                 # finished: a straggler (an abandoned hedge leg completing
@@ -254,6 +380,12 @@ class Trace:
 
     def to_dict(self) -> dict:
         with self._lock:
+            if self.finished and not self._reckoned:
+                # On the first read and not in finish(): a landed trace
+                # no longer changes, most are never read, and the pass
+                # costs as much as recording a third of the spans.
+                reckon_self_times(self.spans)
+                self._reckoned = True
             spans = [s.to_dict() for s in self.spans]
         out = {
             "id": self.trace_id,
@@ -311,6 +443,30 @@ class Trace:
             if len(out) <= max_bytes or keep == 0:
                 return out
             keep -= 1
+
+
+def reckon_self_times(spans: List[Span]) -> None:
+    """Set each span's self_ms: its length less the union of its direct
+    children's intervals, each clipped to it. Where every span ran on the
+    request's context the children of one parent do not overlap, and the
+    self times of a trace add up to its root's length."""
+    kids: Dict[int, List[Span]] = {}
+    for s in spans:
+        if s.parent is not None and not s.amount:
+            kids.setdefault(s.parent, []).append(s)
+    for s in spans:
+        if s.amount:
+            s.self_ms = 0.0
+            continue
+        lo, hi = s.start_ms, s.start_ms + s.dur_ms
+        covered, end = 0.0, lo
+        for k in sorted(kids.get(s.id, ()), key=lambda k: k.start_ms):
+            a = max(k.start_ms, end)
+            b = min(k.start_ms + k.dur_ms, hi)
+            if b > a:
+                covered += b - a
+                end = b
+        s.self_ms = max(0.0, s.dur_ms - covered)
 
 
 class TraceRecorder:

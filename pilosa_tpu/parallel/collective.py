@@ -80,7 +80,7 @@ import numpy as np
 from .. import failpoints
 from ..constants import VIEW_BSI_GROUP_PREFIX, WORDS_PER_ROW
 from ..errors import PilosaError
-from ..obs import current as obs_current
+from ..obs import current as obs_current, current_span as obs_current_span
 from . import CollectiveConfig
 from .device_health import BARRIER_TIMEOUT, BROADCAST, CollectivePlaneHealth
 from .distributed import SHARD_AXIS, global_mesh
@@ -457,7 +457,10 @@ class CollectiveBackend:
                     continue
                 self._senders.submit(self._send, node, desc)
         local = dict(desc)
+        # The runner thread has no obs context: the trace and the span
+        # that is open here travel with the descriptor.
         local["_trace"] = obs_current()
+        local["_span"] = obs_current_span()
         fut = self._runner.submit(local)
         try:
             result = fut.result(timeout=desc["timeoutMs"] / 1000.0 + 30.0)
@@ -573,6 +576,7 @@ class CollectiveBackend:
         if trace is not None:
             trace.record("collective.entry",
                          (time.monotonic() - t_entry) * 1000.0,
+                         parent=desc.get("_span"),
                          kind=kind, seq=desc.get("seq"))
         return out
 
@@ -637,6 +641,7 @@ class CollectiveBackend:
             if trace is not None:
                 trace.record("collective.barrier",
                              (time.monotonic() - t0) * 1000.0,
+                             parent=desc.get("_span"),
                              seq=desc.get("seq"))
 
     # ------------------------------------------------- resident plane stacks

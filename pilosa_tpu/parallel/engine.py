@@ -26,7 +26,7 @@ import numpy as np
 
 from .. import failpoints
 from ..constants import VIEW_BSI_GROUP_PREFIX, VIEW_STANDARD, WORDS_PER_ROW
-from ..obs import NOP_SPAN, span as obs_span
+from ..obs import NOP_SPAN, current as obs_current, span as obs_span
 from ..core.row import Row
 from ..errors import QueryError
 from ..ops import bitplane as bp
@@ -64,6 +64,34 @@ def _pop_elems(a: np.ndarray) -> np.ndarray:
     popcounts. np.bitwise_count is used unconditionally, matching the
     storage/wire layers (storage/bitmap.py, server/wire.py)."""
     return np.bitwise_count(a.view(np.uint16))
+
+
+# The kinds of device program the engine builds: the first element of a
+# program-cache signature. Each has a flat `fn_builds_<kind>` counter beside
+# `fn_cache_builds` (registered at 0, so that a reader of counter growth
+# sees a kind's first build too) and is the `kind` tag of `engine.fn_build`.
+FN_KINDS = (
+    "count", "count_batch", "count_batch_setops", "leaf_delta",
+    "stack_delta", "bitmap", "bitmap_batch", "topn_shard", "topn_shard_src",
+    "topn_src", "topn", "bsi",
+)
+
+
+class _FirstCall:
+    """A freshly built program on its builder's way to the first call. A
+    jitted function compiles (or loads from the persistent cache) when it
+    is first called, not when it is wrapped, so that call is the
+    `engine.fn_build` span; the program cache keeps the bare function."""
+
+    __slots__ = ("fn", "kind")
+
+    def __init__(self, fn: Callable, kind: str):
+        self.fn = fn
+        self.kind = kind
+
+    def __call__(self, *args):
+        with obs_span("engine.fn_build", kind=self.kind):
+            return self.fn(*args)
 
 
 def _lower_ir(ir: tuple) -> Callable:
@@ -361,10 +389,19 @@ class ShardedQueryEngine:
             # fn_cache_hits climbing while fn_cache_builds stays flat
             # across commutative/associative respellings of one tree.
             "fn_cache_hits": 0, "fn_cache_builds": 0,
+            # fn_cache_builds by kind of program (they sum to it): which
+            # program a window built, readable from counter growth alone.
+            **{f"fn_builds_{kind}": 0 for kind in FN_KINDS},
             # Device-program launches (memo hits dispatch nothing). The
             # scheduler's coalescing proof is dispatches/query < 1, so the
             # counters must distinguish a launch from an answered query.
             "count_dispatches": 0, "bitmap_dispatches": 0,
+            # Bytes of resident planes handed to the programs launched
+            # (planes x padded shards x 131,072 B each): what a launch
+            # gives the device to read. restack_bytes: bytes of planes
+            # copied into a fresh (U, S, W) stack on the device, the
+            # copies that are most of the device's busy time under writes.
+            "plane_bytes_read": 0, "restack_bytes": 0,
             # Batched-count launches that went through the Pallas gather
             # kernel rather than the XLA formulation (a subset of
             # count_dispatches): the only outside evidence of which of
@@ -433,9 +470,17 @@ class ShardedQueryEngine:
         idx = self.holder.index(index)
         return -1 if idx is None else idx.write_epoch.value
 
-    def _count_dispatch(self) -> None:
+    def _note_launch(self, planes, counter: Optional[str] = None,
+                     kernel: bool = False) -> None:
+        """One device-program launch over `planes`, the resident arrays
+        handed to it; `counter` is the launch counter of its family (the
+        TopN and BSI programs have none)."""
+        nbytes = sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(planes))
         with self._lock:
-            self.counters["count_dispatches"] += 1
+            if counter is not None:
+                self.counters[counter] += 1
+            self.counters["plane_bytes_read"] += nbytes
+            self.counters["gather_kernel_dispatches"] += kernel
 
     def snapshot(self) -> dict:
         """Wholesale counter export for /debug/vars (the `engine_cache`
@@ -604,6 +649,7 @@ class ShardedQueryEngine:
         fn = self._gate(sig, lambda: self._fn_probe(cache, sig))
         if fn is not None:
             return fn
+        kind = str(sig[0])
         try:
             try:
                 failpoints.fire("device-compile")
@@ -613,6 +659,9 @@ class ShardedQueryEngine:
                 # canonical-shape proof counter.
                 with self._lock:
                     self.counters["fn_cache_builds"] += 1
+                    by_kind = f"fn_builds_{kind}"
+                    self.counters[by_kind] = \
+                        self.counters.get(by_kind, 0) + 1
             except Exception as e:
                 with self._lock:
                     self.counters["device_dispatch_errors"] += 1
@@ -626,7 +675,7 @@ class ShardedQueryEngine:
                     cache.pop(next(iter(cache)))
         finally:
             self._release(sig)
-        return fn
+        return fn if obs_current() is None else _FirstCall(fn, kind)
 
     # ------------------------------------------------------ dispatch guard
     #
@@ -1055,8 +1104,11 @@ class ShardedQueryEngine:
                 np.concatenate([v for _, _, v in updates]),
             ])
             sig = ("leaf_delta", arr.shape, len(rows))
+            def leaf_delta_scatter(a, r, c, v):
+                return a.at[r, c].set(v)
+
             fn = self._fn_build(self._count_fns, sig, lambda: jax.jit(
-                lambda a, r, c, v: a.at[r, c].set(v),
+                leaf_delta_scatter,
                 out_shardings=shard_sharding(self.mesh, 2),
             ))
             new_arr = fn(arr, rows, cols, vals)
@@ -1112,8 +1164,11 @@ class ShardedQueryEngine:
                 np.concatenate([v for _, _, v in updates]),
             ])
             sig = ("stack_delta", arr.shape, len(us))
+            def stack_delta_scatter(a, u, r, c, v):
+                return a.at[u, r, c].set(v)
+
             fn = self._fn_build(self._count_fns, sig, lambda: jax.jit(
-                lambda a, u, r, c, v: a.at[u, r, c].set(v),
+                stack_delta_scatter,
                 out_shardings=shard_sharding(self.mesh, 3, axis=1),
             ))
             new_arr = fn(arr, us, rows, cols, vals)
@@ -1147,9 +1202,22 @@ class ShardedQueryEngine:
         dispatch to (stacked tensor, small index vectors). `pad_pow2` pads
         the leading axis with duplicate rows so nearby leaf-set sizes reuse
         one compiled program."""
-        fp = tuple(self._fingerprint(index, leaf, shards) for leaf in leaves)
         n = len(leaves)
         np2 = (1 << (n - 1).bit_length()) if (pad_pow2 and n) else n
+        # Get-or-build as one span; its `kind` says which of the three it
+        # was: `hit` (resident and fresh), `delta` (a stale stack refreshed
+        # by one scatter) or `restack` (members gathered and copied anew).
+        with obs_span("engine.stack", planes=np2) as sp:
+            stacked, kind = self._stack_get_or_build(
+                index, leaves, shards, n, np2)
+            if sp is not NOP_SPAN:
+                sp.tag(kind=kind)
+        return stacked
+
+    def _stack_get_or_build(self, index: str, leaves: List[Leaf],
+                            shards: Tuple[int, ...], n: int, np2: int):
+        """(the (np2, S, W) stack, how it was come by)."""
+        fp = tuple(self._fingerprint(index, leaf, shards) for leaf in leaves)
         key = (index, tuple(leaves), shards, np2)
 
         def probe():
@@ -1163,7 +1231,7 @@ class ShardedQueryEngine:
 
         stacked = self._gate(("stack", key), probe)
         if stacked is not None:
-            return stacked
+            return stacked, "hit"
         try:
             # Stale resident stack: one scattered update beats regathering
             # every member and restacking the whole (U, S, W) tensor.
@@ -1176,28 +1244,32 @@ class ShardedQueryEngine:
                     if sp is not NOP_SPAN:
                         sp.tag(applied=stacked is not None)
                 if stacked is not None:
-                    return stacked
+                    return stacked, "delta"
             # Stale or missing: gather member planes (leaf-cache hits are
             # cheap; on a fresh stack hit above no gather happens at all).
             arrs = [self._gather_leaf(index, leaf, shards) for leaf in leaves]
             arrs = arrs + [arrs[0]] * (np2 - n)
             with self._lock:
                 if self._stack_jit is None:
+                    def restack_planes(xs):
+                        return jnp.stack(xs)
+
                     self._stack_jit = jax.jit(
-                        lambda xs: jnp.stack(xs),
+                        restack_planes,
                         out_shardings=shard_sharding(self.mesh, 3, axis=1),
                     )
                 stack_jit = self._stack_jit
             stacked = self._oom_guard(None, lambda: stack_jit(tuple(arrs)))
             with self._lock:
                 self.counters["stack_misses"] += 1
+                self.counters["restack_bytes"] += int(stacked.nbytes)
                 self._stack_bytes = self._byte_cache_put(
                     self._stack_cache, key, (fp, stacked),
                     self._stack_budget, self._stack_bytes, "stack_evictions",
                 )
         finally:
             self._release(("stack", key))
-        return stacked
+        return stacked, "restack"
 
     # ----------------------------------------------------------- query memo
 
@@ -1224,6 +1296,14 @@ class ShardedQueryEngine:
         pre-write count would serve stale results forever. With the probe-
         time fingerprint the entry just misses on the next probe (the safe
         direction, matching the leaf cache's fp-before-read ordering)."""
+        with obs_span("engine.memo_probe") as sp:
+            hit, token = self._memo_probe(index, comp, shards)
+            if sp is not NOP_SPAN:
+                sp.tag(hit=hit is not None)
+        return hit, token
+
+    def _memo_probe(self, index: str, comp: "_Compiler",
+                    shards: Tuple[int, ...]):
         key = (index, comp.plan.sig_tuple, tuple(comp.leaves), shards)
         # O(1) staleness fast path: when the index's write epoch hasn't
         # moved since the entry was stored, NOTHING in the index changed,
@@ -1503,18 +1583,19 @@ class ShardedQueryEngine:
 
         def build():
             @jax.jit
-            def fn(leaves):
+            def count_expr(leaves):
                 plane = expr(leaves)
                 # XLA turns the full-tensor sum over the sharded axis into
                 # per-device partial popcounts + an ICI all-reduce.
                 return jnp.sum(jax.lax.population_count(plane).astype(jnp.int32))
 
-            return fn
+            return count_expr
 
         fn = self._fn_build(self._count_fns, sig, build, health_sig=hsig)
         leaves = self._leaf_tensor(index, comp.leaves, shards)
-        self._count_dispatch()
-        result = int(self._device_call(hsig, lambda: int(fn(leaves))))
+        self._note_launch(leaves, "count_dispatches")
+        with obs_span("engine.device_wait"):
+            result = int(self._device_call(hsig, lambda: int(fn(leaves))))
         self.memo_store(token, result)
         return result
 
@@ -1532,15 +1613,15 @@ class ShardedQueryEngine:
 
         def build():
             @jax.jit
-            def fn(leaves):
+            def count_expr(leaves):
                 plane = expr(leaves)
                 return jnp.sum(jax.lax.population_count(plane).astype(jnp.int32))
 
-            return fn
+            return count_expr
 
         fn = self._fn_build(self._count_fns, sig, build, health_sig=hsig)
         leaves = self._leaf_tensor(index, comp.leaves, shards)
-        self._count_dispatch()
+        self._note_launch(leaves, "count_dispatches")
         return self._device_call(hsig, lambda: fn(leaves))
 
     def count_batch(self, index: str, calls: Sequence[Call], shards: Sequence[int],
@@ -1580,9 +1661,10 @@ class ShardedQueryEngine:
                 # would escape as a raw XlaRuntimeError that bypasses
                 # classification, the breakers, and the ladder entirely.
                 # fire=False: the dispatch already paid the failpoint.
-                return self._device_call(
-                    tuple(comps[sub[0]][0].signature),
-                    lambda: np.asarray(arr)[: len(sub)], fire=False)
+                with obs_span("engine.device_wait"):
+                    return self._device_call(
+                        tuple(comps[sub[0]][0].signature),
+                        lambda: np.asarray(arr)[: len(sub)], fire=False)
 
             try:
                 res = run(miss)
@@ -1640,20 +1722,20 @@ class ShardedQueryEngine:
             exprs = [e for _, e in comps]
 
             @jax.jit
-            def fn(leavess):
+            def count_batch_exprs(leavess):
                 outs = []
                 for lv, e in zip(leavess, exprs):
                     plane = e(lv)
                     outs.append(jnp.sum(jax.lax.population_count(plane).astype(jnp.int32)))
                 return jnp.stack(outs)
 
-            return fn
+            return count_batch_exprs
 
         fn = self._fn_build(self._count_fns, sig, build, health_sig=sig0)
         leavess = tuple(
             self._leaf_tensor(index, comp.leaves, shards) for comp, _ in comps
         )
-        self._count_dispatch()
+        self._note_launch(leavess, "count_dispatches")
         return self._device_call(sig0, lambda: fn(leavess))
 
     @staticmethod
@@ -1765,20 +1847,16 @@ class ShardedQueryEngine:
                     )
 
             if invp:
-                @jax.jit
-                def fn(stacked, idxs, inv):
+                def count_batch_setops(stacked, idxs, inv):
                     return jnp.take(counts_of(stacked, idxs), inv)
             else:
-                @jax.jit
-                def fn(stacked, idxs):
+                def count_batch_setops(stacked, idxs):
                     return counts_of(stacked, idxs)
-            return fn
+            return jax.jit(count_batch_setops)
 
         hsig = comps[0][0].plan.sig_tuple
         fn = self._fn_build(self._count_fns, sig, build, health_sig=hsig)
-        with self._lock:
-            self.counters["count_dispatches"] += 1
-            self.counters["gather_kernel_dispatches"] += use_kernel
+        self._note_launch(stacked, "count_dispatches", kernel=use_kernel)
         if inv_in is not None:
             return self._device_call(hsig, lambda: fn(stacked, idxs, inv_in))
         return self._device_call(hsig, lambda: fn(stacked, idxs))
@@ -1806,17 +1884,20 @@ class ShardedQueryEngine:
         comp, expr = comp_expr if comp_expr is not None else self._compile(index, call)
         hsig = comp.plan.sig_tuple
         sig = ("bitmap", hsig, len(shards))
-        fn = self._fn_build(self._bitmap_fns, sig, lambda: jax.jit(expr),
-                            health_sig=hsig)
+        def bitmap_expr(leaves):
+            return expr(leaves)
+
+        fn = self._fn_build(self._bitmap_fns, sig,
+                            lambda: jax.jit(bitmap_expr), health_sig=hsig)
         leaves = self._leaf_tensor(index, comp.leaves, shards)
-        with self._lock:
-            self.counters["bitmap_dispatches"] += 1
+        self._note_launch(leaves, "bitmap_dispatches")
         # block_until_ready inside the guard: the Row keeps its segments
         # on device (no host transfer), but forcing completion here makes
         # an async device fault surface where it is classified and
         # recorded instead of deep inside a later Row operation.
-        planes = self._device_call(
-            hsig, lambda: fn(leaves).block_until_ready())  # (S_padded, W)
+        with obs_span("engine.device_wait"):
+            planes = self._device_call(
+                hsig, lambda: fn(leaves).block_until_ready())  # (S_padded, W)
         return Row({shard: planes[i] for i, shard in enumerate(shards)})
 
     def bitmap_batch(self, index: str, calls: Sequence[Call],
@@ -1858,20 +1939,20 @@ class ShardedQueryEngine:
 
         def build():
             @jax.jit
-            def fn(stacked, idxs):
+            def bitmap_batch_expr(stacked, idxs):
                 leaves = tuple(stacked[ix] for ix in idxs)  # each (Qp, S, W)
                 return expr(leaves)
 
-            return fn
+            return bitmap_batch_expr
 
         fn = self._fn_build(self._bitmap_fns, sig, build, health_sig=hsig)
-        with self._lock:
-            self.counters["bitmap_dispatches"] += 1
+        self._note_launch(stacked, "bitmap_dispatches")
         # block_until_ready inside the guard, like bitmap(): an async
         # device fault must classify here, not inside a later Row op.
-        planes = self._device_call(
-            hsig,
-            lambda: fn(stacked, idxs).block_until_ready())  # (Qp, Sp, W)
+        with obs_span("engine.device_wait"):
+            planes = self._device_call(
+                hsig,
+                lambda: fn(stacked, idxs).block_until_ready())  # (Qp, Sp, W)
         return [
             Row({shard: planes[qi if inverse is None else int(inverse[qi]), i]
                  for i, shard in enumerate(shards)})
@@ -1962,17 +2043,19 @@ class ShardedQueryEngine:
 
                 def build():
                     @jax.jit
-                    def fn(stacked):
+                    def topn_shard_row_counts(stacked):
                         return jnp.sum(
                             jax.lax.population_count(stacked).astype(jnp.int32), axis=2
                         )
 
-                    return fn
+                    return topn_shard_row_counts
 
                 fn = self._fn_build(self._count_fns, sig, build)
-                row_counts = self._device_call(
-                    None,
-                    lambda: np.asarray(fn(rows_tensor))[:r_real, :s_real])
+                self._note_launch(rows_tensor)
+                with obs_span("engine.device_wait"):
+                    row_counts = self._device_call(
+                        None,
+                        lambda: np.asarray(fn(rows_tensor))[:r_real, :s_real])
                 self._aux_store(rkey, rows_fp, row_counts)
 
         if src_call is not None:
@@ -1981,7 +2064,7 @@ class ShardedQueryEngine:
 
             def build():
                 @jax.jit
-                def fn(stacked, src_lv):
+                def topn_shard_src_counts(stacked, src_lv):
                     src = expr(src_lv)
                     src_counts = jnp.sum(
                         jax.lax.population_count(src).astype(jnp.int32), axis=1
@@ -1994,7 +2077,7 @@ class ShardedQueryEngine:
                     )
                     return inter, src_counts
 
-                return fn
+                return topn_shard_src_counts
 
             fn = self._fn_build(self._count_fns, sig, build)
 
@@ -2003,7 +2086,9 @@ class ShardedQueryEngine:
                 return (np.asarray(inter)[:r_real, :s_real],
                         np.asarray(src_counts)[:s_real])
 
-            inter, src_counts = self._device_call(None, run)
+            self._note_launch((rows_tensor, src_leaves))
+            with obs_span("engine.device_wait"):
+                inter, src_counts = self._device_call(None, run)
             value = (row_counts, inter, src_counts)
         else:
             value = (row_counts, None, None)
@@ -2051,18 +2136,21 @@ class ShardedQueryEngine:
 
             def build():
                 @jax.jit
-                def fn(stacked, src_lv):
+                def topn_src_counts(stacked, src_lv):
                     src = expr(src_lv)  # (S, W)
                     masked = jnp.bitwise_and(stacked, src[None, :, :])
                     return jnp.sum(
                         jax.lax.population_count(masked).astype(jnp.int32), axis=(1, 2)
                     )
 
-                return fn
+                return topn_src_counts
 
             fn = self._fn_build(self._count_fns, sig, build)
-            value = self._device_call(
-                None, lambda: np.asarray(fn(rows_tensor, src_leaves))[:r_real])
+            self._note_launch((rows_tensor, src_leaves))
+            with obs_span("engine.device_wait"):
+                value = self._device_call(
+                    None,
+                    lambda: np.asarray(fn(rows_tensor, src_leaves))[:r_real])
             self._aux_store(mkey, fp, value)
             return value[sel]
 
@@ -2070,16 +2158,18 @@ class ShardedQueryEngine:
 
         def build():
             @jax.jit
-            def fn(stacked):
+            def topn_counts(stacked):
                 return jnp.sum(
                     jax.lax.population_count(stacked).astype(jnp.int32), axis=(1, 2)
                 )
 
-            return fn
+            return topn_counts
 
         fn = self._fn_build(self._count_fns, sig, build)
-        value = self._device_call(
-            None, lambda: np.asarray(fn(rows_tensor))[:r_real])
+        self._note_launch(rows_tensor)
+        with obs_span("engine.device_wait"):
+            value = self._device_call(
+                None, lambda: np.asarray(fn(rows_tensor))[:r_real])
         self._aux_store(mkey, fp, value)
         return value[sel]
 
@@ -2128,7 +2218,7 @@ class ShardedQueryEngine:
 
             if kind == "sum":
                 @jax.jit
-                def fn(planes, flt):
+                def bsi_val_count(planes, flt):
                     stacked = planes  # (D+1, S, W)
                     if expr is not None:
                         stacked = jnp.bitwise_and(stacked, expr(flt)[None])
@@ -2140,7 +2230,7 @@ class ShardedQueryEngine:
                 maximize = kind == "max"
 
                 @jax.jit
-                def fn(planes, flt):
+                def bsi_val_count(planes, flt):
                     consider = planes[bit_depth]
                     if expr is not None:
                         consider = jnp.bitwise_and(consider, expr(flt))
@@ -2159,7 +2249,7 @@ class ShardedQueryEngine:
                     )
                     return bits, total(consider)
 
-            return fn
+            return bsi_val_count
 
         fn = self._fn_build(self._count_fns, sig, build)
 
@@ -2172,7 +2262,10 @@ class ShardedQueryEngine:
             bits, count = out
             return (np.asarray(bits), int(count))
 
-        value = self._device_call(None, run)
+        self._note_launch(
+            planes if filter_leaves is None else (planes, filter_leaves))
+        with obs_span("engine.device_wait"):
+            value = self._device_call(None, run)
         self._aux_store(mkey, fp, value)
         return value
 

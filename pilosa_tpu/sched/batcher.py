@@ -201,11 +201,15 @@ class MicroBatcher:
             group.closed = True
             items = list(group.items)
         try:
-            if len(items) == 1:
-                results = [backend.count(index, items[0].call)]
-            else:
-                results = backend.count_batch(
-                    index, [it.call for it in items])
+            # The fused launch the whole group waits for: what a
+            # follower's batch.hold holds beyond the window is this span
+            # of its leader's trace.
+            with obs_span("batch.launch", size=len(items)):
+                if len(items) == 1:
+                    results = [backend.count(index, items[0].call)]
+                else:
+                    results = backend.count_batch(
+                        index, [it.call for it in items])
             for it, r in zip(items, results):
                 it.result = int(r)
         except BaseException as e:
@@ -312,19 +316,21 @@ class MicroBatcher:
             group.closed = True
             items = list(group.items)
         try:
-            if len(items) == 1:
-                results = [self._direct(kind, engine, index, items[0].call,
-                                        shards, items[0].comp_expr)]
-            elif kind == "count":
-                results = engine.count_batch(
-                    index, [it.call for it in items], shards,
-                    comps=[it.comp_expr for it in items],
-                )
-            else:
-                results = engine.bitmap_batch(
-                    index, [it.call for it in items], shards,
-                    comps=[it.comp_expr for it in items],
-                )
+            with obs_span("batch.launch", size=len(items)):
+                if len(items) == 1:
+                    results = [self._direct(
+                        kind, engine, index, items[0].call, shards,
+                        items[0].comp_expr)]
+                elif kind == "count":
+                    results = engine.count_batch(
+                        index, [it.call for it in items], shards,
+                        comps=[it.comp_expr for it in items],
+                    )
+                else:
+                    results = engine.bitmap_batch(
+                        index, [it.call for it in items], shards,
+                        comps=[it.comp_expr for it in items],
+                    )
             for it, r in zip(items, results):
                 it.result = int(r) if kind == "count" else r
         except BaseException as e:

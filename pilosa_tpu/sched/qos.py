@@ -265,8 +265,9 @@ class TenantLedger:
             b.charged_ms += actual
         # The charge as a trace stage (docs/observability.md): a traced
         # query shows what the ledger actually billed it. No-op when
-        # untraced.
-        obs_record("qos.charge", actual, tenant=tenant)
+        # untraced. `amount`: the span's length is the bill, no interval
+        # of the request, so it takes no part in the self times.
+        obs_record("qos.charge", actual, amount=True, tenant=tenant)
 
     # ------------------------------------------------------------- stats
 

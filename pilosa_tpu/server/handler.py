@@ -529,17 +529,23 @@ class Handler:
                 exclude_columns, deadline, epoch, wants_proto, headers,
                 None, None, at_position, max_staleness, tenant)
         token = _obs.activate(trace)
+        status = "ok"
         try:
-            return self._post_query_traced(
-                index, pql, shards, remote, column_attrs, exclude_row_attrs,
-                exclude_columns, deadline, epoch, wants_proto, headers,
-                recorder, trace, at_position, max_staleness, tenant)
+            # The root of the request's span tree: every other span
+            # descends from it, and its self time is what no stage
+            # accounts for (HTTP framing, JSON, result encoding).
+            with trace.span("request"):
+                return self._post_query_traced(
+                    index, pql, shards, remote, column_attrs,
+                    exclude_row_attrs, exclude_columns, deadline, epoch,
+                    wants_proto, headers, recorder, trace, at_position,
+                    max_staleness, tenant)
         except BaseException:
-            recorder.finish(trace, status="error")
+            status = "error"
             raise
         finally:
             _obs.deactivate(token)
-            recorder.finish(trace)
+            recorder.finish(trace, status=status)
 
     def _post_query_traced(self, index, pql, shards, remote, column_attrs,
                            exclude_row_attrs, exclude_columns, deadline,
@@ -586,9 +592,13 @@ class Handler:
                 # and return its stage summary, size-bounded, so the
                 # coordinator attaches it as child spans of its
                 # remote:<peer> span. finish() is idempotent; the
-                # handler's finally only re-lands errors.
+                # handler's finally only re-lands errors. The root span
+                # ends first (the query returned, so it is the one span
+                # still open here), or the summary would lack it.
+                from ..obs.trace import SUMMARY_MAX_BYTES, current_span
+
+                current_span().close()
                 recorder.finish(trace)
-                from ..obs.trace import SUMMARY_MAX_BYTES
 
                 extra["X-Pilosa-Trace-Summary"] = trace.summary_header(
                     SUMMARY_MAX_BYTES)
@@ -1115,14 +1125,23 @@ class Handler:
     def handle_debug_profile(self, query, **kw):
         """Capture a JAX profiler trace (the pprof-equivalent for the
         device hot path). POST /debug/profile?seconds=2 writes a trace
-        under <data_dir>/profiles and returns its path. The profiler is
+        under <data_dir>/profiles and answers its path and the capture's
+        bounds on the host's two clocks (`time.time()` and
+        `time.monotonic()`, seconds). The profiler's Python tracer is OFF
+        unless `?python=1` asks for it: it slows the server several times
+        over, and a capture must not slow what it measures. While the
+        capture runs every request span is also an annotation in the
+        profiler's trace (docs/observability.md). The profiler is
         process-global: concurrent captures are rejected with 409."""
         import os
         import uuid
 
         import jax
 
+        from ..obs import trace as obs_trace
+
         seconds = min(max(float(query.get("seconds", ["1"])[0]), 0.0), 30.0)
+        python = query.get("python", ["0"])[0] in ("1", "true")
         if not self._profile_lock.acquire(blocking=False):
             return 409, "application/json", json.dumps(
                 {"error": "a profile capture is already running"}
@@ -1132,15 +1151,23 @@ class Handler:
             out = os.path.join(base, "profiles",
                                f"{int(time.time())}-{uuid.uuid4().hex[:6]}")
             os.makedirs(out, exist_ok=True)
-            jax.profiler.start_trace(out)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = int(python)
+            jax.profiler.start_trace(out, profiler_options=options)
+            bounds = {"started_wall": time.time(),
+                      "started_mono": time.monotonic()}
             try:
+                obs_trace.capture_began()
                 # pilint: allow-blocking(the sleep IS the capture window; _profile_lock is a try-acquire busy flag — contenders 409 instead of waiting, so nothing can queue behind this)
                 time.sleep(seconds)
             finally:
+                obs_trace.capture_ended()
+                bounds["stopped_wall"] = time.time()
+                bounds["stopped_mono"] = time.monotonic()
                 jax.profiler.stop_trace()
         finally:
             self._profile_lock.release()
-        return {"path": out}
+        return {"path": out, "python_tracer": python, **bounds}
 
     def handle_debug_threads(self, **kw):
         """Stack dump of every live Python thread — the goroutine-dump half

@@ -326,8 +326,14 @@ class Server:
         # slow-query log, per-stage histograms for /metrics. The handler
         # starts/adopts traces; everything downstream records via the
         # obs contextvar.
-        from ..obs import ObsConfig, TraceRecorder
+        from ..obs import ObsConfig, TraceRecorder, trace as obs_trace
 
+        # obs/ imports no jax: the profiler's annotation class is handed
+        # in here, for the spans of a request under a /debug/profile
+        # capture (jax is loaded by now: the executor's engine imports it).
+        import jax.profiler
+
+        obs_trace.set_annotation(jax.profiler.TraceAnnotation)
         self.obs_config = (obs_config or ObsConfig()).validate()
         self.trace_recorder = TraceRecorder(
             self.obs_config, stats=self.stats, logger=self.logger,

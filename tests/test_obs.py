@@ -632,3 +632,548 @@ def test_obs_config_toml_env_flag_precedence(tmp_path, monkeypatch):
         ObsConfig(ring_size=-1).validate()
     with pytest.raises(ValueError):
         ObsConfig(slow_query_ms=-1.0).validate()
+
+
+# ------------------------------------------- span tree and self times (PR 28)
+
+
+def _finished(rec, trace):
+    rec.finish(trace)
+    return {s["name"]: s for s in trace.to_dict()["spans"]}
+
+
+def test_span_ids_and_parents_across_nested_with_and_record(fake_clock):
+    rec = TraceRecorder(ObsConfig(sample_rate=1.0), clock=fake_clock, seed=21)
+    t = rec.maybe_start("i", "q")
+    token = obs.activate(t)
+    try:
+        with obs.span("request") as root:
+            assert obs.current_span() is root
+            obs.record("sched.wait", 0.0, cls="interactive")
+            with obs.span("device.dispatch") as disp:
+                assert obs.current_span() is disp
+                with obs.span("gather"):
+                    fake_clock.advance(0.001)
+                obs.record("batch.hold", 0.0, held=0)
+            assert obs.current_span() is root
+        assert obs.current_span() is None
+    finally:
+        obs.deactivate(token)
+    spans = _finished(rec, t)
+    ids = [s["id"] for s in spans.values()]
+    assert len(set(ids)) == len(ids) == 5
+    assert spans["request"]["parent"] is None
+    assert spans["sched.wait"]["parent"] == spans["request"]["id"]
+    assert spans["device.dispatch"]["parent"] == spans["request"]["id"]
+    assert spans["gather"]["parent"] == spans["device.dispatch"]["id"]
+    assert spans["batch.hold"]["parent"] == spans["device.dispatch"]["id"]
+
+
+def test_open_span_of_another_trace_is_no_parent(fake_clock):
+    """The autoscaler opens a one-span trace of its own while a request's
+    span may be open on the same context: ids are per trace, so a span
+    takes no parent from a trace that is not its own."""
+    rec = TraceRecorder(ObsConfig(sample_rate=1.0), clock=fake_clock, seed=22)
+    outer, inner = rec.maybe_start("i", "a"), rec.maybe_start("i", "b")
+    with outer.span("request"):
+        with inner.span("autoscale.decide"):
+            pass
+        inner.record("reduce", 0.0)
+    assert [s.parent for s in inner.spans] == [None, None]
+
+
+# (children as (start, length) in ms inside a parent of 100 ms from 0; self)
+_SELF_CASES = {
+    "no-children": ([], 100.0),
+    "one-child": ([(10, 30)], 70.0),
+    "abutting": ([(10, 20), (30, 20)], 60.0),
+    "overlapping": ([(10, 30), (30, 40)], 40.0),
+    "nested-overlap": ([(10, 60), (20, 10)], 40.0),
+    "covers-all": ([(0, 100)], 0.0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SELF_CASES))
+def test_self_ms_is_length_less_union_of_children(case, fake_clock):
+    children, want = _SELF_CASES[case]
+    rec = TraceRecorder(ObsConfig(sample_rate=1.0), clock=fake_clock, seed=23)
+    t = rec.maybe_start("i", "q")
+    root = t.span("request")
+    with root:
+        fake_clock.advance(0.100)
+    for start, length in children:
+        sp = t.span("gather", parent=root)
+        sp.start_ms, sp.dur_ms = float(start), float(length)
+        t._append(sp)
+    spans = _finished(rec, t)
+    assert spans["request"]["self_ms"] == pytest.approx(want)
+    # A child without children of its own is all self time.
+    if children:
+        assert spans["gather"]["self_ms"] == spans["gather"]["dur_ms"]
+
+
+def test_self_ms_clips_a_child_to_its_parent_and_skips_amounts(fake_clock):
+    rec = TraceRecorder(ObsConfig(sample_rate=1.0), clock=fake_clock, seed=24)
+    t = rec.maybe_start("i", "q")
+    fake_clock.advance(0.010)
+    root = t.span("request")
+    with root:
+        fake_clock.advance(0.020)
+        # A pre-measured span that began before its parent did (clipped to
+        # it), and a bill whose length is no interval at all.
+        t.record("sched.wait", 25.0, parent=root)
+        t.record("qos.charge", 500.0, parent=root, amount=True)
+    spans = _finished(rec, t)
+    assert spans["request"]["dur_ms"] == pytest.approx(20.0)
+    assert spans["request"]["self_ms"] == pytest.approx(0.0)
+    assert spans["qos.charge"]["self_ms"] == 0.0
+    assert spans["qos.charge"]["dur_ms"] == 500.0
+
+
+def test_span_closed_on_another_thread_keeps_the_parent_it_was_given():
+    import threading
+
+    rec = TraceRecorder(ObsConfig(sample_rate=1.0), seed=25)
+    t = rec.maybe_start("i", "q")
+    token = obs.activate(t)
+    try:
+        with obs.span("executor.fanout") as fan:
+            above = obs.current_span()
+
+            def leg():
+                # A pool thread: no trace and no open span on its context.
+                assert obs.current() is None and obs.current_span() is None
+                with t.span("remote:peer", parent=above):
+                    pass
+                assert obs.current_span() is None
+
+            th = threading.Thread(target=leg)
+            th.start()
+            th.join()
+            # The hop did not disturb the request's own open span.
+            assert obs.current_span() is fan
+    finally:
+        obs.deactivate(token)
+    spans = _finished(rec, t)
+    assert spans["remote:peer"]["parent"] == spans["executor.fanout"]["id"]
+
+
+def test_span_ids_stay_unique_under_threads():
+    """Spans of one trace are opened from many threads at once (hedged
+    legs, pool workers): ids must not collide, and every span keeps the
+    parent it was given."""
+    import sys
+    import threading
+
+    rec = TraceRecorder(ObsConfig(sample_rate=1.0), seed=31)
+    t = rec.maybe_start("i", "q")
+    workers, each = 16, 10  # 321 spans: under the trace's cap of 512
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with t.span("executor.fanout") as fan:
+            def leg():
+                for _ in range(each):
+                    with t.span("remote:peer", parent=fan):
+                        with t.span("gather"):
+                            pass
+
+            threads = [threading.Thread(target=leg) for _ in range(workers)]
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join(timeout=30)
+            assert not any(th.is_alive() for th in threads)
+    finally:
+        sys.setswitchinterval(was)
+    rec.finish(t)
+    spans = t.to_dict()["spans"]
+    assert len(spans) == 2 * workers * each + 1 < obs.trace.SPANS_MAX
+    kept = {s["id"]: s for s in spans}
+    assert len(kept) == len(spans)
+    hops = [s for s in spans if s["name"] == "remote:peer"]
+    assert {s["parent"] for s in hops} == {fan.id}
+    # Each gather ran on its hop's thread, under that hop and no other.
+    assert all(kept[s["parent"]]["name"] == "remote:peer"
+               for s in spans if s["name"] == "gather")
+
+
+def test_a_trace_that_leaves_the_ring_is_freed_without_the_collector():
+    """A span does not point back at its trace once it has ended, so a
+    landed trace is no reference cycle: when it leaves the ring, reference
+    counting frees it and its spans, and the cyclic collector (which has
+    the whole serving heap to look at) finds nothing of theirs."""
+    import gc
+
+    rec = TraceRecorder(ObsConfig(sample_rate=1.0, ring_size=2), seed=32)
+    gc.collect()
+    gc.disable()
+    gc.set_debug(gc.DEBUG_SAVEALL)
+    try:
+        for _ in range(20):
+            _some_spans(rec)
+        gc.collect()
+        left = [o for o in gc.garbage
+                if isinstance(o, (obs.Span, obs.Trace))]
+    finally:
+        gc.set_debug(0)
+        gc.garbage.clear()
+        gc.enable()
+    assert left == []
+    assert len(rec.traces()) == 2
+
+
+def test_summary_header_and_splice_keep_their_formats():
+    rec = TraceRecorder(ObsConfig(sample_rate=1.0), seed=26)
+    peer = rec.maybe_start("i", "q")
+    with peer.span("request"):
+        with peer.span("gather", kind="cold"):
+            pass
+    rec.finish(peer)
+    rows = json.loads(peer.summary_header())["spans"]
+    assert [r[0] for r in rows] == ["gather", "request"]
+    assert all(len(r) in (3, 4) for r in rows)  # name, start, length[, tags]
+    mine = rec.maybe_start("i", "q")
+    with mine.span("remote:peer") as hop:
+        pass
+    hop.splice(peer.summary_header())
+    rec.finish(mine)
+    out = mine.to_dict()["spans"][0]
+    assert [c["name"] for c in out["children"]] == ["gather", "request"]
+    assert set(out["children"][0]) == {"name", "start_ms", "dur_ms", "tags"}
+    assert out["self_ms"] == out["dur_ms"]  # a peer's spans take nothing off
+
+
+def _tree_checks(tr):
+    """What every served trace has to satisfy: one root, every other span
+    under a span of the same trace, and self times that add up to it."""
+    spans = tr["spans"]
+    by_id = {s["id"]: s for s in spans}
+    assert len(by_id) == len(spans)
+    roots = [s for s in spans if s["parent"] is None]
+    assert [s["name"] for s in roots] == ["request"], roots
+    for s in spans:
+        assert s["parent"] is None or s["parent"] in by_id, s
+        assert 0.0 <= s["self_ms"] <= s["dur_ms"] + 1e-9, s
+    total = sum(s["self_ms"] for s in spans)
+    # to_dict rounds to a microsecond: half of one for each span.
+    assert total == pytest.approx(roots[0]["dur_ms"],
+                                  abs=0.001 * len(spans)), spans
+    return {s["name"]: s for s in spans}
+
+
+def test_served_count_is_one_tree_whose_self_times_sum_to_the_root(one_node):
+    h = f"localhost:{one_node.port}"
+    c = InternalClient()
+    assert c.query(h, "t", "Count(Row(f=0))")["results"] == [64]
+    assert c.query(h, "t", "Set(5, f=0)")["results"] == [False]
+    assert c.query(h, "t", "Count(Row(f=0))")["results"] == [64]
+    traces = _get_json(h, "/debug/traces")["traces"]
+    assert len(traces) == 3
+    for tr in traces:
+        _tree_checks(tr)
+    first = _tree_checks(traces[-1])
+    fan = find_span(traces[-1], "executor.fanout")
+    disp = find_span(traces[-1], "device.dispatch")
+    assert disp["parent"] == fan["id"]
+    assert find_span(traces[-1], "reduce")["parent"] == fan["id"]
+    assert find_span(traces[-1], "sched.wait")["parent"] == first["request"]["id"]
+    # What the engine did under the dispatch, by name.
+    probe = find_span(traces[-1], "engine.memo_probe")
+    assert probe["tags"] == {"hit": False} and probe["parent"] == disp["id"]
+    wait = find_span(traces[-1], "engine.device_wait")
+    assert wait["parent"] == disp["id"]
+    # The first Count built its program: the compile is the first call,
+    # inside the wait for the device.
+    build = find_span(traces[-1], "engine.fn_build")
+    assert build["tags"] == {"kind": "count"} and build["parent"] == wait["id"]
+    assert not find_spans(traces[0], "engine.fn_build")
+    # The stage histograms still observe whole lengths, under every name.
+    hists = one_node.trace_recorder.stage_histograms()
+    assert {"request", "engine.memo_probe", "engine.device_wait"} <= set(hists)
+
+
+def test_coalesced_counts_show_the_leaders_launch(one_node):
+    """Four concurrent Counts over distinct rows, held in one group: the
+    leader's trace has `batch.launch` with the stack and the wait for the
+    device under it; a follower's `batch.hold` spans that launch."""
+    import threading
+
+    n = 4
+    fld = one_node.holder.index("t").field("f")
+    fld.import_bits(np.repeat(np.arange(1, n, dtype=np.uint64), 8),
+                    np.tile(np.arange(8, dtype=np.uint64), n - 1))
+    b = one_node.batcher
+    b.window, b.window_max, b.batch_max = 2.0, 10.0, n
+    b.depth_fn = lambda: n
+    h = f"localhost:{one_node.port}"
+    got = {}
+    barrier = threading.Barrier(n)
+
+    def client(i):
+        barrier.wait(timeout=10)
+        got[i] = InternalClient().query(h, "t", f"Count(Row(f={i}))")["results"]
+
+    threads = [threading.Thread(target=client, args=(i,)) for i in range(n)]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join(timeout=60)
+    assert got == {0: [64], 1: [8], 2: [8], 3: [8]}
+    traces = _get_json(h, "/debug/traces")["traces"]
+    assert len(traces) == n
+    leaders = [tr for tr in traces if find_spans(tr, "batch.launch")]
+    assert len(leaders) == 1
+    for tr in traces:
+        _tree_checks(tr)
+    lead = leaders[0]
+    launch = find_span(lead, "batch.launch")
+    assert launch["tags"] == {"size": n}
+    assert launch["parent"] == find_span(lead, "device.dispatch")["id"]
+    stack = find_span(lead, "engine.stack")
+    assert stack["parent"] == launch["id"]
+    assert stack["tags"] == {"planes": n, "kind": "restack"}
+    assert find_span(lead, "engine.device_wait")["parent"] == launch["id"]
+    assert find_span(lead, "engine.fn_build")["tags"]["kind"] == \
+        "count_batch_setops"
+    for tr in traces:
+        if tr is not lead:
+            hold = find_span(tr, "batch.hold")
+            assert hold["tags"]["role"] == "follower"
+            assert hold["dur_ms"] >= launch["dur_ms"] * 0.5
+    ec = one_node.executor.engine.snapshot()
+    # One stack of n planes over one padded shard was copied and handed on.
+    one_plane = ec["restack_bytes"] // n
+    assert ec["restack_bytes"] == n * one_plane > 0
+    assert ec["plane_bytes_read"] >= ec["restack_bytes"]
+
+
+def test_fn_builds_by_kind_sum_to_fn_cache_builds(one_node):
+    from pilosa_tpu.parallel.engine import FN_KINDS
+
+    h = f"localhost:{one_node.port}"
+    c = InternalClient()
+    ec = _get_json(h, "/debug/vars")["engine_cache"]
+    # Registered at 0, so that a first build shows as growth of its key.
+    assert [ec[f"fn_builds_{k}"] for k in FN_KINDS] == [0] * len(FN_KINDS)
+    c.query(h, "t", "Count(Row(f=0))")
+    c.query(h, "t", "TopN(f, Row(f=0), n=1)")
+    c.query(h, "t", "Set(9, f=0)")
+    c.query(h, "t", "Count(Row(f=0))")
+    ec = _get_json(h, "/debug/vars")["engine_cache"]
+    by_kind = {k: v for k, v in ec.items() if k.startswith("fn_builds_")}
+    assert sum(by_kind.values()) == ec["fn_cache_builds"] >= 2
+    assert by_kind["fn_builds_count"] == 1
+    assert ec["plane_bytes_read"] > 0
+
+
+def test_every_device_program_has_a_name_of_its_own(one_node):
+    h = f"localhost:{one_node.port}"
+    c = InternalClient()
+    c.query(h, "t", "Count(Row(f=0))")
+    assert c.query(h, "t", "Set(100, f=0)")["results"] == [True]
+    assert c.query(h, "t", "Count(Row(f=0))")["results"] == [65]
+    c.query(h, "t", "Row(f=0)")
+    eng = one_node.executor.engine
+    names = {sig[0]: fn.__name__
+             for cache in (eng._count_fns, eng._bitmap_fns)
+             for sig, fn in cache.items()}
+    assert names["count"] == "count_expr"
+    assert names["leaf_delta"] == "leaf_delta_scatter"
+    assert names["bitmap"] == "bitmap_expr"
+    assert not {"fn", "<lambda>"} & set(names.values())
+
+
+# --------------------------------- spans on the profiler's clock (PR 28)
+
+
+class _CountingAnnotation:
+    """Stand-in for jax.profiler.TraceAnnotation: counts what is built,
+    entered and left, by name."""
+
+    built, entered, left = [], [], []
+
+    def __init__(self, name, **stats):
+        self.name = name
+        self.stats = stats
+        _CountingAnnotation.built.append(self)
+
+    def __enter__(self):
+        _CountingAnnotation.entered.append(self.name)
+        return self
+
+    def __exit__(self, *exc):
+        _CountingAnnotation.left.append(self.name)
+        return False
+
+
+@pytest.fixture
+def counting_annotation():
+    from pilosa_tpu.obs import trace as obs_trace
+
+    was = obs_trace._annotation
+    for seen in (_CountingAnnotation.built, _CountingAnnotation.entered,
+                 _CountingAnnotation.left):
+        del seen[:]
+    obs_trace.set_annotation(_CountingAnnotation)
+    try:
+        yield _CountingAnnotation
+    finally:
+        obs_trace.capture_ended()
+        obs_trace.set_annotation(was)
+
+
+def _some_spans(rec):
+    t = rec.maybe_start("i", "q")
+    token = obs.activate(t)
+    try:
+        with obs.span("request"):
+            with obs.span("batch.hold", role="leader", held=1):
+                pass
+            with obs.span("engine.device_wait"):
+                pass
+            obs.record("sched.wait", 1.0)
+    finally:
+        obs.deactivate(token)
+    rec.finish(t)
+    return t
+
+
+def test_no_capture_builds_no_annotation(counting_annotation):
+    _some_spans(TraceRecorder(ObsConfig(sample_rate=1.0), seed=27))
+    assert counting_annotation.built == []
+
+
+def test_under_a_capture_every_span_enters_and_leaves_once(counting_annotation):
+    from pilosa_tpu.obs import trace as obs_trace
+
+    obs_trace.capture_began()
+    t = _some_spans(TraceRecorder(ObsConfig(sample_rate=1.0), seed=28))
+    obs_trace.capture_ended()
+    # The clock marks at both ends, and one annotation for each span that
+    # ran; the pre-measured `sched.wait` has none. Parked spans end in
+    # `wait`; the names in the trace itself do not change.
+    want = ["obs.clock", "request", "batch.hold.wait", "engine.device_wait",
+            "obs.clock"]
+    assert counting_annotation.entered == want
+    assert sorted(counting_annotation.left) == sorted(want)
+    clock = counting_annotation.built[0].stats
+    assert set(clock) == {"wall_ns", "mono_ns"}
+    ids = {s.name: s.id for s in t.spans}
+    for a in counting_annotation.built[1:-1]:
+        assert a.stats == {"trace": t.trace_id,
+                           "span": ids[a.name.replace(".hold.wait", ".hold")]}
+    assert [s.name for s in t.spans] == [
+        "batch.hold", "engine.device_wait", "sched.wait", "request"]
+    # After the capture nothing more is built.
+    _some_spans(TraceRecorder(ObsConfig(sample_rate=1.0), seed=29))
+    assert len(counting_annotation.built) == len(want)
+
+
+def test_a_span_open_across_the_captures_start_is_left_alone(
+        counting_annotation):
+    from pilosa_tpu.obs import trace as obs_trace
+
+    rec = TraceRecorder(ObsConfig(sample_rate=1.0), seed=30)
+    t = rec.maybe_start("i", "q")
+    with t.span("request"):
+        obs_trace.capture_began()
+    assert counting_annotation.entered == ["obs.clock"]
+
+
+def test_untraced_request_builds_no_span_and_no_annotation(
+        counting_annotation, monkeypatch):
+    """`[obs] sample-rate 0` and no capture: the whole served path makes
+    neither a Span nor an annotation."""
+    made = []
+    init = obs.Span.__init__
+
+    def counting_init(self, *a, **kw):
+        made.append(a[1])
+        init(self, *a, **kw)
+
+    monkeypatch.setattr(obs.Span, "__init__", counting_init)
+    s = Server(cache_flush_interval=0, member_monitor_interval=0,
+               obs_config=ObsConfig(sample_rate=0.0))
+    s.open()
+    try:
+        # Server.open handed the real class in; count through the stand-in.
+        from pilosa_tpu.obs import trace as obs_trace
+
+        obs_trace.set_annotation(_CountingAnnotation)
+        idx = s.holder.create_index("t")
+        idx.create_field("f").import_bits(
+            np.zeros(8, dtype=np.uint64), np.arange(8, dtype=np.uint64))
+        h = f"localhost:{s.port}"
+        c = InternalClient()
+        assert c.query(h, "t", "Count(Row(f=0))")["results"] == [8]
+        assert c.query(h, "t", "TopN(f, Row(f=0), n=1)")["results"]
+    finally:
+        s.close()
+    assert made == [] and counting_annotation.built == []
+
+
+def _post_json(host, path):
+    req = urllib.request.Request(f"http://{host}{path}", method="POST")
+    with urllib.request.urlopen(req) as r:
+        return json.load(r)
+
+
+@pytest.mark.parametrize("query,python", [("", False), ("&python=1", True)])
+def test_debug_profile_answers_its_bounds_on_both_clocks(one_node, query,
+                                                         python):
+    h = f"localhost:{one_node.port}"
+    w0, m0 = time.time(), time.monotonic()
+    got = _post_json(h, "/debug/profile?seconds=0.2" + query)
+    w1, m1 = time.time(), time.monotonic()
+    assert set(got) == {"path", "python_tracer", "started_wall",
+                        "stopped_wall", "started_mono", "stopped_mono"}
+    assert got["python_tracer"] is python
+    assert w0 <= got["started_wall"] <= got["stopped_wall"] <= w1
+    assert m0 <= got["started_mono"] <= got["stopped_mono"] <= m1
+    assert got["stopped_mono"] - got["started_mono"] >= 0.2
+
+
+def test_debug_profile_holds_the_requests_spans_and_refuses_a_second(
+        one_node):
+    """One capture through the real handler on the CPU backend: a second
+    one is refused with 409 while it runs, and the host plane of what it
+    wrote holds the spans of a request served meanwhile, by name, beside
+    the clock marks."""
+    import glob
+    import threading
+
+    h = f"localhost:{one_node.port}"
+    first = {}
+    th = threading.Thread(target=lambda: first.update(
+        _post_json(h, "/debug/profile?seconds=1.5")))
+    th.start()
+    try:
+        deadline = time.monotonic() + 10
+        while not obs.trace.capturing and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert obs.trace.capturing
+        with pytest.raises(urllib.error.HTTPError) as refused:
+            _post_json(h, "/debug/profile?seconds=0.1")
+        assert refused.value.code == 409
+        assert InternalClient().query(h, "t", "Count(Row(f=0))")["results"] \
+            == [64]
+    finally:
+        th.join(timeout=60)
+    assert not obs.trace.capturing
+    tr = _get_json(h, "/debug/traces")["traces"][0]
+    from jax.profiler import ProfileData
+
+    path = glob.glob(first["path"] + "/plugins/profile/*/*.xplane.pb")[0]
+    seen = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in ("request", "device.dispatch",
+                               "engine.device_wait", "obs.clock"):
+                    seen.setdefault(ev.name, []).append(dict(ev.stats))
+    assert len(seen["obs.clock"]) == 2
+    assert {"wall_ns", "mono_ns"} <= set(seen["obs.clock"][0])
+    for name in ("request", "device.dispatch", "engine.device_wait"):
+        assert seen[name][0]["trace"] == tr["id"]
+        assert seen[name][0]["span"] == find_span(tr, name)["id"]
