@@ -94,4 +94,8 @@ class HolderCleaner:
                         if cache and os.path.exists(cache):
                             os.remove(cache)
                         removed.append(f"{index_name}/{field.name}/{view.name}/{shard}")
+                        # After the pop, as a mutation bumps after its
+                        # generation: the engine's staleness checks trust
+                        # the epoch (parallel/engine.py _fingerprint).
+                        idx.write_epoch.bump()
         return removed

@@ -241,7 +241,22 @@ class Field:
                 view = self._new_view(name)
                 view.open()
                 self.views[name] = view
+                # The view may have come back with fragments from disk
+                # (see View.create_fragment_if_not_exists on the order).
+                if self.epoch is not None:
+                    self.epoch.bump()
             return view
+
+    def delete_view(self, name: str) -> None:
+        """Drop one view from memory, if there is such a view (its files
+        stay)."""
+        with self._lock:
+            view = self.views.pop(name, None)
+            if view is None:
+                return
+            view.close()
+            if self.epoch is not None:
+                self.epoch.bump()
 
     def view_names(self) -> List[str]:
         return sorted(list(self.views))

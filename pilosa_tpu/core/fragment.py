@@ -513,6 +513,13 @@ class Fragment:
         the epoch bump is what stale-proofs the batcher's group keys and
         the memo's O(1) probe — a path that skips either serves stale
         results silently (tests/test_delta.py parametrizes the audit).
+        The ORDER matters too: generation first, epoch last. The engine
+        asks the fragments for their generations once per epoch and keeps
+        the answer under the epoch it read BEFORE asking
+        (parallel/engine.py _fingerprint), so a reader that overlaps this
+        call can at worst keep a generation newer than its epoch, which
+        the bump below then retires. Epoch first would let it keep the
+        old generation under the new epoch: stale until the next write.
 
         `dirty_w64` is the iterable of changed 64-bit word indices within
         the row plane; None means the caller can't enumerate them (bulk
@@ -1552,6 +1559,7 @@ class Fragment:
             # Wholesale replacement: no per-word history exists, so every
             # cached generation older than NOW must full-regather.
             self._journal_reset()
+            # Epoch after generation: the order _invalidate_row explains.
             if self.epoch is not None:
                 self.epoch.bump()
             for row_id in self.rows():
@@ -1565,7 +1573,8 @@ class Fragment:
     def _migrate_invalidate(self) -> None:
         # Must hold _mu. Wholesale storage change with no per-word
         # history: poison every cached generation (full regather) and
-        # stale-proof the batcher/memo via the epoch.
+        # stale-proof the batcher/memo via the epoch — generation first,
+        # epoch last, the order _invalidate_row explains.
         self._plane_cache.clear()
         self._checksums.clear()
         self.generation += 1

@@ -148,6 +148,10 @@ class Index:
         field.open()
         field.save_meta()
         self.fields[name] = field
+        if field.views:
+            # Opened onto a directory that held views: fragments appeared
+            # without a mutation (see View.create_fragment_if_not_exists).
+            self.write_epoch.bump()
         return field
 
     def delete_field(self, name: str) -> None:

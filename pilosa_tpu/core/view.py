@@ -104,6 +104,13 @@ class View:
                 frag.open()
                 self.fragments[shard] = frag
                 created = True
+                # A view with one fragment more reads differently to the
+                # engine's staleness checks, which trust the epoch
+                # (parallel/engine.py _fingerprint): bump AFTER the
+                # fragment is in place, as a mutation bumps after its
+                # generation.
+                if self.epoch is not None:
+                    self.epoch.bump()
         # Broadcast outside the lock: the peer handling CreateShardMessage
         # takes its own view lock and may call back here (deadlock otherwise).
         if created and broadcast and self.broadcast_shard:

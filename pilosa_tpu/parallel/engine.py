@@ -7,7 +7,13 @@ tensor of shape (L, S, W) — L leaf rows, S shards sharded over the device
 mesh, W bitplane words. XLA fuses the whole tree into one fused
 elementwise+popcount kernel per device and inserts ICI collectives for the
 scalar reductions. Leaf planes are cached on device between queries and
-invalidated by fragment generation counters.
+invalidated by fragment generation counters: every cache entry carries
+the per-shard (incarnation, generation) fingerprint of its leaves' view,
+and a probe compares it with the current one. "Current" is asked of the
+fragments once per write epoch, not once per probe: `_fingerprint` keeps
+the last walk of each (index, field, view, shards) under the index's
+write-epoch token and serves it until that token moves (every mutation,
+and every change of which fragments a view has, moves it).
 
 Supported fast-path calls: Row / Intersect / Union / Difference / Xor /
 Range(BSI) compositions, Count(...) and per-row TopN candidate counting.
@@ -75,6 +81,12 @@ FN_KINDS = (
     "stack_delta", "bitmap", "bitmap_batch", "topn_shard", "topn_shard_src",
     "topn_src", "topn", "bsi",
 )
+
+# Bound of the fingerprint cache (ShardedQueryEngine._fingerprint): one
+# entry per (index, field, view, shard tuple) asked about, a few hundred
+# bytes each. A node serves a handful of shard tuples per index, so this
+# is hundreds of times what a busy one needs.
+_FP_CACHE_ENTRIES = 1024
 
 
 class _FirstCall:
@@ -368,6 +380,13 @@ class ShardedQueryEngine:
         self._aux_memo: Dict[Tuple, Tuple[Tuple, object]] = {}
         self._aux_budget = budget(
             "PILOSA_AUX_MEMO_ENTRIES", config.aux_memo_entries, 512)
+        # (index, field, view, shards) -> (write-epoch token, fingerprint):
+        # the last fragment walk of a view, trusted while the index's
+        # epoch stands still (_fingerprint). Fingerprints only, never
+        # Fragment objects: a dropped field's storage must not stay
+        # pinned here. Bounded by _FP_CACHE_ENTRIES, oldest walk out first.
+        # Written under self._lock, read without (see _fingerprint).
+        self._fp_cache: Dict[Tuple, Tuple[Tuple, Tuple]] = {}
         # Effective cache bounds after env > config > tier > default
         # resolution, surfaced verbatim in /debug/vars (engine_budgets) so
         # a deployment can SEE what its knobs resolved to.
@@ -384,6 +403,10 @@ class ShardedQueryEngine:
             "leaf_hits": 0, "leaf_misses": 0, "leaf_evictions": 0,
             "stack_hits": 0, "stack_misses": 0, "stack_evictions": 0,
             "memo_hits": 0, "memo_misses": 0,
+            # Staleness checks (_fingerprint): fp_walks asked every
+            # fragment of a view for its generation, fp_hits were served
+            # the walk of the same write epoch.
+            "fp_hits": 0, "fp_walks": 0,
             # Compiled-program (XLA executable) cache traffic: the proof
             # that canonicalized query shapes SHARE programs is
             # fn_cache_hits climbing while fn_cache_builds stays flat
@@ -875,30 +898,72 @@ class ShardedQueryEngine:
 
     # --------------------------------------------------------- leaf tensors
 
+    def _leaf_fragments(self, index: str, leaf: Leaf,
+                        shards: Tuple[int, ...]):
+        """(fragments, fingerprint) of one leaf's view: the walk itself,
+        one holder lookup per shard (None where a shard has no fragment)
+        and the per-shard (incarnation, generation) pairs READ FROM THOSE
+        fragments. Callers that go on to read the fragments' data
+        (_gather_leaf's refresh, _host_plane) come here and not to
+        _fingerprint, so that the two always belong together."""
+        fragment = self.holder.fragment
+        frags = [fragment(index, leaf.field, leaf.view, s) for s in shards]
+        return frags, tuple(
+            -1 if f is None else (f.incarnation, f.generation) for f in frags)
+
     def _fingerprint(self, index: str, leaf: Leaf, shards: Tuple[int, ...]) -> Tuple:
         """Per-shard (incarnation, generation) pairs for one leaf — the
-        staleness key for every device cache (no device work, just holder
-        lookups). The incarnation half makes a RECREATED fragment (deleted
-        index re-made under the same name, generation counter reset) never
-        compare equal to a stale entry, even if its fresh counter climbs
-        back to the cached value."""
-        return tuple(
-            -1 if f is None else (f.incarnation, f.generation)
-            for f in (
-                self.holder.fragment(index, leaf.field, leaf.view, s)
-                for s in shards
-            )
-        )
+        staleness key for every device cache (no device work). The
+        incarnation half makes a RECREATED fragment (deleted index re-made
+        under the same name, generation counter reset) never compare equal
+        to a stale entry, even if its fresh counter climbs back to the
+        cached value.
+
+        A generation belongs to the fragment, not to the leaf's row, so
+        the answer is the same for every row of a view and stands until
+        something in the index changes. It is therefore asked of the
+        fragments once per write epoch: the walk is kept under the epoch
+        token READ BEFORE IT and served (the same tuple object, so a
+        cache's `cached[0] == fp` compares by identity) while the token
+        stands. Why that is exact: a writer bumps its fragment's generation
+        and only THEN the epoch (core/fragment.py `_invalidate_row`,
+        `read_from`, `_migrate_invalidate`; what adds or drops a fragment
+        bumps after the change too). A walk that overlaps a write may so
+        keep a fingerprint NEWER than its token; the next call sees the
+        moved epoch and walks again. One OLDER than its token cannot be
+        kept, so nothing is served that a walk at that epoch would not
+        return: memo_probe's probe-time discipline, one level down."""
+        token = self._epoch_token(index)
+        key = (index, leaf.field, leaf.view, shards)
+        # A hit takes no lock: one dict read (atomic), one comparison. It
+        # is made 6-9 times a Count and 100-200 times a TopN from every
+        # serving thread, and self._lock there, however briefly held, is
+        # held by a thread that loses the interpreter often enough that
+        # the others queue behind it (measured on the chip: TopN's median
+        # 111 -> 185 ms; PERF.md, PR 29). So fp_hits is bumped unlocked
+        # too, and may undercount by a bump lost between two threads;
+        # fp_walks is exact.
+        ent = self._fp_cache.get(key)
+        if ent is not None and ent[0] == token:
+            self.counters["fp_hits"] += 1
+            return ent[1]
+        fp = self._leaf_fragments(index, leaf, shards)[1]
+        with self._lock:
+            self.counters["fp_walks"] += 1
+            if token != -1:  # no such index: nothing to say when it changes
+                # Re-inserted at the end: the entry walked longest ago is
+                # the first to go.
+                self._fp_cache.pop(key, None)
+                self._fp_cache[key] = (token, fp)
+                while len(self._fp_cache) > _FP_CACHE_ENTRIES:
+                    self._fp_cache.pop(next(iter(self._fp_cache)))
+        return fp
 
     def _gather_leaf(self, index: str, leaf: Leaf, shards: Tuple[int, ...]) -> jax.Array:
         """(S_padded, W) uint32, sharded over the mesh's shard axis."""
         s_padded = pad_shards(len(shards), self.n_devices)
         key = (index, leaf, shards)
-        frags = [
-            self.holder.fragment(index, leaf.field, leaf.view, s) for s in shards
-        ]
-        fingerprint = tuple(
-            -1 if f is None else (f.incarnation, f.generation) for f in frags)
+        fingerprint = self._fingerprint(index, leaf, shards)
 
         def probe():
             with self._lock:
@@ -923,6 +988,15 @@ class ShardedQueryEngine:
         # "why was this gather 30 ms" without correlating counters.
         with obs_span("gather") as sp:
             try:
+                # Only a refresh reads the fragments, so only here are they
+                # looked up, and the entry is stamped with what THEY say
+                # (read before their data, the order every cache relies
+                # on): the epoch's fingerprint may be older than them.
+                # Where the two agree the epoch's tuple is kept, which
+                # later probes compare by identity.
+                frags, fresh = self._leaf_fragments(index, leaf, shards)
+                if fresh != fingerprint:
+                    fingerprint = fresh
                 # Stale resident entry: try the delta path first — upload
                 # only the words the writes changed instead of re-walking
                 # every shard's containers and re-shipping the whole plane.
@@ -1400,12 +1474,7 @@ class ShardedQueryEngine:
         key = (index, leaf, shards)
         if cache is not None and key in cache:
             return cache[key]
-        frags = [
-            self.holder.fragment(index, leaf.field, leaf.view, s)
-            for s in shards
-        ]
-        fp = tuple(
-            -1 if f is None else (f.incarnation, f.generation) for f in frags)
+        frags, fp = self._leaf_fragments(index, leaf, shards)
         buf = None
         if self.tier is not None:
             buf = self.tier.promote(key, frags, fp, len(shards))

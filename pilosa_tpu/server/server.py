@@ -1238,8 +1238,8 @@ class Server:
                 fld.create_view_if_not_exists(msg["view"])
         elif typ == "delete-view":
             fld = self.holder.field(msg["index"], msg["field"])
-            if fld is not None and msg["view"] in fld.views:
-                fld.views.pop(msg["view"]).close()
+            if fld is not None:
+                fld.delete_view(msg["view"])
         elif typ == "create-shard":
             fld = self.holder.field(msg["index"], msg["field"])
             if fld is not None:

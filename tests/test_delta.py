@@ -363,11 +363,16 @@ def test_recreated_index_never_serves_stale_memo(holder):
     epoch0 = holder.index("i").write_epoch.value
     holder.delete_index("i")
     fld = holder.create_index("i").create_field("f")
-    for k in range(epoch0):  # drive the fresh epoch to the stored value
-        fld.set_bit(0, k)
-    assert holder.index("i").write_epoch.value == epoch0
+    fresh = holder.index("i").write_epoch
+    bits = 0
+    # Drive the fresh epoch to the stored value (a new view and a new
+    # fragment move it too, so count the bits, not the bumps).
+    while fresh.value < epoch0:
+        fld.set_bit(0, bits)
+        bits += 1
+    assert fresh.value == epoch0
     got = engine.count("i", call, [0])
-    assert got == epoch0 != old
+    assert got == bits != old
 
 
 def test_recreated_field_never_serves_stale_memo(holder):
