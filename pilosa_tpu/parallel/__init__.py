@@ -38,7 +38,9 @@ class EngineConfig:
     # pin the engine to mesh-devices=1 — per-node programs then carry no
     # collectives at all and only the (runner-serialized) collective
     # plane uses the full mesh. On four real chips the hazard did not
-    # show (docs/multichip.md, "One process, every local chip").
+    # show, in a smoke's waves (PR 21) or under the benchmark's load for
+    # minutes (PR 30), so the launches carry no lock (docs/multichip.md,
+    # "One process, every local chip").
     mesh_devices: int = 0
     # Cache budgets (0 = auto). Auto means: the legacy env override
     # (PILOSA_LEAF_CACHE_BYTES / PILOSA_STACK_CACHE_BYTES /

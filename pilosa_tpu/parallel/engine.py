@@ -22,6 +22,7 @@ Everything else falls back to the executor's per-shard path.
 
 from __future__ import annotations
 
+import math
 import os
 import threading
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -425,6 +426,13 @@ class ShardedQueryEngine:
             # copied into a fresh (U, S, W) stack on the device, the
             # copies that are most of the device's busy time under writes.
             "plane_bytes_read": 0, "restack_bytes": 0,
+            # On a mesh of more than one device: launches (those that
+            # _note_launch counts) whose program spans the devices, with
+            # the all-reduces XLA puts in or the gather kernel's psum. 0
+            # on one device. h2d_bytes: host bytes handed to the device by
+            # the refresh paths, whichever ran (a cold or tier-promoted
+            # plane's device_put, a delta scatter's indices and values).
+            "mesh_launches": 0, "h2d_bytes": 0,
             # Batched-count launches that went through the Pallas gather
             # kernel rather than the XLA formulation (a subset of
             # count_dispatches): the only outside evidence of which of
@@ -504,6 +512,7 @@ class ShardedQueryEngine:
                 self.counters[counter] += 1
             self.counters["plane_bytes_read"] += nbytes
             self.counters["gather_kernel_dispatches"] += kernel
+            self.counters["mesh_launches"] += self.n_devices > 1
 
     def snapshot(self) -> dict:
         """Wholesale counter export for /debug/vars (the `engine_cache`
@@ -516,8 +525,20 @@ class ShardedQueryEngine:
         """What this engine runs on, as JAX reports it (/debug/vars
         `device`): the mesh's platform, kind, size and shape, and each
         device's allocator figures where the backend has any (the CPU
-        backend reports none)."""
+        backend reports none). `cached_plane_bytes`, one figure a device
+        in the same order, is where the leaf and stack caches' arrays
+        really lie."""
         devices = list(self.mesh.devices.flat)
+        with self._lock:
+            cached = [e[1] for cache in (self._leaf_cache, self._stack_cache)
+                      for e in cache.values()]
+        held = dict.fromkeys(devices, 0)
+        for arr in cached:
+            sharding = arr.sharding
+            piece = arr.dtype.itemsize * math.prod(
+                sharding.shard_shape(arr.shape))
+            for d in sharding.device_set:
+                held[d] += piece
         per_device = []
         for d in devices:
             stats = d.memory_stats() or {}
@@ -532,6 +553,7 @@ class ShardedQueryEngine:
             "n_devices": len(devices),
             "mesh_shape": dict(self.mesh.shape),
             "devices": per_device,
+            "cached_plane_bytes": [held[d] for d in devices],
         }
 
     def close(self) -> None:
@@ -896,6 +918,13 @@ class ShardedQueryEngine:
     def n_devices(self) -> int:
         return self.mesh.devices.size
 
+    def _placing(self, nbytes: int):
+        """The span around a host-to-device placement on a refresh path
+        (a cold plane's device_put, a delta scatter's operands); a cache
+        hit never comes here."""
+        return obs_span("engine.place", bytes=int(nbytes),
+                        devices=self.n_devices)
+
     # --------------------------------------------------------- leaf tensors
 
     def _leaf_fragments(self, index: str, leaf: Leaf,
@@ -1020,9 +1049,11 @@ class ShardedQueryEngine:
                 if sp is not NOP_SPAN:
                     sp.tag(kind="tier-promote" if tier_hit else "cold",
                            bytes=int(buf.nbytes))
-                arr = self._oom_guard(None, lambda: jax.device_put(
-                    buf, shard_sharding(self.mesh, 2)))
+                with self._placing(buf.nbytes):
+                    arr = self._oom_guard(None, lambda: jax.device_put(
+                        buf, shard_sharding(self.mesh, 2)))
                 with self._lock:
+                    self.counters["h2d_bytes"] += buf.nbytes
                     if tier_hit:
                         self.counters["leaf_tier_hits"] += 1
                         self.counters["tier_promote_bytes"] += buf.nbytes
@@ -1185,11 +1216,13 @@ class ShardedQueryEngine:
                 leaf_delta_scatter,
                 out_shardings=shard_sharding(self.mesh, 2),
             ))
-            new_arr = fn(arr, rows, cols, vals)
             moved = int(rows.nbytes + cols.nbytes + vals.nbytes)
+            with self._placing(moved):
+                new_arr = fn(arr, rows, cols, vals)
         with self._lock:
             self.counters["leaf_delta_hits"] += 1
             self.counters["delta_bytes"] += moved
+            self.counters["h2d_bytes"] += moved
             self._leaf_bytes = self._byte_cache_put(
                 self._leaf_cache, key, (fingerprint, new_arr),
                 self._leaf_budget, self._leaf_bytes, "leaf_evictions",
@@ -1245,11 +1278,13 @@ class ShardedQueryEngine:
                 stack_delta_scatter,
                 out_shardings=shard_sharding(self.mesh, 3, axis=1),
             ))
-            new_arr = fn(arr, us, rows, cols, vals)
             moved = int(us.nbytes + rows.nbytes + cols.nbytes + vals.nbytes)
+            with self._placing(moved):
+                new_arr = fn(arr, us, rows, cols, vals)
         with self._lock:
             self.counters["stack_delta_hits"] += 1
             self.counters["delta_bytes"] += moved
+            self.counters["h2d_bytes"] += moved
             self._stack_bytes = self._byte_cache_put(
                 self._stack_cache, key, (fp, new_arr),
                 self._stack_budget, self._stack_bytes, "stack_evictions",

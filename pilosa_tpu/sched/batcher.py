@@ -48,6 +48,21 @@ from ..obs import record as obs_record, span as obs_span
 from .deadline import Deadline
 
 
+# How long a follower waits for its leader's fused launch before it gives
+# the leader up for wedged and dispatches alone (`fallbacks`). The leader's
+# launch holds whatever its group needs first: planes gathered cold, a
+# program compiled. The engine's own build gate waits five minutes for
+# exactly that work before it steals a build (engine._gate: "stealing on a
+# mere timeout would re-run 20-40s TPU compiles once per waiter during a
+# cold-start stampede"), and a follower that gives up sooner only queues
+# behind the same gates with a second dispatch. At 30 s it did: 256 shards
+# on four chips, 32 MiB a plane first touched at 0.7-0.9 s each and a 9 s
+# compile, and a follower of the warm-up's first groups fell back (PERF.md,
+# PR 30). A query's own deadline still cuts the wait short. A follower on
+# the collective plane keeps its 30 s: the barrier timeout bounds its leader.
+FOLLOWER_BUDGET_S = 300.0
+
+
 class _Item:
     __slots__ = ("call", "comp_expr", "event", "result", "error")
 
@@ -289,9 +304,9 @@ class MicroBatcher:
         else:
             # Leader wedged (device hang) or deadline pressure: fall back
             # to a direct dispatch rather than parking forever. The bound
-            # is generous — the leader normally answers within the window
-            # plus one launch.
-            budget = 30.0
+            # is the build gate's: a leader gathering cold planes or
+            # compiling is slow, not wedged.
+            budget = FOLLOWER_BUDGET_S
             if deadline is not None:
                 budget = max(0.0, min(budget, deadline.remaining()))
             with obs_span("batch.hold", role="follower", held=1):
