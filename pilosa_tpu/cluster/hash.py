@@ -24,9 +24,14 @@ def fnv64a(data: bytes) -> int:
     return h
 
 
+def shard_hash(index: str, shard: int) -> int:
+    """FNV-1a 64 over the index name and the big-endian shard: the pure
+    half of `partition`, which Cluster keeps per (index, shard)."""
+    return fnv64a(index.encode() + struct.pack(">Q", shard))
+
+
 def partition(index: str, shard: int, partition_n: int = DEFAULT_PARTITION_N) -> int:
-    data = index.encode() + struct.pack(">Q", shard)
-    return fnv64a(data) % partition_n
+    return shard_hash(index, shard) % partition_n
 
 
 def jump_hash(key: int, n: int) -> int:
@@ -39,9 +44,27 @@ def jump_hash(key: int, n: int) -> int:
     return b
 
 
+# Entries a memo of one of the pure functions here may hold before it is
+# emptied and starts again: placement asks about partition_n keys per
+# node count and one hash per shard of an index, so a deployment stays
+# far below it and a stream of wild shard numbers cannot grow it.
+MEMO_ENTRIES = 1 << 16
+
+
 class JmpHasher:
+    """jump_hash, kept per (key, n): a pure function, so what is kept
+    never goes stale and needs no invalidation."""
+
+    def __init__(self):
+        self._kept = {}
+
     def hash(self, key: int, n: int) -> int:
-        return jump_hash(key, n)
+        b = self._kept.get((key, n))
+        if b is None:
+            if len(self._kept) >= MEMO_ENTRIES:
+                self._kept.clear()
+            b = self._kept[(key, n)] = jump_hash(key, n)
+        return b
 
 
 class ModHasher:

@@ -10,10 +10,10 @@ from __future__ import annotations
 
 import threading
 from dataclasses import dataclass
-from typing import List, Optional, Set, Tuple
+from typing import Dict, List, Optional, Set, Tuple
 
 from ..constants import DEFAULT_PARTITION_N
-from .hash import JmpHasher, partition as partition_of
+from .hash import MEMO_ENTRIES, JmpHasher, shard_hash
 from .health import DownView, HealthRegistry
 
 # Cluster states (reference cluster.go:43-45).
@@ -90,11 +90,22 @@ class Cluster:
         self.next_nodes: Optional[List[Node]] = None
         self.migrated: Set[Tuple[str, int]] = set()
         self._routing_mu = threading.Lock()
+        # shard_hash per (index, shard): the pure half of partition().
+        # Placement keeps pure functions only (this and JmpHasher's), and
+        # reads nodes, next_nodes, migrated, replica_n and partition_n
+        # live on every call, because all of them are also changed in
+        # place (tests, add_node, a cutover) where no counter would see.
+        self._shard_hashes: Dict[Tuple[str, int], int] = {}
 
     # ------------------------------------------------------------ placement
 
     def partition(self, index: str, shard: int) -> int:
-        return partition_of(index, shard, self.partition_n)
+        h = self._shard_hashes.get((index, shard))
+        if h is None:
+            if len(self._shard_hashes) >= MEMO_ENTRIES:
+                self._shard_hashes.clear()
+            h = self._shard_hashes[(index, shard)] = shard_hash(index, shard)
+        return h % self.partition_n
 
     def _placement(self, nodes: List[Node], partition_id: int) -> List[Node]:
         if not nodes:
@@ -115,6 +126,46 @@ class Cluster:
         if nxt is not None and (index, shard) in self.migrated:
             nodes = nxt
         return self._placement(nodes, self.partition(index, shard))
+
+    def _witness(self, nodes: List[Node]):
+        return ([n.id for n in nodes], self.replica_n, self.partition_n,
+                self.hasher, self.node.id)
+
+    def placement_witness(self):
+        """Everything the owners of a shard depend on besides its index
+        and number, as a value to compare with `==`; None while a
+        rebalance is in flight (`migrated` then moves with every cutover).
+        Equal witnesses mean equal placement, whatever happened between
+        the two readings: node ids are compared by value and in order, so
+        an assignment, an in-place sort or append, and a rewritten Node.id
+        all show. `next_nodes` is read before `nodes`, as in shard_nodes:
+        a commit assigns `nodes` first."""
+        if self.next_nodes is not None:
+            return None
+        return self._witness(self.nodes)
+
+    def shard_list_owners(self, index: str, shards: List[int]):
+        """(witness, owner ids of each shard in placement order). With a
+        witness the ids were computed from the one copy of `nodes` that
+        the witness names, so the pair belongs together and may be kept
+        for as long as placement_witness() equals it; a partition's list
+        of ids is shared by its shards. Without one (a rebalance in
+        flight) they are shard_nodes' answers, shard by shard."""
+        if self.next_nodes is not None:
+            return None, [[n.id for n in self.shard_nodes(index, s)]
+                          for s in shards]
+        nodes = list(self.nodes)
+        witness = self._witness(nodes)
+        by_partition: Dict[int, List[str]] = {}
+        owners = []
+        for shard in shards:
+            p = self.partition(index, shard)
+            ids = by_partition.get(p)
+            if ids is None:
+                ids = by_partition[p] = [
+                    n.id for n in self._placement(nodes, p)]
+            owners.append(ids)
+        return witness, owners
 
     # ------------------------------------------------------ routing epochs
 
@@ -274,8 +325,10 @@ class Cluster:
 
     def add_node(self, node: Node) -> None:
         if self.node_by_id(node.id) is None:
-            self.nodes.append(node)
-            self.nodes.sort(key=lambda n: n.id)
+            # One assignment, not append + sort in place: a list is empty
+            # to its readers while it sorts, and placement reads `nodes`
+            # from every serving thread without a lock.
+            self.nodes = sorted(self.nodes + [node], key=lambda n: n.id)
 
     def remove_node(self, node_id: str) -> bool:
         n = self.node_by_id(node_id)

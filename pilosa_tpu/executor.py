@@ -9,6 +9,7 @@ dispatch, the shard map/reduce (executor.go:1464-1593), two-phase TopN
 
 from __future__ import annotations
 
+import threading
 import time as _time
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
@@ -56,6 +57,12 @@ def _topn_chunk(n_shards: int) -> int:
 
     budget = int(os.environ.get("PILOSA_TOPN_CHUNK_BYTES", 2 << 30))
     return max(1, min(512, budget // max(1, n_shards * WORDS_PER_ROW * 4)))
+
+
+# Shard lists whose owners Executor._shard_owners keeps at once (an entry
+# is a copy of the list and a pointer a shard; ids are shared). Emptied
+# when full: queries name few distinct lists, one per index as a rule.
+_OWNERS_KEPT = 64
 
 _WRITE_CALLS = {"Set", "Clear", "SetValue", "SetRowAttrs", "SetColumnAttrs"}
 
@@ -218,6 +225,12 @@ class Executor:
         # that fragment reading as EMPTY rather than erroring — this
         # counter surfaces how often results were degraded (/debug/vars).
         self.quarantined_reads = 0
+        # Owners of whole shard lists, kept across queries (_shard_owners),
+        # and how often they served: the `executor` group of /debug/vars.
+        self._owners_kept: Dict[tuple, tuple] = {}
+        self._owners_mu = threading.Lock()
+        self.assign_hits = 0
+        self.assign_walks = 0
         # How long a write caught in a live-rebalance cutover window
         # (ShardMovedError locally, 409 from a frozen remote owner) keeps
         # re-routing while the commit broadcast lands, before surfacing a
@@ -466,6 +479,39 @@ class Executor:
 
     # ----------------------------------------------------------- mapReduce
 
+    def _shard_owners(self, index: str, shards: List[int]):
+        """(owner ids of each shard in placement order, whether this node
+        is among the owners of every one): Cluster.shard_list_owners, kept
+        across queries.
+
+        Who owns a shard is a pure function of the index, the shard and
+        what Cluster.placement_witness() names, so the answer for a shard
+        list is kept WITH the witness it was computed from and served while
+        the live witness equals it. Nothing is invalidated and nothing can
+        change placement behind it: `nodes` assigned or changed in place, a
+        rewritten Node.id and a rebalance (no witness, nothing kept or
+        served) all make the comparison fail. A hit takes no lock: one
+        dict read and two comparisons, from every serving thread (PERF.md,
+        PR 29: a lock here costs more than the walk). So assign_hits may
+        lose a bump between two threads; assign_walks is exact."""
+        key = (index, len(shards), shards[0], shards[-1])
+        kept = self._owners_kept.get(key)
+        if (kept is not None and kept[0] == self.cluster.placement_witness()
+                and kept[1] == shards):
+            self.assign_hits += 1
+            return kept[2], kept[3]
+        witness, owners = self.cluster.shard_list_owners(index, shards)
+        me = self.node.id
+        all_mine = all(me in ids for ids in owners)
+        with self._owners_mu:
+            self.assign_walks += 1
+            if witness is not None:
+                if len(self._owners_kept) >= _OWNERS_KEPT:
+                    self._owners_kept.clear()
+                self._owners_kept[key] = (witness, list(shards), owners,
+                                          all_mine)
+        return owners, all_mine
+
     def _assign_shards(self, index: str, shards: List[int], exclude=()):
         """Shards -> (local list, {node_id: shards}) using health info.
 
@@ -475,7 +521,15 @@ class Executor:
         lazily in placement order and memoized per assignment round, so a
         down peer whose backoff elapsed is admitted for its WHOLE shard
         batch — that one batched request is the half-open probe, and its
-        outcome (recorded by the fan-out) decides re-close vs re-open."""
+        outcome (recorded by the fan-out) decides re-close vs re-open.
+        Only the placement is kept across calls (_shard_owners); the
+        breaker and `exclude` are asked every time."""
+        if not shards:
+            return [], {}
+        me = self.node.id
+        owners, all_mine = self._shard_owners(index, shards)
+        if all_mine and me not in exclude:
+            return list(shards), {}
         health = self.cluster.health
         admitted: Dict[str, bool] = {}
 
@@ -486,26 +540,16 @@ class Executor:
 
         local: List[int] = []
         remote: Dict[str, List[int]] = {}
-        for shard in shards:
-            nodes = self.cluster.shard_nodes(index, shard)
-            owner = None
-            if any(n.id == self.node.id for n in nodes) and (
-                self.node.id not in exclude
-            ):
-                owner = self.node
-            else:
-                for n in nodes:  # placement order, like the reference
-                    if n.id in exclude:
-                        continue
-                    if ok(n.id):
-                        owner = n
-                        break
-            if owner is None:
-                raise PilosaError(f"no available node owns shard {shard}")
-            if owner.id == self.node.id:
+        for shard, ids in zip(shards, owners):
+            if me in ids and me not in exclude:
                 local.append(shard)
+                continue
+            for node_id in ids:  # placement order, like the reference
+                if node_id not in exclude and ok(node_id):
+                    remote.setdefault(node_id, []).append(shard)
+                    break
             else:
-                remote.setdefault(owner.id, []).append(shard)
+                raise PilosaError(f"no available node owns shard {shard}")
         return local, remote
 
     def _map_reduce(self, index: str, shards: List[int], c: Call, opt: ExecOptions, map_fn, reduce_fn):

@@ -1001,6 +1001,13 @@ class Handler:
             ],
             "quarantined_reads": getattr(executor, "quarantined_reads", 0),
         }
+        # Shard-list placement kept across queries (executor._shard_owners):
+        # a read query is a hit; a walk is a placement worked out shard by
+        # shard, once per shard list per topology and on every assignment
+        # while a rebalance is in flight.
+        if executor is not None:
+            out["executor"] = {"assign_hits": executor.assign_hits,
+                               "assign_walks": executor.assign_walks}
         # Ingest health (docs/ingest.md): un-snapshotted WAL bytes across
         # fragments, background-snapshot counters and queue depth, and how
         # many shard batches the import surface has applied/routed — the

@@ -496,7 +496,7 @@ class Server:
                 if hostport(host) != hostport(self.node.uri):
                     peer = normalize(host)
                     self.cluster.add_node(Node(id=peer, uri=peer))
-            self.cluster.nodes.sort(key=lambda n: n.id)
+            self.cluster.nodes = sorted(self.cluster.nodes, key=lambda n: n.id)
             # Re-apply persisted coordinator flags: a runtime promotion
             # (coordinator failover) must survive restart — the config only
             # knows the ORIGINAL role, so a promoted successor restarting
