@@ -36,8 +36,7 @@ class Holder:
         # Background snapshotter (storage/snapshotter.py): fragments whose
         # snapshot policy fires enqueue here so the write path never blocks
         # on snapshot I/O. Only persistent holders get one — pathless
-        # (in-memory) holders snapshot inline, keeping tests and benches
-        # synchronous.
+        # (in-memory) holders snapshot inline, keeping tests synchronous.
         self.snapshotter = None
         if path:
             from ..storage import StorageConfig

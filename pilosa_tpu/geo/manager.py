@@ -37,7 +37,7 @@ fence survives either side's crash:
     check_write  every external write lands here first. Followers
               refuse with StaleGeoEpochError (409) pointing at the
               leader; a leader tallies the accepting epoch
-              (write_epochs) — the bench's fencing evidence.
+              (write_epochs) — the chaos test's fencing evidence.
 
 Jax-free (pilint R2).
 """

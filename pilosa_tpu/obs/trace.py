@@ -482,7 +482,7 @@ class TraceRecorder:
         self.logger = logger
         self.clock = clock
         self._lock = threading.Lock()
-        # Seeded sampler: chaos/bench runs pin the seed so the sampled
+        # Seeded sampler: chaos runs pin the seed so the sampled
         # set replays bit-identically.
         self._rng = random.Random(seed)
         self._ring: deque = deque(maxlen=max(1, self.config.ring_size))

@@ -65,7 +65,7 @@ def global_mesh(limit: Optional[int] = None):
     over it and inserts ICI collectives within a host, DCN across hosts.
 
     `limit` restricts the mesh to the first N devices — single-process
-    only (the MULTICHIP bench's per-device-count scaling curve); a
+    only (the collective plane's `mesh_devices` override); a
     multi-process subset would break the process-contiguous slot layout
     the collective plane verifies."""
     import jax
@@ -162,5 +162,4 @@ def global_and_count(planes_a, planes_b) -> int:
 # placement and silently counted unowned slots as zeros. The production
 # collective plane is parallel/collective.py (placement follows jump-hash,
 # workers verify ownership, entry is barrier-guarded and seq-ordered). The
-# low-level helpers above remain for hand-assembled plane blocks (tests,
-# benchmarks).
+# low-level helpers above remain for hand-assembled plane blocks (tests).

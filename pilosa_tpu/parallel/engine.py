@@ -249,7 +249,7 @@ class ShardedQueryEngine:
         # interpret mode.
         self.platform = self.mesh.devices.flat[0].platform
         if config is None:
-            # No resolved config (library/test/bench use): honor the env
+            # No resolved config (library/test use): honor the env
             # spellings directly. When a Config DID resolve these knobs,
             # flags > env > TOML precedence already happened there —
             # re-reading env here would let a stray export silently beat
@@ -399,7 +399,7 @@ class ShardedQueryEngine:
             "fn_cache_entries": self._fn_budget,
         }
         # Observable cache behavior (hit rate / eviction pressure) for
-        # /debug/vars and the HBM-budget bench stanza.
+        # /debug/vars (the `engine_cache` group).
         self.counters = {
             "leaf_hits": 0, "leaf_misses": 0, "leaf_evictions": 0,
             "stack_hits": 0, "stack_misses": 0, "stack_evictions": 0,
@@ -441,9 +441,9 @@ class ShardedQueryEngine:
             # Delta-refresh accounting: delta hits refreshed a stale
             # resident tensor with a scattered update (delta_bytes of
             # host->device traffic) instead of a full host walk + re-upload
-            # (full_refresh_bytes counts those). The bench MIXED stanza's
-            # win condition is delta_bytes << full_refresh_bytes at equal
-            # correctness under mixed read/write traffic.
+            # (full_refresh_bytes counts those); tests/test_delta.py holds
+            # delta_bytes << full_refresh_bytes at equal correctness under
+            # mixed read/write traffic.
             "leaf_delta_hits": 0, "stack_delta_hits": 0,
             "delta_bytes": 0, "full_refresh_bytes": 0,
             # Tiered-storage accounting: an HBM miss answered by

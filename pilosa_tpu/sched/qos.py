@@ -61,8 +61,8 @@ class QosConfig:
     # Bucket capacity (ms of measured cost) at share 1.0: how much a
     # tenant may burst above its sustained rate.
     burst: float = 500.0
-    # Share multiplier for tenants with no explicit set_share() override:
-    # a tenant's effective rate/burst are rate*share and burst*share.
+    # Share multiplier: a tenant's effective rate/burst are rate*share
+    # and burst*share.
     default_tenant_share: float = 1.0
     # Interactive traffic sheds only past this hard cap: a dry tenant's
     # interactive queries keep admitting (queued behind in-budget
@@ -146,14 +146,6 @@ class TenantLedger:
         return self.config.rate > 0
 
     # ----------------------------------------------------------- buckets
-
-    def set_share(self, tenant: str, share: float) -> None:
-        """Override one tenant's share (its rate/burst multiplier)."""
-        if share <= 0:
-            raise ValueError("tenant share must be > 0")
-        now = self.clock()
-        with self._lock:
-            self._bucket_locked(tenant, now).share = share
 
     def _bucket_locked(self, tenant: str, now: float) -> _Bucket:
         # Must hold _lock. Fetch-and-refill, with recency eviction: the

@@ -701,10 +701,6 @@ class InternalClient:
         return json.loads(self._request(
             "POST", f"{_node_url(host)}/geo/demote", body))
 
-    def geo_status(self, host) -> dict:
-        return json.loads(self._request(
-            "GET", f"{_node_url(host)}/geo/status"))
-
     def translate_data(self, node, offset: int) -> bytes:
         url = f"{_node_url(node)}/internal/translate/data?offset={offset}"
         return self._request("GET", url)

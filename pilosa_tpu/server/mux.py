@@ -335,7 +335,7 @@ class _FrameIO:
                 self.sock.sendall(chunk)
                 # Counted only after the sendall that carried them
                 # succeeded — a failed flush must not inflate the wire
-                # counters the bench reads.
+                # counters /debug/vars' `transport` group reports.
                 if self.stats:
                     self.stats.bump("frames_sent", len(frames))
                     self.stats.bump("bytes_sent", len(chunk))

@@ -633,23 +633,6 @@ def _as_container(c) -> Container:
     return c if isinstance(c, Container) else Container(arr=np.asarray(c, dtype=np.uint16))
 
 
-# Pluggable container-store backend (the reference's Containers interface,
-# roaring.go:66-99). Default is a plain dict; the B+tree store
-# (btree_containers.BTreeContainers) can be swapped in globally — the
-# equivalent of the enterprise build-tag swap
-# `roaring.NewFileBitmap = b.NewBTreeBitmap` (enterprise/enterprise.go:31).
-_CONTAINER_FACTORY = dict
-
-
-def set_container_factory(factory) -> None:
-    global _CONTAINER_FACTORY
-    _CONTAINER_FACTORY = factory
-
-
-def get_container_factory():
-    return _CONTAINER_FACTORY
-
-
 from collections.abc import MutableMapping
 
 
@@ -691,7 +674,7 @@ class Bitmap:
 
     def __init__(self, values=None):
         # key (value >> 16) -> Container of low 16 bits
-        self.containers = _ContainerMap(_CONTAINER_FACTORY(), self._inval_keys)
+        self.containers = _ContainerMap({}, self._inval_keys)
         self.op_n = 0
         # Torn-tail recovery bookkeeping, set by from_buffer: byte length of
         # the last valid record boundary, and how many trailing bytes past

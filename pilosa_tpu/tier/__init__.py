@@ -56,7 +56,7 @@ class TierConfig:
 
     @classmethod
     def from_env(cls) -> "TierConfig":
-        """Env-only resolution for library/test/bench engines constructed
+        """Env-only resolution for library/test engines constructed
         without a Config (same spellings config.py maps for [tier])."""
         c = cls()
         for attr, name, cast in [

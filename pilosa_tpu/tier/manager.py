@@ -194,8 +194,8 @@ class TierManager:
                     self._demote_cv.notify_all()
 
     def drain(self, timeout: float = 10.0) -> bool:
-        """Block until every queued demotion has been captured (tests and
-        the bench use this to make demotion visible deterministically)."""
+        """Block until every queued demotion has been captured (tests use
+        this to make demotion visible deterministically)."""
         deadline = _time.monotonic() + timeout
         with self._demote_cv:
             while self._demote_queue or self._demote_busy:

@@ -640,8 +640,7 @@ def test_mesh_width_never_aliases_resident_planes(holder):
     """Review regression: the resident-cache key carries the mesh width.
     n_shards=4 pads to k=4 at BOTH mesh_devices=4 and =2, so without the
     width in the key the second count would resident-hit the 4-device
-    layout's array — a silently wrong device layout (and a fabricated
-    bench scaling curve)."""
+    layout's array — a silently wrong device layout."""
     _, exp = _plant(holder)
     backend, _ = _pod(holder)
     try:
@@ -728,6 +727,51 @@ def test_executor_falls_back_cleanly_and_counts_reason(holder):
         assert got[0] == len(exp[1] & exp[2])
         assert backend.fallbacks == {"epoch": 1}
     finally:
+        backend.close()
+        ex.close()
+
+
+def test_barrier_faults_cost_no_answer_through_the_executor(holder):
+    """The two tests above, joined at the executor: while every barrier
+    fails, each Count is answered rightly by the fan-out (two pay a
+    barrier timeout, then the open plane refuses the rest at once); when
+    the fault clears, the probe query is served by the plane again."""
+    from pilosa_tpu import failpoints
+    from pilosa_tpu.cluster.health import ResilienceConfig
+    from pilosa_tpu.executor import Executor
+    from pilosa_tpu.parallel.device_health import CollectivePlaneHealth
+
+    _, exp = _plant(holder)
+    backend, server = _pod(holder)
+    clock = [1000.0]
+    backend.health = CollectivePlaneHealth(
+        ResilienceConfig(collective_breaker_failures=2,
+                         collective_breaker_backoff=1.0).validate(),
+        clock=lambda: clock[0])
+    ex = Executor(holder, cluster=server.cluster, workers=0)
+    ex.collective = backend
+    server.executor = ex
+    pairs = [(a, b) for a in (1, 2, 3) for b in (1, 2, 3) if a != b]
+    queries = [f"Count(Intersect(Row(f={a}), Row(f={b})))" for a, b in pairs]
+    want = [len(exp[a] & exp[b]) for a, b in pairs]
+    try:
+        assert [ex.execute("ci", q)[0] for q in queries] == want
+        served = backend.counters["served_count"]
+        assert served == len(queries)
+        failpoints.configure("collective-barrier", "error")
+        assert [ex.execute("ci", q)[0] for q in queries] == want
+        assert backend.counters["served_count"] == served
+        assert backend.counters["barrier_timeouts"] == 2
+        assert backend.health.snapshot()["plane_opened"] == 1
+        assert backend.fallbacks == {"barrier-timeout": 2,
+                                     "breaker-open": len(queries) - 2}
+        failpoints.reset()
+        clock[0] += 10.0
+        assert ex.execute("ci", queries[0])[0] == want[0]
+        assert backend.counters["served_count"] == served + 1
+        assert backend.health.plane_state() == "closed"
+    finally:
+        failpoints.reset()
         backend.close()
         ex.close()
 

@@ -8,6 +8,8 @@ mutations, threshold exceeded), which must degrade to the full path, never
 to a partial delta.
 """
 
+import itertools
+
 import numpy as np
 import pytest
 
@@ -277,6 +279,59 @@ def test_delta_threshold_falls_back_to_full(holder):
     added = sum(fld.set_bit(0, c) for c in new_cols)
     assert engine.count("i", call, shards) == c0 + added
     assert engine.counters["leaf_delta_hits"] == 0
+
+
+def test_write_stream_moves_fewer_bytes_with_delta_on_than_off(holder):
+    """Batched Counts over a resident stack while a write stream dirties
+    its planes (2 shards x 8 rows, 4 batches, 4 single-bit sets before
+    each): the same traffic moves fewer bytes host->device with the delta
+    path on than with it forced off, and each side pays through its own
+    counter only. One monotone write stream across both runs: re-setting
+    a set bit bumps no generation, so a stream of its own per run would
+    hand the second run phantoms."""
+    n_shards, n_rows, batches, writes = 2, 8, 4, 4
+    fld = plant(holder, n_shards=n_shards, n_rows=n_rows, per_row=1024,
+                seed=17)
+    shards = list(range(n_shards))
+    calls = [parse(f"Intersect(Row(f={a}), Row(f={(a + off) % n_rows}))").calls[0]
+             for off in range(1, n_rows) for a in range(n_rows)]
+    stream = itertools.count(1)
+
+    def write_burst():
+        for i in itertools.islice(stream, writes):
+            assert fld.set_bit(i % n_rows, (i * 7919) % SHARD_WIDTH)
+
+    def run(config):
+        engine = ShardedQueryEngine(holder, config=config)
+        try:
+            np.asarray(engine.count_batch_async("i", calls, shards))
+            base = dict(engine.counters)
+            for _ in range(batches):
+                write_burst()
+                got = np.asarray(engine.count_batch_async("i", calls, shards))
+            moved = {k: engine.counters[k] - base[k] for k in (
+                "delta_bytes", "full_refresh_bytes", "stack_delta_hits")}
+            # The batch program pads its query count to a power of two.
+            return got[:len(calls)].tolist(), base["full_refresh_bytes"], moved
+        finally:
+            engine.close()
+
+    _, _, on = run(EngineConfig())
+    got, cold_bytes, off = run(EngineConfig(delta_max_fraction=0.0))
+    assert on["full_refresh_bytes"] == 0 and on["stack_delta_hits"] > 0
+    assert 0 < on["delta_bytes"] <= batches * writes * 64
+    assert off["delta_bytes"] == 0 and off["stack_delta_hits"] == 0
+    # A write to `f` stales every resident leaf of `f`: with no delta
+    # every batch walks and uploads all eight planes again, as the cold
+    # batch did.
+    assert cold_bytes >= n_rows * n_shards * WORDS_PER_ROW * 4
+    assert off["full_refresh_bytes"] == batches * cold_bytes
+    assert on["delta_bytes"] < off["full_refresh_bytes"]
+    fresh = ShardedQueryEngine(holder)
+    try:
+        assert got == fresh.count_batch("i", calls, shards).tolist()
+    finally:
+        fresh.close()
 
 
 def test_property_random_writes_delta_equals_full(holder):

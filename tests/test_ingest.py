@@ -274,6 +274,55 @@ def test_background_snapshot_folds_wal(tmp_path):
     h2.close()
 
 
+def test_n_small_batches_append_n_records_and_rewrite_nothing(tmp_path):
+    """24 column-local batches of 250 bits into a fragment whose file
+    holds 32 dense rows: each batch costs one appended record, the file
+    is never rewritten, and all 24 together append less than a fifth of
+    what ONE rewrite (the discipline before the bulk record) would have
+    written."""
+    from pilosa_tpu.storage.bitmap import Container
+
+    n_rows, n_batches, per_batch, batch_rows = 32, 24, 250, 8
+    n_containers = SHARD_WIDTH >> 16
+    h = holder_with_snapshotter(tmp_path, fsync="never")
+    fld = h.create_index("t").create_field("f")
+    frag = fld.create_view_if_not_exists("standard") \
+        .create_fragment_if_not_exists(0, broadcast=False)
+    rng = np.random.default_rng(29)
+    words = rng.integers(0, 1 << 64, size=(n_rows * n_containers, 1024),
+                         dtype=np.uint64)
+    counts = np.bitwise_count(words).sum(axis=1)
+    for ci in range(n_rows * n_containers):
+        frag.storage.containers[ci] = Container(bits=words[ci],
+                                                n=int(counts[ci]))
+    frag.snapshot()
+    base = os.path.getsize(frag.path)
+    assert base == frag.storage_bytes >= n_rows * n_containers * 8192
+    assert frag.op_n == 0 and frag.wal_bytes == 0
+
+    window = 1 << 17
+    for i in range(n_batches):
+        brows = np.repeat(np.arange(batch_rows, dtype=np.uint64),
+                          per_batch // batch_rows)
+        bcols = (rng.integers(0, window, brows.size, dtype=np.uint64)
+                 + np.uint64((i * window) % (SHARD_WIDTH - window + 1)))
+        fld.import_bits(brows, bcols)
+        assert frag.op_n == i + 1
+    stats = h.ingest_stats()
+    assert stats["snapshots_taken"] == 0 and stats["snapshots_deferred"] == 0
+    assert stats["wal_bytes"] == frag.wal_bytes > 0
+    assert frag.storage_bytes == base
+    assert os.path.getsize(frag.path) == base + frag.wal_bytes
+    assert 5 * frag.wal_bytes < base
+    want = [frag.row_count(r) for r in range(batch_rows)]
+    h.close()
+    h2 = Holder(str(tmp_path / "indexes")).open()
+    f2 = h2.fragment("t", "f", "standard", 0)
+    assert f2.op_n == n_batches  # replayed, not folded
+    assert [f2.row_count(r) for r in range(batch_rows)] == want
+    h2.close()
+
+
 def test_background_snapshot_does_not_block_writers_or_readers(tmp_path):
     """The acceptance gate: with the snapshot's write/fsync phase stalled
     via failpoint, a reader AND a writer (fragment-mutex holder) must

@@ -1,7 +1,10 @@
-"""Tests for URI, iterators, diagnostics, sysinfo, topology, holder cleaner,
+"""Tests for URI, diagnostics, sysinfo, topology, holder cleaner,
 stats, time quantum, and translate replication."""
 
+import glob
 import json
+import os
+import re
 import time
 from datetime import datetime
 
@@ -11,9 +14,7 @@ import pytest
 from pilosa_tpu import timeq
 from pilosa_tpu.cluster.node import Cluster, Node
 from pilosa_tpu.cluster.topology import HolderCleaner, Topology
-from pilosa_tpu.core.fragment import Fragment
 from pilosa_tpu.diagnostics import DiagnosticsCollector
-from pilosa_tpu.iterator import BufIterator, fragment_iterator, limit_iterator, slice_iterator
 from pilosa_tpu.stats import InMemoryStatsClient, MultiStatsClient, NopStatsClient, Timer
 from pilosa_tpu.sysinfo import system_info
 from pilosa_tpu.translate import TranslateStore
@@ -28,31 +29,6 @@ def test_uri_parse():
     assert URI.parse("localhost:1").normalize() == "http://localhost:1"
     with pytest.raises(URIError):
         URI.parse("")
-
-
-def test_fragment_iterator(tmp_path):
-    f = Fragment(str(tmp_path / "frag"), "i", "f", "standard", 2)
-    f.open()
-    from pilosa_tpu.constants import SHARD_WIDTH
-
-    base = 2 * SHARD_WIDTH
-    f.set_bit(0, base + 5)
-    f.set_bit(3, base + 1)
-    pairs = list(fragment_iterator(f))
-    assert pairs == [(0, base + 5), (3, base + 1)]
-    assert list(fragment_iterator(f, seek_row=1)) == [(3, base + 1)]
-    f.close()
-
-
-def test_buf_slice_limit_iterators():
-    it = BufIterator(slice_iterator([2, 1, 1], [5, 9, 3]))
-    assert it.peek() == (1, 3)
-    assert it.next() == (1, 3)
-    it.unread((1, 3))
-    assert it.next() == (1, 3)
-    assert list(limit_iterator(slice_iterator([0, 1, 2], [1, 2, 3]), 2, 100)) == [
-        (0, 1), (1, 2),
-    ]
 
 
 def test_time_quantum_views():
@@ -385,3 +361,49 @@ def test_translate_store_truncated_tail_recovery(tmp_path):
     assert ts3.translate_column_to_string("i", 3) == "c"
     assert ts3.translate_columns_to_uint64("i", ["c"]) == [3]
     ts3.close()
+
+
+# ------------------------------------------------ the documents' pointers
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_documents_cite_only_tests_that_exist():
+    """The documents name tier-1 tests as their subsystems' acceptance
+    proofs: every `tests/test_x.py` they cite is a file, and every
+    `test_name` and `TestClass` in backticks is defined in one."""
+    defined = set()
+    for path in glob.glob(os.path.join(REPO, "tests", "test_*.py")) \
+            + glob.glob(os.path.join(REPO, "benchmark", "tests", "test_*.py")):
+        with open(path) as f:
+            defined.update(re.findall(
+                r"^\s*(?:def|class)\s+(test_\w+|Test\w+)", f.read(), re.M))
+    missing = []
+    for doc in [os.path.join(REPO, "README.md")] \
+            + sorted(glob.glob(os.path.join(REPO, "docs", "*.md"))):
+        with open(doc) as f:
+            text = f.read()
+        for rel in set(re.findall(r"(?<![\w/])tests/(?:test_|conftest)\w*\.py\b", text)):
+            if not os.path.isfile(os.path.join(REPO, rel)):
+                missing.append((os.path.basename(doc), rel))
+        for cite in re.findall(r"`([\w/.:]*\b(?:test_|Test)\w+)`", text):
+            for name in cite.split("::"):
+                if re.fullmatch(r"test_\w+|Test[A-Z]\w+", name) \
+                        and name not in defined:
+                    missing.append((os.path.basename(doc), name))
+    assert missing == []
+
+
+def test_readme_layout_names_only_paths_that_exist():
+    with open(os.path.join(REPO, "README.md")) as f:
+        block = f.read().split("## Layout", 1)[1].split("```")[1]
+    missing = []
+    for line in block.strip("\n").splitlines():
+        if not line[:14].strip():
+            continue  # a continuation of the entry above
+        name = line.split()[0]
+        path = os.path.join(REPO, "pilosa_tpu", name) \
+            if line.startswith("  ") else os.path.join(REPO, name)
+        if not os.path.exists(path):
+            missing.append(name)
+    assert missing == []

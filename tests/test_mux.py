@@ -1043,3 +1043,80 @@ def test_chaos_smoke_over_mux(tmp_path):
                    for s in servers)
     finally:
         _close_cluster(servers)
+
+
+def _index_with_co_owned_shard(s0, h0):
+    """Create index `t` with field `f`; return a client, a shard the
+    coordinator owns beside one peer, and that peer (only a local apply
+    captures the op payloads a hint carries)."""
+    c = InternalClient(timeout=10.0)
+    c.ensure_index(h0, "t")
+    c.ensure_field(h0, "t", "f")
+    time.sleep(0.05)
+    for sh in range(48):
+        owners = s0.cluster.shard_nodes("t", sh)
+        if any(o.id == s0.node.id for o in owners):
+            return c, sh, next(o for o in owners if o.id != s0.node.id)
+    raise AssertionError("placement gave the coordinator no shard")
+
+
+@pytest.mark.chaos
+def test_hints_drain_over_mux_and_replicas_agree(tmp_path):
+    """A replica's link drops while 12 writes are acknowledged; the
+    misses become hints, the hints are delivered over pmux once the link
+    heals (not one HTTP request from the coordinator), and the replica
+    then holds every acknowledged bit."""
+    servers, hosts = _mk_cluster(tmp_path, enabled_nodes={0, 1, 2})
+    try:
+        s0, h0 = servers[0], hosts[0]
+        c, shard, victim = _index_with_co_owned_shard(s0, h0)
+        cols = [shard * SHARD_WIDTH + 1000 + i for i in range(12)]
+        failpoints.seed(11)
+        failpoints.configure(f"client-send@{victim.uri}", "drop")
+        for col in cols:
+            assert c.query(h0, "t", f"Set({col}, f=0)")["results"] == [True]
+        assert s0.hints.pending(victim.id) > 0
+        failpoints.reset()
+        for _ in range(200):
+            for s in servers:
+                s._monitor_members()
+            s0.hints.deliver_once(s0.cluster, s0.client)
+            if s0.hints.pending(victim.id) == 0:
+                break
+            time.sleep(0.05)
+        assert s0.hints.pending(victim.id) == 0
+        on_victim = s0.client.query_node(
+            victim, "t", "Count(Row(f=0))", shards=[shard])[0]
+        assert on_victim == len(cols)
+        assert c.query(h0, "t", "Count(Row(f=0))")["results"] == [len(cols)]
+        snap = s0.transport_stats.snapshot()
+        assert snap["requests_mux"] > 0
+        assert snap["requests_http"] == 0, "a hop or a hint rode HTTP"
+    finally:
+        _close_cluster(servers)
+
+
+def test_shard_retrieval_bytes_are_the_same_on_both_transports(tmp_path):
+    """The migration stream's whole-shard retrieval answers the same
+    bytes over pmux and over HTTP."""
+    servers, hosts = _mk_cluster(tmp_path, enabled_nodes={0, 1, 2})
+    try:
+        s0, h0 = servers[0], hosts[0]
+        c, shard, peer = _index_with_co_owned_shard(s0, h0)
+        for i in range(12):
+            c.query(h0, "t", f"Set({shard * SHARD_WIDTH + 1000 + i}, f=0)")
+        before = s0.transport_stats.snapshot()
+        over_mux = s0.client.retrieve_shard_from_uri(
+            peer.uri, "t", "f", "standard", shard)
+        mid = s0.transport_stats.snapshot()
+        assert mid["requests_mux"] == before["requests_mux"] + 1
+        assert mid["requests_http"] == before["requests_http"]
+        mux, s0.client.mux = s0.client.mux, None
+        try:
+            over_http = s0.client.retrieve_shard_from_uri(
+                peer.uri, "t", "f", "standard", shard)
+        finally:
+            s0.client.mux = mux
+        assert len(over_mux) > 0 and over_mux == over_http
+    finally:
+        _close_cluster(servers)

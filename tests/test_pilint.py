@@ -561,7 +561,7 @@ class TestFailpointHygiene:
         vs = lint("""
             def f():
                 fire("not-a-real-point")
-        """, path="bench.py", env=self._env(), rules=["R6"])
+        """, path="scripts/tool.py", env=self._env(), rules=["R6"])
         assert vs == []
 
     def test_orphan_spec_in_test_is_violation(self):
@@ -723,7 +723,7 @@ class TestSpanHygiene:
     def test_outside_pilosa_tpu_not_checked(self):
         vs = lint("""
             span("anything-goes")
-        """, path="bench.py", env=self._env(), rules=["R7"])
+        """, path="scripts/tool.py", env=self._env(), rules=["R7"])
         assert vs == []
 
     def test_orphan_asserted_span_is_violation(self):
@@ -1476,7 +1476,7 @@ class TestNoneGuardedStats:
     def test_outside_pilosa_tpu_not_checked(self):
         vs = lint("""
             stats.count("X", 1)
-        """, path="bench.py", rules=["R10"])
+        """, path="scripts/tool.py", rules=["R10"])
         assert vs == []
 
     def test_annotation_suppresses(self):

@@ -80,17 +80,14 @@ def test_refill_and_burst_cap(fake_clock):
 
 
 def test_share_scales_rate_and_cap(fake_clock):
-    led = ledger(fake_clock)
-    led.set_share("gold", 2.0)
-    for _ in range(4):
-        led.charge_estimate("gold")  # 200 charged
+    led = ledger(fake_clock, default_tenant_share=2.0)
+    for _ in range(6):
+        led.charge_estimate("gold")  # 300 charged of burst x share = 200
     assert led.balance("gold") == pytest.approx(-100.0)
     fake_clock.advance(5.0)  # refills 10 * 2.0 * 5 = 100
     assert led.balance("gold") == pytest.approx(0.0)
     fake_clock.advance(3600.0)
     assert led.balance("gold") == pytest.approx(200.0)  # burst x share
-    with pytest.raises(ValueError):
-        led.set_share("gold", 0.0)
 
 
 # ------------------------------------------------------------ shed ordering
@@ -401,3 +398,48 @@ def test_http_tenant_header_end_to_end(qos_server):
     assert dv["qos"]["enabled"] is True
     assert dv["qos"]["shed_batch"] >= 1
     assert "autoscale" in dv  # controller group rides along, even idle
+
+
+def test_http_shed_tenant_leaves_the_other_tenant_served(tmp_path):
+    """Noisy-neighbour isolation over HTTP: once one tenant is past its
+    hard cap and every request of its is a typed 429, another tenant's
+    queries and imports are answered, and never with a 429."""
+    from pilosa_tpu.server.client import InternalClient
+    from pilosa_tpu.server.server import Server
+
+    # A minute of burst: the quiet tenant's own cold compile cannot dry it.
+    s = Server(
+        data_dir=str(tmp_path / "node0"), cache_flush_interval=0,
+        qos_config=QosConfig(rate=0.001, burst=60_000.0, interactive_cap=2.0,
+                             estimate_ms=5.0),
+    )
+    s.open()
+    try:
+        client = InternalClient()
+        host = f"localhost:{s.port}"
+        client.create_index(host, "i")
+        client.create_field(host, "i", "f")
+        client.query(host, "i", "Set(1, f=1)")
+        s.qos.settle("noisy", 0.0, 200_000.0)  # one 200 s query: past the cap
+        noisy = {"X-Pilosa-Tenant": "noisy"}
+        quiet = {"X-Pilosa-Tenant": "quiet"}
+        statuses = []
+        for _ in range(3):
+            status, headers, _ = _post(s.port, "/index/i/query",
+                                       "Count(Row(f=1))", noisy)
+            assert (status, headers.get("X-Pilosa-Tenant")) == (429, "noisy")
+            assert float(headers.get("Retry-After")) > 0
+            status, _, body = _post(s.port, "/index/i/query",
+                                    "Count(Row(f=1))", quiet)
+            statuses.append(status)
+            assert json.loads(body)["results"][0] == 1
+        payload = json.dumps({"shard": 0, "rowIDs": [2], "columnIDs": [9]})
+        status, _, _ = _post(s.port, "/index/i/field/f/import", payload,
+                             {"Content-Type": "application/json", **quiet})
+        statuses.append(status)
+        assert statuses == [200, 200, 200, 200]
+        snap = s.qos.snapshot()
+        assert snap["shed_interactive"] == 3 and snap["shed_batch"] == 0
+        assert snap["top"]["quiet"]["queries"] == 4
+    finally:
+        s.close()

@@ -288,6 +288,71 @@ class TestHostTierRoundTrip:
         finally:
             engine.close()
 
+    @pytest.mark.parametrize("tiered", [True, False],
+                             ids=["tiered", "drop_regather"])
+    def test_batched_sweeps_over_three_times_the_budget(
+            self, holder, monkeypatch, tiered):
+        """18 planes under a leaf and a stack budget of 6, counted in
+        rotating batches of 6 with the memo off (a repeat would be a dict
+        read): once the tier is warm no sweep walks a live container
+        again, nor does a write to every seventh plane; without the tier
+        every touch of a sweep does."""
+        monkeypatch.setenv("PILOSA_MEMO_ENTRIES", "0")
+        n_rows, n_shards, sweeps, batch = 18, 2, 4, 6
+        fld, expected = plant(holder, n_shards, n_rows, per_row=512, seed=17)
+        shards = tuple(range(n_shards))
+        calls = {r: parse(f"Row(f={r})").calls[0] for r in range(n_rows)}
+        budget = n_rows * n_shards * WORDS_PER_ROW * 4 // 3
+        engine = ShardedQueryEngine(
+            holder,
+            # One device: a plane is its shards' words and no padding.
+            config=EngineConfig(mesh_devices=1, leaf_cache_bytes=budget,
+                                stack_cache_bytes=budget),
+            tier_config=TierConfig(host_bytes=(1 << 28) if tiered else 0,
+                                   disk_bytes=0, prefetch_interval=0))
+
+        def sweep_batched(s):
+            # Same planes, another batch composition each sweep, so that
+            # no stack key repeats.
+            rot = [(r + s) % n_rows for r in range(n_rows)]
+            for g in range(0, n_rows, batch):
+                grp = rot[g:g + batch]
+                got = engine.count_batch(
+                    "i", [calls[r] for r in grp], shards).tolist()
+                assert got == [len(expected[r]) for r in grp]
+            if tiered:
+                engine.tier.drain()
+
+        try:
+            sweep_batched(sweeps)
+            base = dict(engine.counters)
+            for s in range(sweeps):
+                sweep_batched(s)
+            moved = {k: engine.counters[k] - base[k]
+                     for k in ("leaf_misses", "leaf_tier_hits", "leaf_hits")}
+            # Every touch of a plane is a hit, a promotion or a walk.
+            assert sum(moved.values()) == sweeps * n_rows
+            if not tiered:
+                assert engine.tier is None
+                assert moved["leaf_misses"] > 0 and moved["leaf_tier_hits"] == 0
+                return
+            assert moved["leaf_misses"] == 0
+            assert moved["leaf_tier_hits"] > 0
+            for r in range(0, n_rows, 7):
+                col = r * 31 % SHARD_WIDTH
+                if fld.set_bit(r, col):
+                    expected[r].add(col)
+            engine.tier.drain()
+            pre = dict(engine.counters)
+            assert sweep(engine, "i", calls, shards, range(n_rows)) == [
+                len(expected[r]) for r in range(n_rows)]
+            assert engine.counters["leaf_misses"] == pre["leaf_misses"]
+            # Rows 7 and 14 were demoted when written: their journals
+            # fold as they are promoted.
+            assert engine.tier.snapshot()["delta_folds"] > 0
+        finally:
+            engine.close()
+
     def test_inclusive_host_tier_skips_unchanged_recapture(self, holder):
         """Steady-state read churn: evict → promote → evict again with no
         writes in between must not re-serialize the plane."""

@@ -1,8 +1,8 @@
 #!/usr/bin/env bash
-# Single-entry gate: the three checks a change must pass, in cost order,
+# Single-entry gate: the two checks a change must pass, in cost order,
 # fail-fast. Run from the repo root:
 #
-#   tools/check.sh            # pilint full tree -> tier-1 pytest -> bench smoke
+#   tools/check.sh            # pilint full tree -> tier-1 pytest
 #   tools/check.sh --changed  # pilint incremental (vs HEAD) first instead
 #
 # Each stage's exit code stops the gate; the summary line at the end is
@@ -48,7 +48,4 @@ if [ "$rc" -ne 0 ]; then
     fi
 fi
 
-stage "bench smoke (BENCH_SMOKE=1)"
-BENCH_SMOKE=1 JAX_PLATFORMS=cpu python bench.py || fail "bench"
-
-echo "check.sh: OK (pilint + tier-1 + bench smoke)"
+echo "check.sh: OK (pilint + tier-1)"
