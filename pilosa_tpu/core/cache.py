@@ -10,7 +10,9 @@ from __future__ import annotations
 import time
 from collections import OrderedDict
 from dataclasses import dataclass
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
+
+import numpy as np
 
 from ..constants import DEFAULT_CACHE_SIZE
 
@@ -47,6 +49,19 @@ def sort_pairs(pairs: List[Pair]) -> List[Pair]:
     return sorted(pairs, key=lambda p: (-p.count, p.id))
 
 
+RankArrays = Tuple[np.ndarray, np.ndarray]  # (ids, counts), int64, rank order
+
+
+def rank_arrays(ranked: List[Pair]) -> RankArrays:
+    """A ranking as two int64 arrays, for the executor's batched TopN
+    runners, which work on the shard axis and read no Pair."""
+    return (np.fromiter((p.id for p in ranked), np.int64, len(ranked)),
+            np.fromiter((p.count for p in ranked), np.int64, len(ranked)))
+
+
+_NO_RANKING: RankArrays = rank_arrays([])
+
+
 class RankCache:
     """Keeps the top `max_entries` (row, count) pairs, sorted lazily."""
 
@@ -54,6 +69,7 @@ class RankCache:
         self.max_entries = max_entries
         self.entries: Dict[int, int] = {}
         self._sorted: Optional[List[Pair]] = None
+        self._arrays: Optional[RankArrays] = None  # _sorted, as arrays
         self._last_invalidate = 0.0
 
     def add(self, row_id: int, n: int) -> None:
@@ -61,7 +77,7 @@ class RankCache:
             self.entries.pop(row_id, None)
         else:
             self.entries[row_id] = n
-        self._sorted = None
+        self._sorted = self._arrays = None
 
     bulk_add = add
 
@@ -89,6 +105,7 @@ class RankCache:
         if len(ranked) > self.max_entries:
             ranked = ranked[: self.max_entries]
             self.entries = {p.id: p.count for p in ranked}
+        self._arrays = rank_arrays(ranked)
         self._sorted = ranked
         self._last_invalidate = now
 
@@ -97,9 +114,19 @@ class RankCache:
             self.invalidate(force=True)
         return list(self._sorted or [])
 
+    def top_arrays(self) -> RankArrays:
+        """top() as (ids, counts) arrays, shared and not to be written.
+        Lock-free like top(): a reader racing a writer sees the ranking
+        from before the write, or rebuilds."""
+        arrays = self._arrays
+        if arrays is None:
+            self.invalidate(force=True)
+            arrays = self._arrays
+        return arrays if arrays is not None else _NO_RANKING
+
     def clear(self) -> None:
         self.entries.clear()
-        self._sorted = None
+        self._sorted = self._arrays = None
 
 
 class LRUCache:
@@ -138,6 +165,9 @@ class LRUCache:
             [Pair(id=i, count=c) for i, c in list(self.entries.items())]
         )
 
+    def top_arrays(self) -> RankArrays:
+        return rank_arrays(self.top())
+
     def clear(self) -> None:
         self.entries.clear()
 
@@ -162,6 +192,9 @@ class NopCache:
 
     def top(self) -> List[Pair]:
         return []
+
+    def top_arrays(self) -> RankArrays:
+        return _NO_RANKING
 
     def clear(self) -> None:
         pass

@@ -883,40 +883,31 @@ class Fragment:
 
     # ----------------------------------------------------------------- TopN
 
-    def top(self, opt: TopOptions, inter_counts: Optional[Dict[int, int]] = None,
-            src_count: Optional[int] = None) -> List[Pair]:
-        """TopN over this fragment. `inter_counts` (row -> |row ∩ src| for
-        THIS shard) lets the executor batch the device popcounts for many
-        shards into one program and replay the heap here without any
-        per-fragment device work (heap semantics: fragment.go:899-990).
-        `src_count` (|src| for THIS shard) comes from the same batched
-        program so tanimoto TopN (fragment.go:1008-1027) rides the batched
-        path too — without it tanimoto needs opt.src materialized."""
+    def top(self, opt: TopOptions) -> List[Pair]:
+        """TopN over this fragment: the per-shard rung, and the reference
+        the executor's batched runners (which replay the same selection on
+        (rows, shards) arrays, executor._replay_topn) are held to."""
         pairs = self._top_pairs(list(opt.row_ids))
         n = 0 if opt.row_ids else opt.n
-        has_src = opt.src is not None or inter_counts is not None
+        has_src = opt.src is not None
 
         filters = set(opt.filter_values) if opt.filter_name and opt.filter_values else None
 
         tanimoto = 0
         min_tan = max_tan = 0.0
+        src_count = 0
         if opt.tanimoto_threshold > 0 and opt.src is not None:
             src_count = opt.src.count()
-        if opt.tanimoto_threshold > 0 and src_count is not None:
             tanimoto = opt.tanimoto_threshold
             min_tan = src_count * tanimoto / 100.0
             max_tan = src_count * 100.0 / tanimoto
-        if src_count is None:
-            src_count = 0
 
         # Pre-filter candidates (cheap host checks), then batch-count the
         # survivors' intersections with src on device.
         candidates = self._filter_candidates(pairs, opt, min_tan, max_tan, filters)
 
         inter: Dict[int, int] = {}
-        if inter_counts is not None:
-            inter = {int(r): int(c) for r, c in inter_counts.items()}
-        elif opt.src is not None and candidates:
+        if opt.src is not None and candidates:
             src_plane = self._filter_plane(opt.src)
             for i in range(0, len(candidates), TOPN_BATCH):
                 chunk = candidates[i : i + TOPN_BATCH]
@@ -984,9 +975,7 @@ class Fragment:
                 # though the heap-full early-exit in top() still consults
                 # it, exactly as fragment.go:976-981 does. Bounds pruning:
                 # cnt outside [min_tan, max_tan] cannot reach the
-                # coefficient threshold. The bounds need src_count, so
-                # top_candidates (bounds 0/0, src not yet counted) prunes
-                # nothing here and top() re-filters with real bounds.
+                # coefficient threshold.
                 if (min_tan > 0 or max_tan > 0) and (
                     cnt <= min_tan or cnt >= max_tan
                 ):
@@ -1001,13 +990,12 @@ class Fragment:
             candidates.append((row_id, cnt))
         return candidates
 
-    def top_candidates(self, opt: TopOptions) -> List[Tuple[int, int]]:
-        """Pre-filtered (row_id, cache_count) candidates for a TopN pass —
-        the host-side half of top(), exposed so the executor can batch the
-        device half (src intersections) across many fragments."""
-        pairs = self._top_pairs(list(opt.row_ids))
-        filters = set(opt.filter_values) if opt.filter_name and opt.filter_values else None
-        return self._filter_candidates(pairs, opt, 0.0, 0.0, filters)
+    def top_arrays(self):
+        """This shard's ranking as (ids, counts) arrays in rank order, for
+        the executor's batched TopN: what _top_pairs([]) gives top(), with
+        one throttle check, no Pair and no lock."""
+        self.cache.invalidate()
+        return self.cache.top_arrays()
 
     def _top_pairs(self, row_ids: List[int]) -> List[Pair]:
         if self.cache_type == CACHE_TYPE_NONE and not row_ids:

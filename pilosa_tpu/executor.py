@@ -15,9 +15,11 @@ from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence
 
+import numpy as np
+
 from .constants import MAX_WRITES_PER_REQUEST, SHARD_WIDTH, VIEW_BSI_GROUP_PREFIX, VIEW_STANDARD
 from .core.cache import Pair, add_pairs, sort_pairs
-from .core.fragment import TopOptions
+from .core.fragment import Fragment, TopOptions
 from .core.holder import Holder
 from .core.row import Row
 from .errors import (
@@ -57,6 +59,69 @@ def _topn_chunk(n_shards: int) -> int:
 
     budget = int(os.environ.get("PILOSA_TOPN_CHUNK_BYTES", 2 << 30))
     return max(1, min(512, budget // max(1, n_shards * WORDS_PER_ROW * 4)))
+
+
+def _rank_matrix(rankings):
+    """Per-shard rankings (Fragment.top_arrays: ids and cache counts in
+    rank order) laid side by side as two (shards, ranks) int64 arrays. A
+    shard that ranks fewer rows is padded with count 0, which no
+    candidate rule lets through."""
+    width = max((len(ids) for ids, _ in rankings), default=0)
+    shape = (len(rankings), width)
+    if not width:
+        return np.zeros(shape, np.int64), np.zeros(shape, np.int64)
+    pad = np.zeros(width, np.int64)
+    ids_parts, cnt_parts = [], []
+    for ids, cnt in rankings:
+        ids_parts.append(ids)
+        cnt_parts.append(cnt)
+        if len(ids) < width:
+            ids_parts.append(pad[len(ids):])
+            cnt_parts.append(pad[len(ids):])
+    return (np.concatenate(ids_parts).reshape(shape),
+            np.concatenate(cnt_parts).reshape(shape))
+
+
+def _tanimoto_passes(count, cnt, src, tanimoto):
+    """The reference's coefficient test (fragment.go:1008-1027) in float64,
+    cell by cell as Fragment.top computes it: ceil(count * 100.0 /
+    (cnt + src - count)) > tanimoto. Cells with nothing to divide by
+    (count, cnt and src all 0) fail it, as they fail count > 0."""
+    with np.errstate(divide="ignore", invalid="ignore"):
+        return np.ceil(count * 100.0 / (cnt + src - count)) > tanimoto
+
+
+def _replay_topn(cnt, count, cand, src, n, min_threshold, tanimoto):
+    """Fragment.top's heap selection (fragment.go:899-990) for every shard
+    at once. `cnt` (cache counts), `count` (intersections with src) and
+    `cand` (which cells are candidates) are (shards, ranks) in rank order,
+    `src` is (shards,). Returns the mask of accepted cells; what `count`
+    reads where `cand` is false is never looked at.
+
+    The heap never evicts, and once it holds `n` every later push is at
+    least its minimum, so the threshold a shard applies after the fill is
+    ONE number: the smallest of the first `n` accepted counts. Cache
+    counts fall along the rank axis, so "stop at the first cnt <
+    threshold" is `cnt >= threshold` cell by cell."""
+    if tanimoto:
+        src = src[:, None]
+        # Bounds pruning (Fragment._filter_candidates), now that src is
+        # counted: cnt outside them cannot reach the coefficient.
+        cand = cand & ~((src > 0) & ((cnt <= src * tanimoto / 100.0)
+                                     | (cnt >= src * 100.0 / tanimoto)))
+        fill = cand & (count > 0) & _tanimoto_passes(count, cnt, src, tanimoto)
+    else:
+        fill = cand & (count >= min_threshold)
+    if n == 0:
+        return fill
+    filling = np.cumsum(fill, axis=1) - fill < n  # accepted so far < n
+    fill &= filling
+    top = np.iinfo(np.int64).max
+    threshold = np.where(fill, count, top).min(axis=1, initial=top)[:, None]
+    after = cand & ~filling & (cnt >= threshold) & (count >= threshold)
+    if tanimoto:  # else every accepted count is min_threshold or more
+        after &= threshold >= min_threshold
+    return fill | after
 
 
 # Shard lists whose owners Executor._shard_owners keeps at once (an entry
@@ -231,6 +296,11 @@ class Executor:
         self._owners_mu = threading.Lock()
         self.assign_hits = 0
         self.assign_walks = 0
+        # Batched TopN runner calls answered on (rows, shards) arrays, and
+        # shards a batched runner handed one by one to the per-shard rung
+        # (_execute_topn_shards): plain bumps, no lock, the same group.
+        self.topn_array_walks = 0
+        self.topn_shard_replays = 0
         # How long a write caught in a live-rebalance cutover window
         # (ShardMovedError locally, 409 from a frozen remote owner) keeps
         # re-routing while the commit broadcast lands, before surfacing a
@@ -1404,12 +1474,26 @@ class Executor:
         if tanimoto > 100:
             raise QueryError("Tanimoto Threshold is from 1 to 100 only")
         src_call = c.children[0] if c.children else None
+        field_name = c.args.get("_field") or DEFAULT_FIELD
+        thr = max(c.uint_arg("threshold")[0], DEFAULT_MIN_THRESHOLD)
+        attr_name = c.args.get("attrName", "")
+        attr_values = set(c.args.get("attrValues") or [])
+
+        def attr_rows(rows: List[int]) -> List[int]:
+            """The rows the attr filter lets through: asked once per row,
+            not once per row per shard (a field has one row attr store)."""
+            if not (attr_name and attr_values):
+                return rows
+            fld = self.holder.field(index, field_name)
+            store = fld.row_attr_store if fld else None
+            return [r for r in rows if Fragment.row_attrs_match(
+                store, r, attr_name, attr_values)]
 
         if (
             ids
-            and not c.args.get("attrName")
+            and not attr_name
             and not tanimoto
-            and max(c.uint_arg("threshold")[0], DEFAULT_MIN_THRESHOLD) <= 1
+            and thr <= 1
             and (src_call is None or self.engine.supports(src_call, index))
             and self._collective_ok(index, shards, opt)
         ):
@@ -1419,7 +1503,6 @@ class Executor:
             # need per-shard counts, fragment.go:899-990).
             from .parallel.collective import CollectiveUnavailable
 
-            field_name = c.args.get("_field") or DEFAULT_FIELD
             try:
                 pairs: List[Pair] = []
                 CHUNK = _topn_chunk(len(shards))  # bounds the (R, S, W) global stack
@@ -1452,26 +1535,11 @@ class Executor:
             # already produces), and attr-filter semantics (a host-side
             # per-row check against the field's row attr store,
             # fragment.go:922-934 — filtered rows never join the program).
-            field_name = c.args.get("_field") or DEFAULT_FIELD
-            thr = max(c.uint_arg("threshold")[0], DEFAULT_MIN_THRESHOLD)
-            attr_name = c.args.get("attrName", "")
-            attr_values = set(c.args.get("attrValues") or [])
 
             def local_runner(local_shards):
-                import math
-
-                run_ids = ids
-                if attr_name and attr_values:
-                    from .core.fragment import Fragment
-
-                    fld = self.holder.field(index, field_name)
-                    store = fld.row_attr_store if fld else None
-                    run_ids = [
-                        r for r in ids
-                        if Fragment.row_attrs_match(store, r, attr_name, attr_values)
-                    ]
-                    if not run_ids:
-                        return []
+                run_ids = attr_rows(ids)
+                if not run_ids:
+                    return []
                 # Row (cache) counts only gate tanimoto and thresholds > 1:
                 # at thr<=1 the count>0 check below subsumes them, so the
                 # common phase-2 skips the candidate-plane popcount pass
@@ -1481,25 +1549,23 @@ class Executor:
                     index, field_name, run_ids, local_shards, src_call,
                     need_rc,
                 )
-                pairs: Dict[int, int] = {}
-                for ri, row_id in enumerate(run_ids):
-                    for si in range(len(local_shards)):
-                        # inter is never None here: this branch requires a
-                        # supported src_call.
-                        count = int(inter[ri, si])
-                        cnt = int(row_counts[ri, si]) if need_rc else count
-                        if cnt <= 0 or count == 0:
-                            continue
-                        if tanimoto:
-                            tan = math.ceil(
-                                count * 100.0 / (cnt + int(src_counts[si]) - count)
-                            )
-                            if tan <= tanimoto:
-                                continue
-                        elif cnt < thr or count < thr:
-                            continue
-                        pairs[row_id] = pairs.get(row_id, 0) + count
-                return [Pair(id=r, count=n) for r, n in pairs.items()]
+                # inter is never None here: this branch requires a
+                # supported src_call. One (rows, shards) mask, one sum.
+                count = np.asarray(inter, np.int64)
+                cnt = np.asarray(row_counts, np.int64) if need_rc else count
+                keep = (cnt > 0) & (count > 0)
+                if tanimoto:
+                    keep &= _tanimoto_passes(
+                        count, cnt, np.asarray(src_counts, np.int64)[None, :],
+                        tanimoto)
+                else:
+                    keep &= (cnt >= thr) & (count >= thr)
+                totals = np.where(keep, count, 0).sum(axis=1).tolist()
+                # add_pairs: an id named twice counts twice, as it does on
+                # the per-shard rung.
+                return add_pairs([], [
+                    Pair(id=r, count=t) for r, t in zip(run_ids, totals) if t
+                ])
 
         elif (
             src_call is not None
@@ -1511,44 +1577,35 @@ class Executor:
             # UNION of candidates across all local shards run as ONE device
             # program — the per-fragment fallback pays a device round trip
             # per plane chunk per shard.
-            # Heap semantics stay exact: Fragment.top replays them from the
-            # precomputed per-shard counts (fragment.go:899-990). Tanimoto
+            # Heap semantics stay exact: _replay_topn runs Fragment.top's
+            # selection (fragment.go:899-990) over the shard axis. Tanimoto
             # (the ChEMBL workload, docs/examples.md:321-328) and attr
             # filters ride this path too: the coefficient needs only the
             # per-shard src popcount the same program produces, and attr
-            # filtering is a host-side candidate check.
-            field_name = c.args.get("_field") or DEFAULT_FIELD
+            # filtering is a host-side candidate check, once per row.
+            # The host half visits no (row, shard) cell in a statement of
+            # its own: 74 ms a TopN at 256 shards when it did (PERF.md,
+            # PR 34).
             n_arg, _ = c.uint_arg("n")
-            thr = max(c.uint_arg("threshold")[0], DEFAULT_MIN_THRESHOLD)
-            topn_opt = TopOptions(
-                n=n_arg,
-                min_threshold=thr,
-                filter_name=c.args.get("attrName", ""),
-                filter_values=c.args.get("attrValues") or [],
-                tanimoto_threshold=tanimoto,
-            )
 
             def local_runner(local_shards):
-                frags = []
-                union: List[int] = []
-                seen = set()
+                shard_list, rankings = [], []
                 for s in local_shards:
                     frag = self._fragment(index, field_name, VIEW_STANDARD, s)
-                    if frag is None:
-                        continue
-                    cands = frag.top_candidates(topn_opt)
-                    frags.append((frag, cands))
-                    for r, _ in cands:
-                        if r not in seen:
-                            seen.add(r)
-                            union.append(r)
-                if not frags or not union:
+                    if frag is not None:
+                        shard_list.append(s)
+                        rankings.append(frag.top_arrays())
+                rank_ids, rank_cnt = _rank_matrix(rankings)
+                # Candidate rules of Fragment._filter_candidates as masks
+                # (tanimoto's bounds wait for src's counts: _replay_topn).
+                cand = rank_cnt > 0 if tanimoto else rank_cnt >= thr
+                union = np.unique(rank_ids[cand])
+                if attr_name and attr_values:
+                    union = np.asarray(attr_rows(union.tolist()), np.int64)
+                    cand &= np.isin(rank_ids, union)
+                if not len(union):
                     return []
-                shard_list = [f.shard for f, _ in frags]
-                inter_by_shard: Dict[int, Dict[int, int]] = {
-                    s: {} for s in shard_list
-                }
-                src_count_by_shard: Dict[int, int] = {}
+                chunks = []
                 CHUNK = _topn_chunk(len(shard_list))  # bounds the gather working set
                 for i in range(0, len(union), CHUNK):
                     if i:
@@ -1557,29 +1614,33 @@ class Executor:
                         # (503) instead of finishing dead device work.
                         self._check_chunk_deadline(
                             opt.deadline, "between TopN chunks")
-                    chunk = union[i : i + CHUNK]
                     # Ranking uses the cache counts already attached to the
                     # candidates; the device program only computes the src
                     # intersections (need_row_counts=False).
                     _, inter, src_counts = self._topn_counts_laddered(
-                        index, field_name, chunk, shard_list, src_call,
-                        False,
+                        index, field_name, union[i : i + CHUNK].tolist(),
+                        shard_list, src_call, False,
                     )
-                    for si, s in enumerate(shard_list):
-                        src_count_by_shard[s] = int(src_counts[si])
-                    for ri, r in enumerate(chunk):
-                        for si, s in enumerate(shard_list):
-                            inter_by_shard[s][r] = int(inter[ri, si])
-                out: List[Pair] = []
-                for frag, cands in frags:
-                    counts = {
-                        r: inter_by_shard[frag.shard].get(r, 0) for r, _ in cands
-                    }
-                    out.extend(frag.top(
-                        topn_opt, inter_counts=counts,
-                        src_count=src_count_by_shard[frag.shard],
-                    ))
-                return add_pairs([], out)
+                    chunks.append(inter)
+                inter = np.concatenate(chunks)
+                # (union, shards) -> each shard's rank order. A cell that is
+                # no candidate reads some row's count, which nothing uses.
+                row_of = np.minimum(
+                    np.searchsorted(union, rank_ids), len(union) - 1)
+                count = np.asarray(inter, np.int64)[
+                    row_of, np.arange(len(shard_list))[:, None]]
+                accepted = _replay_topn(
+                    rank_cnt, count, cand, np.asarray(src_counts, np.int64),
+                    n_arg, thr, tanimoto)
+                # Every accepted count is over 0, so a row some shard
+                # accepted has a total over 0: what add_pairs over the
+                # shards' pair lists gave.
+                totals = np.zeros(len(union), np.int64)
+                np.add.at(totals, row_of[accepted], count[accepted])
+                return [
+                    Pair(id=r, count=t)
+                    for r, t in zip(union.tolist(), totals.tolist()) if t
+                ]
 
         if local_runner is not None:
             # Last rung for a batch neither the device nor the host
@@ -1589,12 +1650,15 @@ class Executor:
 
             def guarded_runner(local_shards):
                 try:
-                    return batched_runner(local_shards)
+                    out = batched_runner(local_shards)
+                    self.topn_array_walks += 1
+                    return out
                 except DeviceDispatchError as e:
                     self._count_stat("DeviceLadderFallback")
                     self.logger.error(
                         "batched TopN unavailable (%s), per-shard rung: %s",
                         e.kind, e)
+                    self.topn_shard_replays += len(local_shards)
                     out = []
                     for s in local_shards:
                         out = add_pairs(out, map_fn(s))

@@ -1006,8 +1006,14 @@ class Handler:
         # shard, once per shard list per topology and on every assignment
         # while a rebalance is in flight.
         if executor is not None:
-            out["executor"] = {"assign_hits": executor.assign_hits,
-                               "assign_walks": executor.assign_walks}
+            out["executor"] = {
+                "assign_hits": executor.assign_hits,
+                "assign_walks": executor.assign_walks,
+                # Batched TopN runner calls answered on arrays, and shards
+                # such a runner handed to the per-shard rung instead.
+                "topn_array_walks": executor.topn_array_walks,
+                "topn_shard_replays": executor.topn_shard_replays,
+            }
         # Ingest health (docs/ingest.md): un-snapshotted WAL bytes across
         # fragments, background-snapshot counters and queue depth, and how
         # many shard batches the import surface has applied/routed — the
