@@ -86,7 +86,7 @@ class HolderCleaner:
                     for shard in list(view.fragments):
                         if cluster.owns_shard(cluster.node.id, index_name, shard):
                             continue
-                        frag = view.fragments.pop(shard)
+                        frag = view.drop_fragment(shard)
                         frag.close()
                         if frag.path and os.path.exists(frag.path):
                             os.remove(frag.path)
@@ -94,8 +94,9 @@ class HolderCleaner:
                         if cache and os.path.exists(cache):
                             os.remove(cache)
                         removed.append(f"{index_name}/{field.name}/{view.name}/{shard}")
-                        # After the pop, as a mutation bumps after its
-                        # generation: the engine's staleness checks trust
-                        # the epoch (parallel/engine.py _fingerprint).
+                        # After the drop (which told the view's journal),
+                        # as a mutation bumps after its generation: the
+                        # engine's staleness checks trust both
+                        # (parallel/engine.py _fingerprint).
                         idx.write_epoch.bump()
         return removed

@@ -154,6 +154,94 @@ class WriteEpoch:
             self.value += 1
 
 
+# The row a ChangeJournal entry names when the whole fragment changed at
+# once (read_from, a migration install) or came or went (a fragment
+# created in the view, or dropped from it).
+ALL_ROWS = -1
+
+# Entries a view's ChangeJournal keeps: between this many and twice as
+# many, some 150 bytes each (a megabyte a view at most). A reader whose
+# stamp is older than the oldest entry kept is told "cannot say" and asks
+# the fragments instead, so the bound is a trade between a reader's scan
+# (tens of nanoseconds an entry) and a walk over the shards, never a
+# matter of correctness.
+_JOURNAL_ENTRIES = 4096
+
+
+class ChangeJournal:
+    """What the writers of ONE view changed, told by them: the engine's
+    caches ask here "what was written since my stamp?" rather than asking
+    every fragment of the view for its generation (parallel/engine.py
+    `_fingerprint`, `_gather_leaf`).
+
+    `stamp` is `(incarnation, seq)`: `seq` counts the entries ever
+    noted, the incarnation is this journal's (a view made again is a new
+    journal, whose stamps equal none of the old one's). An entry is
+    `(seq, shard, row, fp)`: the fragment of `shard` changed `row`, and
+    `fp` is that fragment's `(incarnation, generation)` BEFORE the write,
+    so that `dirty_words_since(row, fp[1])` gives the write's words and
+    all later ones; `row` is ALL_ROWS (and `fp` None) where no row can be
+    named. It holds numbers only, never a Fragment: a dropped view's
+    storage is not pinned by what remembers it.
+
+    Writers (each under its own fragment's mutex, so several at once per
+    view) come through `note`, which takes the journal's one short lock.
+    Readers take none. They rely on two orders: an entry is in the log
+    BEFORE the stamp that covers it is published, and `since` reads the
+    log AFTER its caller read the stamp. The log is only ever appended to
+    or replaced whole by a trimmed copy, so a reference to it stays
+    consistent."""
+
+    __slots__ = ("incarnation", "stamp", "_log", "_mu")
+
+    def __init__(self):
+        self.incarnation = next(_INCARNATION)
+        self.stamp = (self.incarnation, 0)
+        self._log: list = []
+        self._mu = threading.Lock()
+
+    def note(self, shard: int, row: int, fp) -> None:
+        with self._mu:
+            seq = self.stamp[1] + 1
+            log = self._log
+            log.append((seq, shard, row, fp))
+            if len(log) >= 2 * _JOURNAL_ENTRIES:
+                self._log = log[-_JOURNAL_ENTRIES:]
+            self.stamp = (self.incarnation, seq)
+
+    def since(self, old, new):
+        """The entries after stamp `old` up to stamp `new` (one the caller
+        read from `stamp` earlier), oldest first; None where the journal
+        cannot say: `old` is no stamp of this journal, or older than the
+        oldest entry kept."""
+        if old == -1 or new == -1 or old[0] != new[0] \
+                or new[0] != self.incarnation:
+            return None
+        n = new[1] - old[1]
+        if n <= 0:
+            return ()
+        log = self._log
+        at = old[1] + 1 - log[0][0]
+        if at < 0:
+            return None
+        return log[at:at + n]
+
+    def changed(self, old, new, rows):
+        """{(shard, row): fp} of the cells of `rows` written after `old`
+        up to `new`, each with the fp of its FIRST such write; None where
+        the journal cannot say or an entry names ALL_ROWS."""
+        ents = self.since(old, new)
+        if ents is None:
+            return None
+        cells: dict = {}
+        for _, shard, row, fp in ents:
+            if row == ALL_ROWS:
+                return None
+            if row in rows:
+                cells.setdefault((shard, row), fp)
+        return cells
+
+
 @dataclass
 class FragmentBlock:
     id: int
@@ -194,6 +282,7 @@ class Fragment:
         delta_journal_ops: Optional[int] = None,
         snapshotter=None,
         cdc=None,
+        journal: Optional[ChangeJournal] = None,
     ):
         self.path = path
         self.index = index
@@ -269,6 +358,10 @@ class Fragment:
         # Index-level write epoch (see WriteEpoch), bumped alongside
         # generation so O(1) index staleness reads need no fragment walk.
         self.epoch = epoch
+        # The view's change journal (see ChangeJournal), told of every
+        # generation bump: which row, or ALL_ROWS. None for a fragment
+        # that stands alone, outside any view.
+        self.journal = journal
         # Dirty-word journal. The engine's delta-refresh path asks
         # dirty_words_since(row, cached_gen) to upload only the changed
         # words of a stale resident plane instead of re-walking and
@@ -513,13 +606,16 @@ class Fragment:
         the epoch bump is what stale-proofs the batcher's group keys and
         the memo's O(1) probe — a path that skips either serves stale
         results silently (tests/test_delta.py parametrizes the audit).
-        The ORDER matters too: generation first, epoch last. The engine
-        asks the fragments for their generations once per epoch and keeps
-        the answer under the epoch it read BEFORE asking
-        (parallel/engine.py _fingerprint), so a reader that overlaps this
-        call can at worst keep a generation newer than its epoch, which
-        the bump below then retires. Epoch first would let it keep the
-        old generation under the new epoch: stale until the next write.
+        The ORDER matters too: generation, then the view's journal, then
+        the epoch. The engine's caches stamp what they hold with the
+        journal's `stamp` (parallel/engine.py _fingerprint) and ask the
+        journal what was written since; the memo reads the epoch BEFORE
+        that stamp. A reader that overlaps this call can so at worst hold
+        a stamp newer than its epoch, which the bump below then retires.
+        Epoch first would let it keep the old stamp under the new epoch:
+        stale until the next write. And the journal after the generation
+        and the dirty words, under this fragment's mutex: whoever reads
+        the entry finds the generation moved and the words recorded.
 
         `dirty_w64` is the iterable of changed 64-bit word indices within
         the row plane; None means the caller can't enumerate them (bulk
@@ -545,6 +641,20 @@ class Fragment:
                 d[w] = g
             if self._dirty_n > self.delta_journal_ops:
                 self._journal_reset()
+        if self.journal is not None:
+            self.journal.note(self.shard, row_id,
+                              (self.incarnation, self.generation - 1))
+        if self.epoch is not None:
+            self.epoch.bump()
+
+    def _invalidate_all(self) -> None:
+        """Every row at once (must hold _mu): the generation, no dirty-word
+        history, ALL_ROWS to the view's journal, the epoch, in the order
+        _invalidate_row explains."""
+        self.generation += 1
+        self._journal_reset()
+        if self.journal is not None:
+            self.journal.note(self.shard, ALL_ROWS, None)
         if self.epoch is not None:
             self.epoch.bump()
 
@@ -1543,13 +1653,9 @@ class Fragment:
             self._plane_cache.clear()
             self._checksums.clear()
             self.cache.clear()
-            self.generation += 1
             # Wholesale replacement: no per-word history exists, so every
             # cached generation older than NOW must full-regather.
-            self._journal_reset()
-            # Epoch after generation: the order _invalidate_row explains.
-            if self.epoch is not None:
-                self.epoch.bump()
+            self._invalidate_all()
             for row_id in self.rows():
                 self.cache.bulk_add(row_id, self.row_count(row_id))
             self.cache.invalidate(force=True)
@@ -1565,10 +1671,7 @@ class Fragment:
         # epoch last, the order _invalidate_row explains.
         self._plane_cache.clear()
         self._checksums.clear()
-        self.generation += 1
-        self._journal_reset()
-        if self.epoch is not None:
-            self.epoch.bump()
+        self._invalidate_all()
 
     def migrate_install(self, data: bytes) -> None:
         """Install a migration base snapshot (a serialized container

@@ -13,7 +13,7 @@ import threading
 from typing import Callable, Dict, List, Optional
 
 from ..constants import CACHE_TYPE_RANKED, DEFAULT_CACHE_SIZE, SHARD_WIDTH
-from .fragment import Fragment
+from .fragment import ALL_ROWS, ChangeJournal, Fragment
 
 
 class View:
@@ -49,6 +49,10 @@ class View:
         self.snapshotter = snapshotter
         self.cdc = cdc
         self.fragments: Dict[int, Fragment] = {}
+        # What this view's writers changed, told by them (ChangeJournal):
+        # every fragment made here notes its writes in it, and so does the
+        # view when a fragment comes or goes.
+        self.journal = ChangeJournal()
         self._lock = threading.RLock()
 
     def open(self) -> "View":
@@ -90,6 +94,7 @@ class View:
             delta_journal_ops=self.delta_journal_ops,
             snapshotter=self.snapshotter,
             cdc=self.cdc,
+            journal=self.journal,
         )
 
     def fragment(self, shard: int) -> Optional[Fragment]:
@@ -105,16 +110,27 @@ class View:
                 self.fragments[shard] = frag
                 created = True
                 # A view with one fragment more reads differently to the
-                # engine's staleness checks, which trust the epoch
-                # (parallel/engine.py _fingerprint): bump AFTER the
-                # fragment is in place, as a mutation bumps after its
-                # generation.
+                # engine's caches, which trust the journal and the epoch
+                # (parallel/engine.py _fingerprint): tell them AFTER the
+                # fragment is in place, journal then epoch, as a mutation
+                # does after its generation.
+                self.journal.note(shard, ALL_ROWS, None)
                 if self.epoch is not None:
                     self.epoch.bump()
         # Broadcast outside the lock: the peer handling CreateShardMessage
         # takes its own view lock and may call back here (deadlock otherwise).
         if created and broadcast and self.broadcast_shard:
             self.broadcast_shard(self.index, self.field, shard)
+        return frag
+
+    def drop_fragment(self, shard: int) -> Fragment:
+        """Take one fragment out of the view (its owner moved; the caller
+        closes it and removes its files) and say so to the journal. The
+        caller bumps the epoch after, as create_fragment_if_not_exists
+        does."""
+        with self._lock:
+            frag = self.fragments.pop(shard)
+            self.journal.note(shard, ALL_ROWS, None)
         return frag
 
     def available_shards(self) -> List[int]:

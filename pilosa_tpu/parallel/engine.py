@@ -7,13 +7,15 @@ tensor of shape (L, S, W) — L leaf rows, S shards sharded over the device
 mesh, W bitplane words. XLA fuses the whole tree into one fused
 elementwise+popcount kernel per device and inserts ICI collectives for the
 scalar reductions. Leaf planes are cached on device between queries and
-invalidated by fragment generation counters: every cache entry carries
-the per-shard (incarnation, generation) fingerprint of its leaves' view,
-and a probe compares it with the current one. "Current" is asked of the
-fragments once per write epoch, not once per probe: `_fingerprint` keeps
-the last walk of each (index, field, view, shards) under the index's
-write-epoch token and serves it until that token moves (every mutation,
-and every change of which fragments a view has, moves it).
+invalidated by what the writers say: every view keeps a change journal
+(core/fragment.py ChangeJournal) that each write to one of its fragments
+notes its shard and row in. A cache entry carries the journal's stamp,
+one comparison tells a probe whether anything was written to the view
+since, and if so the journal names the cells: an entry none of whose
+rows was written is fresh again as it stands, another is patched in
+those cells alone. Only where the journal cannot say (it has forgotten
+that far back, a fragment came or went, a whole fragment was replaced)
+are the view's fragments asked one by one, as they all used to be.
 
 Supported fast-path calls: Row / Intersect / Union / Difference / Xor /
 Range(BSI) compositions, Count(...) and per-row TopN candidate counting.
@@ -82,13 +84,6 @@ FN_KINDS = (
     "stack_delta", "bitmap", "bitmap_batch", "topn_shard", "topn_shard_src",
     "topn_src", "topn", "bsi",
 )
-
-# Bound of the fingerprint cache (ShardedQueryEngine._fingerprint): one
-# entry per (index, field, view, shard tuple) asked about, a few hundred
-# bytes each. A node serves a handful of shard tuples per index, so this
-# is hundreds of times what a busy one needs.
-_FP_CACHE_ENTRIES = 1024
-
 
 class _FirstCall:
     """A freshly built program on its builder's way to the first call. A
@@ -320,8 +315,10 @@ class ShardedQueryEngine:
         gw = int(config.gather_workers)
         self._gather_workers = gw if gw > 0 else min(8, os.cpu_count() or 1)
         self._gather_pool = None  # lazy ThreadPoolExecutor
-        # (index, leaf, shards) -> (generation fingerprint, sharded device array)
-        self._leaf_cache: Dict[Tuple, Tuple[Tuple, jax.Array]] = {}
+        # (index, leaf, shards) -> (its view's stamp, sharded device array,
+        # per-shard (incarnation, generation) pairs of the last walk: what
+        # the safe rung compares when the journal cannot say)
+        self._leaf_cache: Dict[Tuple, Tuple[Tuple, jax.Array, Tuple]] = {}
         self._leaf_bytes = 0
         # (index, leaves, shards, U) -> (fingerprint, stacked (U, S, W) array)
         self._stack_cache: Dict[Tuple, Tuple[Tuple, jax.Array]] = {}
@@ -381,13 +378,6 @@ class ShardedQueryEngine:
         self._aux_memo: Dict[Tuple, Tuple[Tuple, object]] = {}
         self._aux_budget = budget(
             "PILOSA_AUX_MEMO_ENTRIES", config.aux_memo_entries, 512)
-        # (index, field, view, shards) -> (write-epoch token, fingerprint):
-        # the last fragment walk of a view, trusted while the index's
-        # epoch stands still (_fingerprint). Fingerprints only, never
-        # Fragment objects: a dropped field's storage must not stay
-        # pinned here. Bounded by _FP_CACHE_ENTRIES, oldest walk out first.
-        # Written under self._lock, read without (see _fingerprint).
-        self._fp_cache: Dict[Tuple, Tuple[Tuple, Tuple]] = {}
         # Effective cache bounds after env > config > tier > default
         # resolution, surfaced verbatim in /debug/vars (engine_budgets) so
         # a deployment can SEE what its knobs resolved to.
@@ -404,10 +394,16 @@ class ShardedQueryEngine:
             "leaf_hits": 0, "leaf_misses": 0, "leaf_evictions": 0,
             "stack_hits": 0, "stack_misses": 0, "stack_evictions": 0,
             "memo_hits": 0, "memo_misses": 0,
-            # Staleness checks (_fingerprint): fp_walks asked every
-            # fragment of a view for its generation, fp_hits were served
-            # the walk of the same write epoch.
-            "fp_hits": 0, "fp_walks": 0,
+            # Stale entries (leaf, stack) and how their question "what
+            # was written since my stamp?" was answered: fp_journal_reads
+            # asked the view's change journal (bumped without the lock,
+            # so it may undercount), fp_walks fell to asking every
+            # fragment of the view because the journal could not say.
+            # leaf_republished / stack_republished: stale entries none of
+            # whose rows had been written, made fresh with no fragment
+            # touched.
+            "fp_journal_reads": 0, "fp_walks": 0,
+            "leaf_republished": 0, "stack_republished": 0,
             # Compiled-program (XLA executable) cache traffic: the proof
             # that canonicalized query shapes SHARE programs is
             # fn_cache_hits climbing while fn_cache_builds stays flat
@@ -929,83 +925,135 @@ class ShardedQueryEngine:
 
     def _leaf_fragments(self, index: str, leaf: Leaf,
                         shards: Tuple[int, ...]):
-        """(fragments, fingerprint) of one leaf's view: the walk itself,
-        one holder lookup per shard (None where a shard has no fragment)
-        and the per-shard (incarnation, generation) pairs READ FROM THOSE
-        fragments. Callers that go on to read the fragments' data
-        (_gather_leaf's refresh, _host_plane) come here and not to
-        _fingerprint, so that the two always belong together."""
+        """(fragments, per-shard pairs) of one leaf's view: the walk, one
+        holder lookup per shard (None where a shard has no fragment) and
+        the (incarnation, generation) pairs READ FROM THOSE fragments.
+        Whoever goes on to read the fragments' data (a cold or demoted
+        plane in _gather_leaf, _host_plane) comes here, so that the two
+        always belong together; so does a stale leaf whose journal cannot
+        say what changed (_leaf_delta, which counts it in fp_walks)."""
         fragment = self.holder.fragment
         frags = [fragment(index, leaf.field, leaf.view, s) for s in shards]
         return frags, tuple(
             -1 if f is None else (f.incarnation, f.generation) for f in frags)
 
-    def _fingerprint(self, index: str, leaf: Leaf, shards: Tuple[int, ...]) -> Tuple:
-        """Per-shard (incarnation, generation) pairs for one leaf — the
-        staleness key for every device cache (no device work). The
-        incarnation half makes a RECREATED fragment (deleted index re-made
-        under the same name, generation counter reset) never compare equal
-        to a stale entry, even if its fresh counter climbs back to the
-        cached value.
+    def _journal(self, index: str, field: str, view: str):
+        """The change journal of one view (core/fragment.py
+        ChangeJournal), or None where there is no such view."""
+        idx = self.holder.index(index)
+        fld = None if idx is None else idx.field(field)
+        v = None if fld is None else fld.view(view)
+        return None if v is None else v.journal
 
-        A generation belongs to the fragment, not to the leaf's row, so
-        the answer is the same for every row of a view and stands until
-        something in the index changes. It is therefore asked of the
-        fragments once per write epoch: the walk is kept under the epoch
-        token READ BEFORE IT and served (the same tuple object, so a
-        cache's `cached[0] == fp` compares by identity) while the token
-        stands. Why that is exact: a writer bumps its fragment's generation
-        and only THEN the epoch (core/fragment.py `_invalidate_row`,
-        `read_from`, `_migrate_invalidate`; what adds or drops a fragment
-        bumps after the change too). A walk that overlaps a write may so
-        keep a fingerprint NEWER than its token; the next call sees the
-        moved epoch and walks again. One OLDER than its token cannot be
-        kept, so nothing is served that a walk at that epoch would not
-        return: memo_probe's probe-time discipline, one level down."""
-        token = self._epoch_token(index)
-        key = (index, leaf.field, leaf.view, shards)
-        # A hit takes no lock: one dict read (atomic), one comparison. It
-        # is made 6-9 times a Count and 100-200 times a TopN from every
-        # serving thread, and self._lock there, however briefly held, is
-        # held by a thread that loses the interpreter often enough that
-        # the others queue behind it (measured on the chip: TopN's median
-        # 111 -> 185 ms; PERF.md, PR 29). So fp_hits is bumped unlocked
-        # too, and may undercount by a bump lost between two threads;
-        # fp_walks is exact.
-        ent = self._fp_cache.get(key)
-        if ent is not None and ent[0] == token:
-            self.counters["fp_hits"] += 1
-            return ent[1]
-        fp = self._leaf_fragments(index, leaf, shards)[1]
+    @staticmethod
+    def _stamps(journals) -> Tuple:
+        return tuple(-1 if j is None else j.stamp for j in journals)
+
+    def _fingerprint(self, index: str, leaves) -> Tuple:
+        """The staleness key of every cache (no device work, no fragment
+        touched): the `(incarnation, seq)` stamp of the change journal of
+        each distinct view among `leaves`, in order of first appearance;
+        -1 for a view that is not there. Two fingerprints are compared
+        with one comparison per view, whatever the number of shards.
+
+        A stamp moves with every write to ANY fragment of its view, also
+        one in a shard outside the shards a caller asked about: on a node
+        that owns part of a view an entry is found stale more often than
+        the per-shard pairs found it, never less often. The incarnation
+        half makes a view that was made again (a deleted field or index
+        re-made under the same name) never compare equal to an old entry.
+
+        Why a cache may trust it: a writer moves its fragment's generation,
+        then notes the write in the journal (the entry first, the stamp
+        that covers it after), and only THEN bumps the index's epoch
+        (core/fragment.py `_invalidate_row`, `_invalidate_all`; what adds
+        or drops a fragment of a view notes ALL_ROWS after the change). So
+        data read after a stamp holds every write the stamp covers, and a
+        caller that read the epoch before coming here (memo_probe) holds
+        at worst a stamp NEWER than its epoch token, which the next probe
+        re-reads; one OLDER than its token it cannot hold."""
+        views = dict.fromkeys((leaf.field, leaf.view) for leaf in leaves)
+        return self._stamps(self._journal(index, f, v) for f, v in views)
+
+    def _changed(self, journals, old: Tuple, new: Tuple, leads):
+        """What was written to an entry's rows between the fingerprint
+        `old` it carries and `new`: one `{(shard, row): fp}` per view
+        (ChangeJournal.changed), all of them empty where nothing was; None
+        where a journal cannot say. `leads` has one dict per view, keyed
+        by the rows the entry holds of it. No lock, no fragment touched."""
+        self.counters["fp_journal_reads"] += 1
+        out = []
+        for journal, o, n, lead in zip(journals, old, new, leads):
+            cells = {} if o == n else (
+                None if journal is None else journal.changed(o, n, lead))
+            if cells is None:
+                return None
+            out.append(cells)
+        return out
+
+    def _republish(self, cache: Dict, key, cached: Tuple, fp,
+                   counter: str) -> None:
+        """`cached`, none of whose rows was written up to `fp`, is fresh
+        again under `fp`, unless another thread has put a newer entry in
+        its place meanwhile."""
         with self._lock:
-            self.counters["fp_walks"] += 1
-            if token != -1:  # no such index: nothing to say when it changes
-                # Re-inserted at the end: the entry walked longest ago is
-                # the first to go.
-                self._fp_cache.pop(key, None)
-                self._fp_cache[key] = (token, fp)
-                while len(self._fp_cache) > _FP_CACHE_ENTRIES:
-                    self._fp_cache.pop(next(iter(self._fp_cache)))
-        return fp
+            if cache.get(key) is cached:
+                cache.pop(key)  # and back in at the MRU end
+                cache[key] = (fp,) + cached[1:]
+            self.counters[counter] += 1
+
+    def _named_members(self, index: str, field: str, view: str,
+                       shards: Tuple[int, ...], cells: Dict, lead: Dict):
+        """_collect_updates members for the cells a journal named.
+        `lead[row]` lists the leading coordinates of `row` in the cached
+        tensor: `[()]` for a leaf, `[(u,), ...]` for a stack."""
+        for (shard, row), old in cells.items():
+            try:
+                i = shards.index(shard)
+            except ValueError:
+                continue  # a shard the entry does not cover
+            frag = self.holder.fragment(index, field, view, shard)
+            for coords in lead[row]:
+                yield coords + (i,), frag, row, old
 
     def _gather_leaf(self, index: str, leaf: Leaf, shards: Tuple[int, ...]) -> jax.Array:
-        """(S_padded, W) uint32, sharded over the mesh's shard axis."""
+        """(S_padded, W) uint32, sharded over the mesh's shard axis.
+
+        A resident plane whose stamp is the journal's is served as it
+        stands. One whose stamp is older asks the journal what was written
+        since (`_changed`): if nothing names its row it is republished
+        under the new stamp from inside the probe (no gate, no span, no
+        fragment touched); if cells of its row are named, only those go
+        through the delta scatter (`_leaf_delta`). Where the journal cannot
+        say, `_leaf_delta` asks the fragments, as it did for every stale
+        plane before there was a journal."""
         s_padded = pad_shards(len(shards), self.n_devices)
         key = (index, leaf, shards)
-        fingerprint = self._fingerprint(index, leaf, shards)
+        journal = self._journal(index, leaf.field, leaf.view)
+        lead = {leaf.row: ((),)}
 
         def probe():
             with self._lock:
+                # Read under the lock that entries are published under:
+                # no entry then carries a stamp newer than this one.
+                stamp = -1 if journal is None else journal.stamp
                 cached = self._leaf_cache.get(key)
-                if cached is not None and cached[0] == fingerprint:
+                if cached is None:
+                    return None
+                fresh = cached[0] == stamp
+                if fresh:
                     self._leaf_cache[key] = self._leaf_cache.pop(key)  # LRU touch
                     self.counters["leaf_hits"] += 1
-                    hit = cached[1]
-                else:
+            if not fresh:
+                cells = self._changed(
+                    (journal,), (cached[0],), (stamp,), (lead,))
+                if cells is None or cells[0]:
                     return None
+                self._republish(self._leaf_cache, key, cached, stamp,
+                                "leaf_republished")
             if self.tier is not None and self.tier.has_prefetched():
                 self.tier.note_hbm_hit(key)
-            return hit
+            return cached[1]
 
         arr = self._gate(("leaf", key), probe)
         if arr is not None:
@@ -1017,32 +1065,30 @@ class ShardedQueryEngine:
         # "why was this gather 30 ms" without correlating counters.
         with obs_span("gather") as sp:
             try:
-                # Only a refresh reads the fragments, so only here are they
-                # looked up, and the entry is stamped with what THEY say
-                # (read before their data, the order every cache relies
-                # on): the epoch's fingerprint may be older than them.
-                # Where the two agree the epoch's tuple is kept, which
-                # later probes compare by identity.
-                frags, fresh = self._leaf_fragments(index, leaf, shards)
-                if fresh != fingerprint:
-                    fingerprint = fresh
+                with self._lock:
+                    stale = self._leaf_cache.get(key)
+                    # The stamp the refreshed plane will carry, read
+                    # BEFORE the fragments' data (the order every cache
+                    # relies on) and after the entry it is compared with.
+                    stamp = -1 if journal is None else journal.stamp
                 # Stale resident entry: try the delta path first — upload
                 # only the words the writes changed instead of re-walking
                 # every shard's containers and re-shipping the whole plane.
-                with self._lock:
-                    stale = self._leaf_cache.get(key)
                 if stale is not None:
-                    arr = self._leaf_delta(key, leaf.row, stale, frags,
-                                           fingerprint, evicted)
+                    arr = self._leaf_delta(key, stale, journal, stamp, lead,
+                                           evicted)
                     if arr is not None:
                         sp.tag(kind="delta")
                         return arr
+                # Only from here on are the fragments' data read, so only
+                # here are they all looked up.
+                frags, pairs = self._leaf_fragments(index, leaf, shards)
                 # Demoted plane? Decode the compressed host/disk-tier image
                 # (journal deltas folded) instead of walking every shard's
                 # live containers.
                 buf = None
                 if self.tier is not None:
-                    buf = self.tier.promote(key, frags, fingerprint, s_padded)
+                    buf = self.tier.promote(key, frags, pairs, s_padded)
                 tier_hit = buf is not None
                 if buf is None:
                     buf = self._host_gather(frags, leaf.row, s_padded)
@@ -1061,7 +1107,7 @@ class ShardedQueryEngine:
                         self.counters["leaf_misses"] += 1
                         self.counters["full_refresh_bytes"] += buf.nbytes
                     self._leaf_bytes = self._byte_cache_put(
-                        self._leaf_cache, key, (fingerprint, arr),
+                        self._leaf_cache, key, (stamp, arr, pairs),
                         self._leaf_budget, self._leaf_bytes, "leaf_evictions",
                         evicted,
                     )
@@ -1127,11 +1173,13 @@ class ShardedQueryEngine:
         """Shared delta collector for the leaf and stack paths (one body so
         the guards/budget/incarnation logic cannot diverge between them).
 
-        `members`: iterable of (coords, frag, row, old_fp, new_fp) per
-        STALE cache member — coords are the member's leading indices in the
-        cached tensor ((shard,) for a leaf, (u, shard) for a stack), fps
-        are -1 or (incarnation, generation) pairs. `size` is the cached
-        tensor's element count (the delta budget base).
+        `members`: iterable of (coords, frag, row, old_fp) per cache
+        member that may have changed — coords are the member's indices in
+        the cached tensor ((shard,) for a leaf, (u, shard) for a stack),
+        `frag` the fragment as it is looked up now, `old_fp` the
+        (incarnation, generation) pair the cached words are at least as
+        new as (-1: there was no fragment). `size` is the cached tensor's
+        element count (the delta budget base).
 
         Returns None when only a full regather is safe (missing fragment,
         fragment recreated since the fp was read, journal can't answer,
@@ -1140,13 +1188,12 @@ class ShardedQueryEngine:
         from rows outside the cache and zero bytes need to move."""
         out = []
         n32 = 0
-        for coords, frag, row, old_fp, new_fp in members:
-            if frag is None or old_fp == -1 or new_fp == -1:
+        for coords, frag, row, old_fp in members:
+            if frag is None or old_fp == -1:
                 return None
-            if old_fp[0] != new_fp[0] or frag.incarnation != new_fp[0]:
+            if frag.incarnation != old_fp[0]:
                 # Different incarnation: the journal's generations are not
-                # comparable across it (and the frag we just looked up may
-                # itself be newer than the fingerprint we read).
+                # comparable across it.
                 return None
             w = frag.dirty_words_since(row, old_fp[1])
             if w is None:
@@ -1182,19 +1229,36 @@ class ShardedQueryEngine:
             return arrays
         return [np.concatenate([a, np.repeat(a[:1], npad - n)]) for a in arrays]
 
-    def _leaf_delta(self, key, row: int, stale, frags, fingerprint,
+    def _leaf_delta(self, key, stale, journal, stamp, lead: Dict,
                     evicted: Optional[List] = None):
-        """Refresh a stale cached (S, W) leaf; None = caller must
-        full-regather. `evicted` collects evicted keys for demotion."""
-        old_fp, arr = stale
-        if self._delta_max_fraction <= 0 or len(old_fp) != len(fingerprint):
+        """Refresh a stale cached (S, W) leaf up to `stamp`; None = caller
+        must full-regather. `evicted` collects evicted keys for demotion.
+
+        The cells to look at are those the journal names. Where it cannot
+        say, the safe rung: every fragment of the view is asked
+        (`fp_walks`) and each shard whose pair differs from the one the
+        entry kept from its last walk is looked at."""
+        index, leaf, shards = key
+        old_stamp, arr, kept = stale
+        if self._delta_max_fraction <= 0:
             return None
-        updates = self._collect_updates(
-            (((i,), frag, row, old_fp[i], fingerprint[i])
-             for i, frag in enumerate(frags)
-             if old_fp[i] != fingerprint[i]),
-            arr.size,
-        )
+        cells = self._changed((journal,), (old_stamp,), (stamp,), (lead,))
+        if cells is not None:
+            # The kept pairs stand: a cell's words are no older for having
+            # been patched, and `dirty_words_since` an older generation
+            # names more words, never fewer.
+            pairs = kept
+            members = self._named_members(
+                index, leaf.field, leaf.view, shards, cells[0], lead)
+        else:
+            with self._lock:
+                self.counters["fp_walks"] += 1
+            frags, pairs = self._leaf_fragments(index, leaf, shards)
+            if len(kept) != len(pairs):
+                return None
+            members = (((i,), frag, leaf.row, kept[i])
+                       for i, frag in enumerate(frags) if kept[i] != pairs[i])
+        updates = self._collect_updates(members, arr.size)
         if updates is None:
             return None
         if not updates:
@@ -1224,32 +1288,31 @@ class ShardedQueryEngine:
             self.counters["delta_bytes"] += moved
             self.counters["h2d_bytes"] += moved
             self._leaf_bytes = self._byte_cache_put(
-                self._leaf_cache, key, (fingerprint, new_arr),
+                self._leaf_cache, key, (stamp, new_arr, pairs),
                 self._leaf_budget, self._leaf_bytes, "leaf_evictions",
                 evicted,
             )
         return new_arr
 
-    def _stack_delta(self, key, index: str, leaves, shards, stale, fp):
-        """Refresh a stale (U, S, W) stack with one scattered update — no
-        host walk, no member re-gather, no restack. None = full rebuild."""
+    def _stack_delta(self, key, stale, journals, fp: Tuple, by_view: Dict):
+        """Refresh a stale (U, S, W) stack up to `fp` with one scattered
+        update of the cells the journals name — no host walk, no member
+        re-gather, no restack. None = full rebuild: also where a journal
+        cannot say, for then each member plane finds out for itself
+        (_gather_leaf and its safe rung) and the stack is built of them."""
+        index, leaves, shards, _ = key
         old_fp, arr = stale
-        if self._delta_max_fraction <= 0 or len(old_fp) != len(fp):
+        if self._delta_max_fraction <= 0:
             return None
-        if any(len(o) != len(n) for o, n in zip(old_fp, fp)):
+        cells = self._changed(journals, old_fp, fp, by_view.values())
+        if cells is None:
             return None
-
-        def members():
-            for u, leaf in enumerate(leaves):
-                if old_fp[u] == fp[u]:
-                    continue
-                for i, s in enumerate(shards):
-                    if old_fp[u][i] == fp[u][i]:
-                        continue
-                    frag = self.holder.fragment(index, leaf.field, leaf.view, s)
-                    yield (u, i), frag, leaf.row, old_fp[u][i], fp[u][i]
-
-        updates = self._collect_updates(members(), arr.size)
+        updates = self._collect_updates(
+            (member for ((field, view), lead), named in zip(
+                by_view.items(), cells)
+             for member in self._named_members(
+                 index, field, view, shards, named, lead)),
+            arr.size)
         if updates is None:
             return None
         # pow2 padding rows duplicate leaf 0; today no compiled program
@@ -1325,18 +1388,41 @@ class ShardedQueryEngine:
 
     def _stack_get_or_build(self, index: str, leaves: List[Leaf],
                             shards: Tuple[int, ...], n: int, np2: int):
-        """(the (np2, S, W) stack, how it was come by)."""
-        fp = tuple(self._fingerprint(index, leaf, shards) for leaf in leaves)
+        """(the (np2, S, W) stack, how it was come by). A stale stack
+        asks the journals of its views what was written since its
+        fingerprint, as a stale leaf does (_gather_leaf)."""
         key = (index, tuple(leaves), shards, np2)
+        # The views in the fingerprint's order, and the journal of each.
+        views = dict.fromkeys((leaf.field, leaf.view) for leaf in leaves)
+        journals = tuple(self._journal(index, f, v) for f, v in views)
+
+        def where():
+            """{view: {row: [(u,), ...]}}: where each row of each view
+            lies in the stack. Only a stale stack needs it."""
+            by_view: Dict[Tuple, Dict[int, List]] = {v: {} for v in views}
+            for u, leaf in enumerate(leaves):
+                by_view[(leaf.field, leaf.view)].setdefault(
+                    leaf.row, []).append((u,))
+            return by_view
 
         def probe():
             with self._lock:
+                fp = self._stamps(journals)  # under the lock: _gather_leaf
                 cached = self._stack_cache.get(key)
-                if cached is not None and cached[0] == fp:
+                if cached is None:
+                    return None
+                fresh = cached[0] == fp
+                if fresh:
                     self._stack_cache[key] = self._stack_cache.pop(key)  # LRU touch
                     self.counters["stack_hits"] += 1
-                    return cached[1]
-            return None
+            if not fresh:
+                cells = self._changed(
+                    journals, cached[0], fp, where().values())
+                if cells is None or any(cells):
+                    return None
+                self._republish(self._stack_cache, key, cached, fp,
+                                "stack_republished")
+            return cached[1]
 
         stacked = self._gate(("stack", key), probe)
         if stacked is not None:
@@ -1346,10 +1432,13 @@ class ShardedQueryEngine:
             # every member and restacking the whole (U, S, W) tensor.
             with self._lock:
                 stale = self._stack_cache.get(key)
+                # Read before the members' planes, which are so at least
+                # as new as the stamp the stack will carry.
+                fp = self._stamps(journals)
             if stale is not None:
                 with obs_span("gather", kind="stack-delta") as sp:
                     stacked = self._stack_delta(
-                        key, index, leaves, shards, stale, fp)
+                        key, stale, journals, fp, where())
                     if sp is not NOP_SPAN:
                         sp.tag(applied=stacked is not None)
                 if stacked is not None:
@@ -1428,7 +1517,7 @@ class ShardedQueryEngine:
                 self._memo[key] = self._memo.pop(key)  # LRU touch
                 self.counters["memo_hits"] += 1
                 return ent[2], (key, ent[0], epoch)
-        fp = tuple(self._fingerprint(index, leaf, shards) for leaf in comp.leaves)
+        fp = self._fingerprint(index, comp.leaves)
         token = (key, fp, epoch)
         with self._lock:
             ent = self._memo.get(key)
@@ -2103,11 +2192,9 @@ class ShardedQueryEngine:
         mkey = ("topn_shard", index, field, tuple(canon_rows), shards,
                 src_sig, tuple(comp.leaves) if comp else None,
                 need_row_counts)
-        fp = tuple(self._fingerprint(index, leaf, shards) for leaf in leaves)
+        fp = self._fingerprint(index, leaves)
         if comp is not None:
-            fp = fp + tuple(
-                self._fingerprint(index, leaf, shards) for leaf in comp.leaves
-            )
+            fp = fp + self._fingerprint(index, comp.leaves)
 
         def answer(value):
             row_counts, inter, src_counts = value
@@ -2219,11 +2306,9 @@ class ShardedQueryEngine:
         mkey = ("topn_total", index, field, tuple(row_ids), shards, src_sig,
                 tuple(comp0.leaves) if comp0 else None)
         leaves_fp = [Leaf(field, VIEW_STANDARD, r) for r in row_ids]
-        fp = tuple(self._fingerprint(index, leaf, shards) for leaf in leaves_fp)
+        fp = self._fingerprint(index, leaves_fp)
         if comp0 is not None:
-            fp = fp + tuple(
-                self._fingerprint(index, leaf, shards) for leaf in comp0.leaves
-            )
+            fp = fp + self._fingerprint(index, comp0.leaves)
         hit = self._aux_probe(mkey, fp)
         if hit is not None:
             return hit[sel]
@@ -2301,11 +2386,9 @@ class ShardedQueryEngine:
         # host-only work (the val-count outputs are tiny).
         mkey = ("bsi", index, field, kind, bit_depth, shards, fsig,
                 tuple(comp.leaves) if comp else None)
-        fp = tuple(self._fingerprint(index, leaf, shards) for leaf in leaves)
+        fp = self._fingerprint(index, leaves)
         if comp is not None:
-            fp = fp + tuple(
-                self._fingerprint(index, leaf, shards) for leaf in comp.leaves
-            )
+            fp = fp + self._fingerprint(index, comp.leaves)
         hit = self._aux_probe(mkey, fp)
         if hit is not None:
             return hit

@@ -15,7 +15,8 @@ import pytest
 
 from pilosa_tpu.constants import SHARD_WIDTH, WORDS_PER_ROW
 from pilosa_tpu.core.field import FieldOptions
-from pilosa_tpu.core.fragment import Fragment, WriteEpoch
+from pilosa_tpu.core.fragment import (
+    ALL_ROWS, ChangeJournal, Fragment, WriteEpoch)
 from pilosa_tpu.core.holder import Holder
 from pilosa_tpu.parallel import EngineConfig
 from pilosa_tpu.parallel.engine import Leaf, ShardedQueryEngine
@@ -191,6 +192,60 @@ def test_every_mutation_path_bumps_generation_and_epoch(name):
     assert epoch.value > e0, f"{name} did not bump write epoch"
 
 
+class _SpyEpoch(WriteEpoch):
+    """An epoch that notes, at every bump, how far the fragment's
+    generation and its view's journal have come."""
+
+    def __init__(self, frag):
+        super().__init__()
+        self.frag, self.seen = frag, []
+
+    def bump(self):
+        self.seen.append((self.frag.generation, self.frag.journal.stamp[1]))
+        super().bump()
+
+
+@pytest.mark.parametrize("name", sorted(MUTATIONS))
+def test_every_mutation_path_tells_the_journal_before_the_epoch(name):
+    """What the engine's caches trust instead of asking every fragment:
+    each path notes in its view's journal, for every generation bump, its
+    shard and the row it changed (or ALL_ROWS) with the generation BEFORE
+    the bump, and the entry is there when the epoch moves. A path that
+    skips it leaves a resident plane of that row served as fresh."""
+    journal = ChangeJournal()
+    f = Fragment(None, "i", "f", "standard", 0, journal=journal)
+    f.open()
+    f.set_bit(0, 0)  # seed so clear_bit actually clears
+    rows = range(10)  # the paths' set rows and BSI planes between them
+    planes = {r: f.plane_np(r).copy() for r in rows}
+    g0, s0 = f.generation, journal.stamp
+    f.epoch = spy = _SpyEpoch(f)
+    MUTATIONS[name](f)
+    ents = journal.since(s0, journal.stamp)
+    assert ents, f"{name} told the journal nothing"
+    assert f.generation == g0 + len(ents)  # an entry a bump
+    for k, (seq, shard, row, fp) in enumerate(ents):
+        assert (seq, shard) == (s0[1] + k + 1, f.shard)
+        assert fp == (None if row == ALL_ROWS else (f.incarnation, g0 + k))
+    # Journal before epoch: at every bump of the epoch the journal had an
+    # entry for every bump of the generation so far.
+    assert spy.seen
+    assert all(gen - g0 == seq - s0[1] for gen, seq in spy.seen)
+    named = {row for _, _, row, _ in ents}
+    changed = {r for r in rows if not np.array_equal(planes[r], f.plane_np(r))}
+    assert changed, f"{name} changed no plane: the audit audits nothing"
+    assert ALL_ROWS in named or changed <= named
+    # And the words are where the entry says: since the generation it
+    # carries, the fragment's own journal has every changed word.
+    for _, _, row, fp in ents:
+        if row != ALL_ROWS:
+            words = f.dirty_words_since(row, fp[1])
+            if words is not None:  # None: a bulk path, regathered whole
+                w64 = f.plane_np(row).view(np.uint64)
+                was = planes[row].view(np.uint64)
+                assert set(np.flatnonzero(w64 != was)) <= set(words.tolist())
+
+
 # ---------------------------------------------------- engine delta refresh
 
 
@@ -321,11 +376,12 @@ def test_write_stream_moves_fewer_bytes_with_delta_on_than_off(holder):
     assert on["full_refresh_bytes"] == 0 and on["stack_delta_hits"] > 0
     assert 0 < on["delta_bytes"] <= batches * writes * 64
     assert off["delta_bytes"] == 0 and off["stack_delta_hits"] == 0
-    # A write to `f` stales every resident leaf of `f`: with no delta
-    # every batch walks and uploads all eight planes again, as the cold
-    # batch did.
+    # A write to `f` stales every resident leaf of `f`, and the view's
+    # journal says which rows: with no delta every batch walks and uploads
+    # again the four planes its burst wrote to, as the cold batch did all
+    # eight, and republishes the other four as they stand.
     assert cold_bytes >= n_rows * n_shards * WORDS_PER_ROW * 4
-    assert off["full_refresh_bytes"] == batches * cold_bytes
+    assert off["full_refresh_bytes"] == batches * writes * cold_bytes // n_rows
     assert on["delta_bytes"] < off["full_refresh_bytes"]
     fresh = ShardedQueryEngine(holder)
     try:
