@@ -301,6 +301,14 @@ class Executor:
         # (_execute_topn_shards): plain bumps, no lock, the same group.
         self.topn_array_walks = 0
         self.topn_shard_replays = 0
+        # Of the candidate phase (the phase-1 runner) alone, bumped together
+        # once a call has its answer: calls that handed candidates to the
+        # device, the programs they launched (one a chunk of _topn_chunk
+        # rows) and the rows in them. The refetch of the winners is one
+        # program more and is in none of the three.
+        self.topn_queries = 0
+        self.topn_chunks = 0
+        self.topn_candidate_rows = 0
         # How long a write caught in a live-rebalance cutover window
         # (ShardMovedError locally, 409 from a frozen remote owner) keeps
         # re-routing while the commit broadcast lands, before surfacing a
@@ -1551,21 +1559,24 @@ class Executor:
                 )
                 # inter is never None here: this branch requires a
                 # supported src_call. One (rows, shards) mask, one sum.
-                count = np.asarray(inter, np.int64)
-                cnt = np.asarray(row_counts, np.int64) if need_rc else count
-                keep = (cnt > 0) & (count > 0)
-                if tanimoto:
-                    keep &= _tanimoto_passes(
-                        count, cnt, np.asarray(src_counts, np.int64)[None, :],
-                        tanimoto)
-                else:
-                    keep &= (cnt >= thr) & (count >= thr)
-                totals = np.where(keep, count, 0).sum(axis=1).tolist()
-                # add_pairs: an id named twice counts twice, as it does on
-                # the per-shard rung.
-                return add_pairs([], [
-                    Pair(id=r, count=t) for r, t in zip(run_ids, totals) if t
-                ])
+                with obs_span("topn.replay", rows=len(run_ids),
+                              shards=len(local_shards)):
+                    count = np.asarray(inter, np.int64)
+                    cnt = np.asarray(row_counts, np.int64) if need_rc else count
+                    keep = (cnt > 0) & (count > 0)
+                    if tanimoto:
+                        keep &= _tanimoto_passes(
+                            count, cnt,
+                            np.asarray(src_counts, np.int64)[None, :], tanimoto)
+                    else:
+                        keep &= (cnt >= thr) & (count >= thr)
+                    totals = np.where(keep, count, 0).sum(axis=1).tolist()
+                    # add_pairs: an id named twice counts twice, as it does
+                    # on the per-shard rung.
+                    return add_pairs([], [
+                        Pair(id=r, count=t)
+                        for r, t in zip(run_ids, totals) if t
+                    ])
 
         elif (
             src_call is not None
@@ -1585,24 +1596,30 @@ class Executor:
             # filtering is a host-side candidate check, once per row.
             # The host half visits no (row, shard) cell in a statement of
             # its own: 74 ms a TopN at 256 shards when it did (PERF.md,
-            # PR 34).
+            # PR 34). Its stages are spans of their own (topn.rank, one
+            # topn.chunk a device program, topn.replay), so that the
+            # fan-out's self time holds none of it.
             n_arg, _ = c.uint_arg("n")
 
             def local_runner(local_shards):
-                shard_list, rankings = [], []
-                for s in local_shards:
-                    frag = self._fragment(index, field_name, VIEW_STANDARD, s)
-                    if frag is not None:
-                        shard_list.append(s)
-                        rankings.append(frag.top_arrays())
-                rank_ids, rank_cnt = _rank_matrix(rankings)
-                # Candidate rules of Fragment._filter_candidates as masks
-                # (tanimoto's bounds wait for src's counts: _replay_topn).
-                cand = rank_cnt > 0 if tanimoto else rank_cnt >= thr
-                union = np.unique(rank_ids[cand])
-                if attr_name and attr_values:
-                    union = np.asarray(attr_rows(union.tolist()), np.int64)
-                    cand &= np.isin(rank_ids, union)
+                with obs_span("topn.rank", shards=len(local_shards)) as sp:
+                    shard_list, rankings = [], []
+                    for s in local_shards:
+                        frag = self._fragment(
+                            index, field_name, VIEW_STANDARD, s)
+                        if frag is not None:
+                            shard_list.append(s)
+                            rankings.append(frag.top_arrays())
+                    rank_ids, rank_cnt = _rank_matrix(rankings)
+                    # Candidate rules of Fragment._filter_candidates as
+                    # masks (tanimoto's bounds wait for src's counts:
+                    # _replay_topn).
+                    cand = rank_cnt > 0 if tanimoto else rank_cnt >= thr
+                    union = np.unique(rank_ids[cand])
+                    if attr_name and attr_values:
+                        union = np.asarray(attr_rows(union.tolist()), np.int64)
+                        cand &= np.isin(rank_ids, union)
+                    sp.tag(rows=len(union))
                 if not len(union):
                     return []
                 chunks = []
@@ -1614,33 +1631,43 @@ class Executor:
                         # (503) instead of finishing dead device work.
                         self._check_chunk_deadline(
                             opt.deadline, "between TopN chunks")
+                    rows = union[i : i + CHUNK].tolist()
                     # Ranking uses the cache counts already attached to the
                     # candidates; the device program only computes the src
                     # intersections (need_row_counts=False).
-                    _, inter, src_counts = self._topn_counts_laddered(
-                        index, field_name, union[i : i + CHUNK].tolist(),
-                        shard_list, src_call, False,
-                    )
+                    with obs_span("topn.chunk", rows=len(rows),
+                                  shards=len(shard_list)):
+                        _, inter, src_counts = self._topn_counts_laddered(
+                            index, field_name, rows, shard_list, src_call,
+                            False,
+                        )
                     chunks.append(inter)
-                inter = np.concatenate(chunks)
-                # (union, shards) -> each shard's rank order. A cell that is
-                # no candidate reads some row's count, which nothing uses.
-                row_of = np.minimum(
-                    np.searchsorted(union, rank_ids), len(union) - 1)
-                count = np.asarray(inter, np.int64)[
-                    row_of, np.arange(len(shard_list))[:, None]]
-                accepted = _replay_topn(
-                    rank_cnt, count, cand, np.asarray(src_counts, np.int64),
-                    n_arg, thr, tanimoto)
-                # Every accepted count is over 0, so a row some shard
-                # accepted has a total over 0: what add_pairs over the
-                # shards' pair lists gave.
-                totals = np.zeros(len(union), np.int64)
-                np.add.at(totals, row_of[accepted], count[accepted])
-                return [
-                    Pair(id=r, count=t)
-                    for r, t in zip(union.tolist(), totals.tolist()) if t
-                ]
+                with obs_span("topn.replay", rows=len(union),
+                              shards=len(shard_list)):
+                    inter = np.concatenate(chunks)
+                    # (union, shards) -> each shard's rank order. A cell
+                    # that is no candidate reads some row's count, which
+                    # nothing uses.
+                    row_of = np.minimum(
+                        np.searchsorted(union, rank_ids), len(union) - 1)
+                    count = np.asarray(inter, np.int64)[
+                        row_of, np.arange(len(shard_list))[:, None]]
+                    accepted = _replay_topn(
+                        rank_cnt, count, cand,
+                        np.asarray(src_counts, np.int64), n_arg, thr, tanimoto)
+                    # Every accepted count is over 0, so a row some shard
+                    # accepted has a total over 0: what add_pairs over the
+                    # shards' pair lists gave.
+                    totals = np.zeros(len(union), np.int64)
+                    np.add.at(totals, row_of[accepted], count[accepted])
+                    pairs = [
+                        Pair(id=r, count=t)
+                        for r, t in zip(union.tolist(), totals.tolist()) if t
+                    ]
+                self.topn_queries += 1
+                self.topn_chunks += len(chunks)
+                self.topn_candidate_rows += len(union)
+                return pairs
 
         if local_runner is not None:
             # Last rung for a batch neither the device nor the host

@@ -1013,6 +1013,11 @@ class Handler:
                 # such a runner handed to the per-shard rung instead.
                 "topn_array_walks": executor.topn_array_walks,
                 "topn_shard_replays": executor.topn_shard_replays,
+                # The candidate phase of filtered TopNs: calls answered,
+                # device programs (chunks) launched, rows in them.
+                "topn_queries": executor.topn_queries,
+                "topn_chunks": executor.topn_chunks,
+                "topn_candidate_rows": executor.topn_candidate_rows,
             }
         # Ingest health (docs/ingest.md): un-snapshotted WAL bytes across
         # fragments, background-snapshot counters and queue depth, and how
