@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..constants import BITS_PER_WORD, SHARD_WIDTH
+from ..constants import BITS_PER_WORD, SHARD_WIDTH, WORDS_PER_ROW
 
 # ------------------------------------------------------------- host packing
 
@@ -103,6 +103,46 @@ def row_counts(planes, filter_plane=None) -> jnp.ndarray:
     if filter_plane is not None:
         planes = jnp.bitwise_and(planes, filter_plane[None, :])
     return popcount(planes)
+
+
+# ------------------------------------------------------------ folded stacks
+#
+# A resident (U, S, W) stack of fewer than 8 shards a device is stored as
+# (U, S*k, W//k) (parallel/mesh.py stack_fold): the same words in the same
+# order, a shard's W words over k sublane rows. A program learns k from the
+# stack it is handed, and at k == 1 each of these returns its argument, so
+# the program traced is the unfolded one.
+
+
+def fold_of(stacked) -> int:
+    """k of a resident stack, read from its word axis."""
+    return WORDS_PER_ROW // stacked.shape[-1]
+
+
+def fold_planes(planes, k: int):
+    """(..., S, W) -> (..., S*k, W//k): the planes a program gets beside a
+    folded stack (a filter's (S, W) plane), brought to the stack's form."""
+    if k == 1:
+        return planes
+    *lead, s, w = planes.shape
+    return planes.reshape(*lead, s * k, w // k)
+
+
+def unfold_planes(planes, k: int):
+    """(..., S*k, W//k) -> (..., S, W): result planes back in the form
+    every caller of the engine knows."""
+    if k == 1:
+        return planes
+    *lead, sk, wk = planes.shape
+    return planes.reshape(*lead, sk // k, wk * k)
+
+
+def shard_sums(partials, k: int):
+    """(R, S*k) per-folded-row counts -> (R, S) per-shard counts."""
+    if k == 1:
+        return partials
+    r, sk = partials.shape
+    return jnp.sum(partials.reshape(r, sk // k, k), axis=2)
 
 
 # ----------------------------------------------------------------- BSI ops

@@ -60,7 +60,11 @@ def _gather_blocks(s: int, w: int, l: int):
 def batched_gather_expr_count(stacked, idxs, expr, interpret: bool):
     """Per-query fused gather+expr+popcount: (Q,) int32.
 
-    `stacked` is the resident (U, S, W) uint32 leaf stack, `idxs` is a tuple
+    `stacked` is the resident (U, S, W) uint32 leaf stack (or, where a
+    device holds fewer than 8 shards, the same words stored folded as
+    (U, S*k, W//k), parallel/mesh.py stack_fold: the kernel sums over
+    shards and words alike, so that is S*k shards of W//k words to it and
+    a full 8-row sublane tile a block), `idxs` is a tuple
     of L (Q,) int32 leaf-slot vectors (one per leaf position of the
     compiled expression), `expr` an elementwise jnp function over L planes
     (a canonical PQL set-op tree, docs/query-compiler.md). For query q the

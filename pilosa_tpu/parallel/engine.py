@@ -49,7 +49,9 @@ from .device_health import (
     COMPILE, DeviceDispatchError, DeviceDispatchTimeout, DevicePlaneHealth,
     OOM, classify_device_error,
 )
-from .mesh import SHARD_AXIS, default_mesh, pad_shards, shard_sharding
+from .mesh import (
+    SHARD_AXIS, default_mesh, pad_shards, shard_sharding, stack_fold,
+)
 
 
 def _place_compile_cache() -> None:
@@ -320,8 +322,11 @@ class ShardedQueryEngine:
         # the safe rung compares when the journal cannot say)
         self._leaf_cache: Dict[Tuple, Tuple[Tuple, jax.Array, Tuple]] = {}
         self._leaf_bytes = 0
-        # (index, leaves, shards, U) -> (fingerprint, stacked (U, S, W) array)
-        self._stack_cache: Dict[Tuple, Tuple[Tuple, jax.Array]] = {}
+        # (index, leaves, shards, U) -> (fingerprint, stacked (U, S, W) array,
+        # kept as (U, S*k, W//k) where stack_fold(S, devices) is k > 1;
+        # {view: {row: [(u,), ...]}}, where each row lies in it: what a
+        # stale entry asks its journals about, worked out once a build)
+        self._stack_cache: Dict[Tuple, Tuple[Tuple, jax.Array, Dict]] = {}
         self._stack_bytes = 0
         # Device-cache budgets (bytes, LRU-evicted). The stacked tensors
         # duplicate the per-leaf planes they're built from, so both caches
@@ -376,6 +381,12 @@ class ShardedQueryEngine:
         # phase-2 refetch hit here. Bounded by entries (values are small
         # (R,S) host arrays); shares the memo hit/miss counters.
         self._aux_memo: Dict[Tuple, Tuple[Tuple, object]] = {}
+        # (field, the requested row ids' bytes) -> _topn_rows' answer. A
+        # TopN over thousands of rows asks for the same chunks of 512 at
+        # every query, so the per-row Python of a launch (canonical order,
+        # a Leaf a row) is paid once a chunk and not once a launch.
+        # Dropped whole when it outgrows its bound: no lock, no LRU walk.
+        self._topn_rows_memo: Dict[Tuple, Tuple] = {}
         self._aux_budget = budget(
             "PILOSA_AUX_MEMO_ENTRIES", config.aux_memo_entries, 512)
         # Effective cache bounds after env > config > tier > default
@@ -434,6 +445,10 @@ class ShardedQueryEngine:
             # count_dispatches): the only outside evidence of which of
             # the two served a coalesced batch.
             "gather_kernel_dispatches": 0,
+            # Launches over a resident stack that is kept folded onto the
+            # sublanes (parallel/mesh.py stack_fold: fewer than 8 shards a
+            # device). 0 for good from 8 shards a device up.
+            "folded_launches": 0,
             # Delta-refresh accounting: delta hits refreshed a stale
             # resident tensor with a scattered update (delta_bytes of
             # host->device traffic) instead of a full host walk + re-upload
@@ -498,15 +513,18 @@ class ShardedQueryEngine:
         return -1 if idx is None else idx.write_epoch.value
 
     def _note_launch(self, planes, counter: Optional[str] = None,
-                     kernel: bool = False) -> None:
+                     kernel: bool = False, stack=None) -> None:
         """One device-program launch over `planes`, the resident arrays
         handed to it; `counter` is the launch counter of its family (the
-        TopN and BSI programs have none)."""
+        TopN and BSI programs have none); `stack` is the resident stack
+        among them, where the program reads one."""
         nbytes = sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(planes))
+        folded = stack is not None and bp.fold_of(stack) > 1
         with self._lock:
             if counter is not None:
                 self.counters[counter] += 1
             self.counters["plane_bytes_read"] += nbytes
+            self.counters["folded_launches"] += folded
             self.counters["gather_kernel_dispatches"] += kernel
             self.counters["mesh_launches"] += self.n_devices > 1
 
@@ -1294,14 +1312,17 @@ class ShardedQueryEngine:
             )
         return new_arr
 
-    def _stack_delta(self, key, stale, journals, fp: Tuple, by_view: Dict):
+    def _stack_delta(self, key, stale, journals, fp: Tuple):
         """Refresh a stale (U, S, W) stack up to `fp` with one scattered
         update of the cells the journals name — no host walk, no member
         re-gather, no restack. None = full rebuild: also where a journal
         cannot say, for then each member plane finds out for itself
-        (_gather_leaf and its safe rung) and the stack is built of them."""
+        (_gather_leaf and its safe rung) and the stack is built of them.
+        In a stack kept folded as (U, S*k, W//k) word c of shard r lies at
+        [u, r*k + c // (W//k), c % (W//k)]: the same scatter, addressed
+        so on the host's small update arrays."""
         index, leaves, shards, _ = key
-        old_fp, arr = stale
+        old_fp, arr, by_view = stale
         if self._delta_max_fraction <= 0:
             return None
         cells = self._changed(journals, old_fp, fp, by_view.values())
@@ -1333,6 +1354,9 @@ class ShardedQueryEngine:
                 np.concatenate([c for _, c, _ in updates]),
                 np.concatenate([v for _, _, v in updates]),
             ])
+            k, wk = bp.fold_of(arr), arr.shape[2]
+            if k > 1:
+                rows, cols = rows * k + cols // wk, cols % wk
             sig = ("stack_delta", arr.shape, len(us))
             def stack_delta_scatter(a, u, r, c, v):
                 return a.at[u, r, c].set(v)
@@ -1349,7 +1373,7 @@ class ShardedQueryEngine:
             self.counters["delta_bytes"] += moved
             self.counters["h2d_bytes"] += moved
             self._stack_bytes = self._byte_cache_put(
-                self._stack_cache, key, (fp, new_arr),
+                self._stack_cache, key, (fp, new_arr, by_view),
                 self._stack_budget, self._stack_bytes, "stack_evictions",
             )
         return new_arr
@@ -1365,7 +1389,14 @@ class ShardedQueryEngine:
         pad_pow2: bool = False,
     ) -> jax.Array:
         """One resident (U, S, W) device tensor for a leaf list, rebuilt only
-        when a member fragment's generation changes.
+        when a member fragment's generation changes. Where a device holds
+        fewer than 8 of the S shards it is STORED as (U, S*k, W//k), k =
+        stack_fold(S, devices): the same words in the same order, each
+        shard's over k sublane rows, because the chip lays a short shard
+        axis out one sublane in eight (parallel/mesh.py stack_fold). The
+        programs that read it take k from its shape (ops/bitplane.py
+        fold_of) and reduce accordingly; from 8 shards a device up k is 1
+        and nothing differs.
 
         Serving latency for batched queries is dominated by per-call host
         work, not device FLOPs: passing one argument per leaf (dozens of
@@ -1383,27 +1414,19 @@ class ShardedQueryEngine:
             stacked, kind = self._stack_get_or_build(
                 index, leaves, shards, n, np2)
             if sp is not NOP_SPAN:
-                sp.tag(kind=kind)
+                sp.tag(kind=kind, fold=bp.fold_of(stacked))
         return stacked
 
     def _stack_get_or_build(self, index: str, leaves: List[Leaf],
                             shards: Tuple[int, ...], n: int, np2: int):
-        """(the (np2, S, W) stack, how it was come by). A stale stack
-        asks the journals of its views what was written since its
-        fingerprint, as a stale leaf does (_gather_leaf)."""
+        """(the (np2, S, W) stack, folded where stack_fold says so; how it
+        was come by). A stale stack asks the journals of its views what
+        was written since its fingerprint, as a stale leaf does
+        (_gather_leaf)."""
         key = (index, tuple(leaves), shards, np2)
         # The views in the fingerprint's order, and the journal of each.
         views = dict.fromkeys((leaf.field, leaf.view) for leaf in leaves)
         journals = tuple(self._journal(index, f, v) for f, v in views)
-
-        def where():
-            """{view: {row: [(u,), ...]}}: where each row of each view
-            lies in the stack. Only a stale stack needs it."""
-            by_view: Dict[Tuple, Dict[int, List]] = {v: {} for v in views}
-            for u, leaf in enumerate(leaves):
-                by_view[(leaf.field, leaf.view)].setdefault(
-                    leaf.row, []).append((u,))
-            return by_view
 
         def probe():
             with self._lock:
@@ -1417,7 +1440,7 @@ class ShardedQueryEngine:
                     self.counters["stack_hits"] += 1
             if not fresh:
                 cells = self._changed(
-                    journals, cached[0], fp, where().values())
+                    journals, cached[0], fp, cached[2].values())
                 if cells is None or any(cells):
                     return None
                 self._republish(self._stack_cache, key, cached, fp,
@@ -1437,8 +1460,7 @@ class ShardedQueryEngine:
                 fp = self._stamps(journals)
             if stale is not None:
                 with obs_span("gather", kind="stack-delta") as sp:
-                    stacked = self._stack_delta(
-                        key, stale, journals, fp, where())
+                    stacked = self._stack_delta(key, stale, journals, fp)
                     if sp is not NOP_SPAN:
                         sp.tag(applied=stacked is not None)
                 if stacked is not None:
@@ -1449,20 +1471,33 @@ class ShardedQueryEngine:
             arrs = arrs + [arrs[0]] * (np2 - n)
             with self._lock:
                 if self._stack_jit is None:
-                    def restack_planes(xs):
-                        return jnp.stack(xs)
+                    # The reshape rides inside the program, so a folded
+                    # stack is born folded: a device's block stays its
+                    # own shards' words and no second copy is resident.
+                    def restack_planes(xs, fold):
+                        return bp.fold_planes(jnp.stack(xs), fold)
 
                     self._stack_jit = jax.jit(
-                        restack_planes,
+                        restack_planes, static_argnums=1,
                         out_shardings=shard_sharding(self.mesh, 3, axis=1),
                     )
                 stack_jit = self._stack_jit
-            stacked = self._oom_guard(None, lambda: stack_jit(tuple(arrs)))
+            fold = stack_fold(len(shards), self.n_devices)
+            stacked = self._oom_guard(
+                None, lambda: stack_jit(tuple(arrs), fold))
+            # {view: {row: [(u,), ...]}}: where each row of each view lies
+            # in the stack. Kept with the entry: only a stale stack asks,
+            # and one of hundreds of rows is stale after every write to
+            # its view.
+            by_view: Dict[Tuple, Dict[int, List]] = {v: {} for v in views}
+            for u, leaf in enumerate(leaves):
+                by_view[(leaf.field, leaf.view)].setdefault(
+                    leaf.row, []).append((u,))
             with self._lock:
                 self.counters["stack_misses"] += 1
                 self.counters["restack_bytes"] += int(stacked.nbytes)
                 self._stack_bytes = self._byte_cache_put(
-                    self._stack_cache, key, (fp, stacked),
+                    self._stack_cache, key, (fp, stacked, by_view),
                     self._stack_budget, self._stack_bytes, "stack_evictions",
                 )
         finally:
@@ -2030,7 +2065,10 @@ class ShardedQueryEngine:
             else:
                 # XLA formulation: each leaf position is a gathered
                 # (Q, S, W) operand; partitions over a multi-device mesh
-                # by itself.
+                # by itself. Every leaf comes out of the stack and the
+                # sum runs over shards and words, so a folded stack needs
+                # nothing here (nor in the kernel, to which it is S*k
+                # shards of W//k words).
                 def counts_of(stacked, idxs):
                     leaves = tuple(stacked[ix] for ix in idxs)  # each (Q, S, W)
                     plane = expr(leaves)
@@ -2049,7 +2087,8 @@ class ShardedQueryEngine:
 
         hsig = comps[0][0].plan.sig_tuple
         fn = self._fn_build(self._count_fns, sig, build, health_sig=hsig)
-        self._note_launch(stacked, "count_dispatches", kernel=use_kernel)
+        self._note_launch(stacked, "count_dispatches", kernel=use_kernel,
+                          stack=stacked)
         if inv_in is not None:
             return self._device_call(hsig, lambda: fn(stacked, idxs, inv_in))
         return self._device_call(hsig, lambda: fn(stacked, idxs))
@@ -2134,12 +2173,12 @@ class ShardedQueryEngine:
             @jax.jit
             def bitmap_batch_expr(stacked, idxs):
                 leaves = tuple(stacked[ix] for ix in idxs)  # each (Qp, S, W)
-                return expr(leaves)
+                return bp.unfold_planes(expr(leaves), bp.fold_of(stacked))
 
             return bitmap_batch_expr
 
         fn = self._fn_build(self._bitmap_fns, sig, build, health_sig=hsig)
-        self._note_launch(stacked, "bitmap_dispatches")
+        self._note_launch(stacked, "bitmap_dispatches", stack=stacked)
         # block_until_ready inside the guard, like bitmap(): an async
         # device fault must classify here, not inside a later Row op.
         with obs_span("engine.device_wait"):
@@ -2151,6 +2190,30 @@ class ShardedQueryEngine:
                  for i, shard in enumerate(shards)})
             for qi in range(n_calls)
         ]
+
+    _TOPN_ROWS_MEMO_ENTRIES = 512
+
+    def _topn_rows(self, field: str, row_ids: Sequence[int]):
+        """(rows, leaves, sel) of a TopN launch over `row_ids`: the row
+        ids in canonical (sorted, deduped) order as a tuple, a Leaf of
+        `field`'s standard view for each, and where each requested id
+        lies among them. The stacked tensor and the result memos are
+        keyed on the canonical order, so TopN phase-1 (first-seen
+        candidate order) and the phase-2 refetch (sorted ids) share one
+        device tensor and one memo entry instead of duplicating both."""
+        req = np.asarray(row_ids, dtype=np.int64)
+        mkey = (field, req.tobytes())
+        hit = self._topn_rows_memo.get(mkey)
+        if hit is None:
+            canon = np.unique(req)
+            rows = tuple(canon.tolist())
+            hit = (rows,
+                   tuple(Leaf(field, VIEW_STANDARD, r) for r in rows),
+                   np.searchsorted(canon, req))  # canonical -> requested
+            if len(self._topn_rows_memo) >= self._TOPN_ROWS_MEMO_ENTRIES:
+                self._topn_rows_memo.clear()
+            self._topn_rows_memo[mkey] = hit
+        return hit
 
     def topn_shard_counts(
         self, index: str, field: str, row_ids: Sequence[int],
@@ -2172,27 +2235,25 @@ class ShardedQueryEngine:
         cache counts and phase-2 at threshold<=1 needs only intersections,
         so the common TopN query never pays for the (R, S, W) popcount —
         only the fused AND+popcount program over the resident stack.
+
+        The resident stack is (Rp, S, W), stored as (Rp, S*k, W//k) where
+        a device holds fewer than 8 shards (_stacked_leaf_tensor): both
+        programs then sum a shard's k folded rows, and what they return
+        is (R, S) either way.
         """
         shards = tuple(shards)
-        # Canonical (sorted, deduped) row order: the stacked tensor and the
-        # result memo are keyed on it, so TopN phase-1 (first-seen candidate
-        # order) and the phase-2 refetch (sorted ids) share one device
-        # tensor and one memo entry instead of duplicating both.
-        req = np.asarray(row_ids, dtype=np.int64)
-        canon = np.unique(req)
-        sel = np.searchsorted(canon, req)  # canonical -> requested order
-        canon_rows = [int(r) for r in canon]
+        canon_rows, leaves, sel = self._topn_rows(field, row_ids)
         s_real = len(shards)
-        leaves = [Leaf(field, VIEW_STANDARD, r) for r in canon_rows]
         src_sig = None
         comp = expr = None
         if src_call is not None:
             comp, expr = self._compile(index, src_call)
             src_sig = tuple(comp.signature)
-        mkey = ("topn_shard", index, field, tuple(canon_rows), shards,
+        mkey = ("topn_shard", index, field, canon_rows, shards,
                 src_sig, tuple(comp.leaves) if comp else None,
                 need_row_counts)
-        fp = self._fingerprint(index, leaves)
+        # Every leaf is of the one view: the first says what all would.
+        fp = self._fingerprint(index, leaves[:1])
         if comp is not None:
             fp = fp + self._fingerprint(index, comp.leaves)
 
@@ -2227,7 +2288,7 @@ class ShardedQueryEngine:
             # computed BEFORE the gather above; its first len(leaves)
             # entries are exactly the candidate-row fingerprints.
             rows_fp = fp[: len(leaves)]
-            rkey = ("topn_rows", index, field, tuple(canon_rows), shards)
+            rkey = ("topn_rows", index, field, canon_rows, shards)
             row_counts = self._aux_probe(rkey, rows_fp)
             if row_counts is None:
                 sig = ("topn_shard", len(shards), rows_tensor.shape[0])
@@ -2235,14 +2296,14 @@ class ShardedQueryEngine:
                 def build():
                     @jax.jit
                     def topn_shard_row_counts(stacked):
-                        return jnp.sum(
+                        return bp.shard_sums(jnp.sum(
                             jax.lax.population_count(stacked).astype(jnp.int32), axis=2
-                        )
+                        ), bp.fold_of(stacked))
 
                     return topn_shard_row_counts
 
                 fn = self._fn_build(self._count_fns, sig, build)
-                self._note_launch(rows_tensor)
+                self._note_launch(rows_tensor, stack=rows_tensor)
                 with obs_span("engine.device_wait"):
                     row_counts = self._device_call(
                         None,
@@ -2256,16 +2317,20 @@ class ShardedQueryEngine:
             def build():
                 @jax.jit
                 def topn_shard_src_counts(stacked, src_lv):
+                    k = bp.fold_of(stacked)
                     src = expr(src_lv)
                     src_counts = jnp.sum(
                         jax.lax.population_count(src).astype(jnp.int32), axis=1
                     )
                     # AND+popcount+reduce fuses into one pass over the
-                    # stack — the masked plane is never materialized.
-                    masked = jnp.bitwise_and(stacked, src[None, :, :])
-                    inter = jnp.sum(
+                    # stack — the masked plane is never materialized. The
+                    # (S, W) filter is brought to a folded stack's form
+                    # (128 KiB a shard); the stack is read as it lies.
+                    masked = jnp.bitwise_and(
+                        stacked, bp.fold_planes(src, k)[None, :, :])
+                    inter = bp.shard_sums(jnp.sum(
                         jax.lax.population_count(masked).astype(jnp.int32), axis=2
-                    )
+                    ), k)
                     return inter, src_counts
 
                 return topn_shard_src_counts
@@ -2277,7 +2342,7 @@ class ShardedQueryEngine:
                 return (np.asarray(inter)[:r_real, :s_real],
                         np.asarray(src_counts)[:s_real])
 
-            self._note_launch((rows_tensor, src_leaves))
+            self._note_launch((rows_tensor, src_leaves), stack=rows_tensor)
             with obs_span("engine.device_wait"):
                 inter, src_counts = self._device_call(None, run)
             value = (row_counts, inter, src_counts)
@@ -2294,25 +2359,20 @@ class ShardedQueryEngine:
         one batched program — the distributed TopN inner loop. Canonical
         row ordering + the composite-result memo, as topn_shard_counts."""
         shards = tuple(shards)
-        req = np.asarray(row_ids, dtype=np.int64)
-        canon = np.unique(req)
-        sel = np.searchsorted(canon, req)
-        row_ids = [int(r) for r in canon]
+        row_ids, leaves, sel = self._topn_rows(field, row_ids)
         src_sig = None
         comp0 = expr0 = None
         if src_call is not None:
             comp0, expr0 = self._compile(index, src_call)
             src_sig = tuple(comp0.signature)
-        mkey = ("topn_total", index, field, tuple(row_ids), shards, src_sig,
+        mkey = ("topn_total", index, field, row_ids, shards, src_sig,
                 tuple(comp0.leaves) if comp0 else None)
-        leaves_fp = [Leaf(field, VIEW_STANDARD, r) for r in row_ids]
-        fp = self._fingerprint(index, leaves_fp)
+        fp = self._fingerprint(index, leaves[:1])
         if comp0 is not None:
             fp = fp + self._fingerprint(index, comp0.leaves)
         hit = self._aux_probe(mkey, fp)
         if hit is not None:
             return hit[sel]
-        leaves = leaves_fp
         # pad_pow2: candidate-id counts vary per query; see topn_shard_counts.
         rows_tensor = self._stacked_leaf_tensor(index, leaves, shards,
                                                 pad_pow2=True)  # (Rp, S, W)
@@ -2326,7 +2386,8 @@ class ShardedQueryEngine:
             def build():
                 @jax.jit
                 def topn_src_counts(stacked, src_lv):
-                    src = expr(src_lv)  # (S, W)
+                    src = bp.fold_planes(
+                        expr(src_lv), bp.fold_of(stacked))  # (S, W)
                     masked = jnp.bitwise_and(stacked, src[None, :, :])
                     return jnp.sum(
                         jax.lax.population_count(masked).astype(jnp.int32), axis=(1, 2)
@@ -2335,7 +2396,7 @@ class ShardedQueryEngine:
                 return topn_src_counts
 
             fn = self._fn_build(self._count_fns, sig, build)
-            self._note_launch((rows_tensor, src_leaves))
+            self._note_launch((rows_tensor, src_leaves), stack=rows_tensor)
             with obs_span("engine.device_wait"):
                 value = self._device_call(
                     None,
@@ -2355,7 +2416,7 @@ class ShardedQueryEngine:
             return topn_counts
 
         fn = self._fn_build(self._count_fns, sig, build)
-        self._note_launch(rows_tensor)
+        self._note_launch(rows_tensor, stack=rows_tensor)
         with obs_span("engine.device_wait"):
             value = self._device_call(
                 None, lambda: np.asarray(fn(rows_tensor))[:r_real])
@@ -2408,7 +2469,8 @@ class ShardedQueryEngine:
                 def bsi_val_count(planes, flt):
                     stacked = planes  # (D+1, S, W)
                     if expr is not None:
-                        stacked = jnp.bitwise_and(stacked, expr(flt)[None])
+                        stacked = jnp.bitwise_and(stacked, bp.fold_planes(
+                            expr(flt), bp.fold_of(planes))[None])
                     return jnp.sum(
                         jax.lax.population_count(stacked).astype(jnp.int32),
                         axis=(1, 2),
@@ -2420,7 +2482,8 @@ class ShardedQueryEngine:
                 def bsi_val_count(planes, flt):
                     consider = planes[bit_depth]
                     if expr is not None:
-                        consider = jnp.bitwise_and(consider, expr(flt))
+                        consider = jnp.bitwise_and(consider, bp.fold_planes(
+                            expr(flt), bp.fold_of(planes)))
                     bits = []
                     for i in range(bit_depth - 1, -1, -1):
                         if maximize:
@@ -2450,7 +2513,8 @@ class ShardedQueryEngine:
             return (np.asarray(bits), int(count))
 
         self._note_launch(
-            planes if filter_leaves is None else (planes, filter_leaves))
+            planes if filter_leaves is None else (planes, filter_leaves),
+            stack=planes)
         with obs_span("engine.device_wait"):
             value = self._device_call(None, run)
         self._aux_store(mkey, fp, value)

@@ -14,11 +14,14 @@ deliberately 1-D.
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Sequence
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from ..constants import WORDS_PER_ROW
 
 SHARD_AXIS = "shards"
 
@@ -52,6 +55,27 @@ def pad_shards(n_shards: int, n_devices: int) -> int:
     if n_shards % n_devices == 0:
         return n_shards
     return ((n_shards // n_devices) + 1) * n_devices
+
+
+def stack_fold(n_shards: int, n_devices: int) -> int:
+    """Sublane rows a shard's W words are folded onto in a resident stack.
+
+    The chip tiles the last two axes of a uint32 array (8 sublanes, 128
+    lanes). A stack whose shard axis a device holds fewer than 8 long is
+    laid out `T(1,128)`, one sublane of eight in use a vector register,
+    and its fused reduce reads at an eighth of the speed; the layout
+    belongs to the array as it is STORED, so a reshape inside the reading
+    program changes nothing (docs/query-compiler.md, "The layout of a
+    stack"). Such a stack is therefore kept as (U, S*k, W//k) with k the
+    least factor that makes a device's S*k rows a multiple of 8: the same
+    bytes in the same order, word c of shard r at [r*k + c // (W//k),
+    c % (W//k)]. From 8 shards a device up the compiler fills the
+    sublanes by itself and k is 1. A function of the shard count a device
+    holds and of nothing else; W//k stays a multiple of the 128 lanes."""
+    s_local = pad_shards(n_shards, n_devices) // n_devices
+    if s_local >= 8:
+        return 1
+    return min(8 // math.gcd(s_local, 8), max(1, WORDS_PER_ROW // 128))
 
 
 def device_for_shard(shard_index: int, n_shards_padded: int, n_devices: int) -> int:

@@ -31,6 +31,14 @@ def holder(tmp_path):
     h.close()
 
 
+def unfolded(stack):
+    """A resident stack as the (U, S, W) array it stands for: one of fewer
+    than 8 shards a device is kept as (U, S*k, W//k)
+    (parallel/mesh.py stack_fold)."""
+    stack = np.asarray(stack)
+    return stack.reshape(stack.shape[0], -1, WORDS_PER_ROW)
+
+
 def plant(holder, n_shards=4, n_rows=4, per_row=300, seed=7):
     idx = holder.create_index_if_not_exists("i")
     fld = idx.create_field_if_not_exists("f")
@@ -424,7 +432,7 @@ def test_property_random_writes_delta_equals_full(holder):
     for round_ in range(8):
         mutate_once()
         # Delta-maintained tensors...
-        stack = np.asarray(
+        stack = unfolded(
             engine._stacked_leaf_tensor("i", leaves, shards, pad_pow2=True))
         plane = np.asarray(engine._gather_leaf("i", leaves[0], shards))
         # ...must equal a cold rebuild straight from storage.
@@ -511,7 +519,7 @@ def test_stack_delta_keeps_pad_rows_in_sync(holder):
     leaves = [Leaf("f", "standard", r) for r in range(3)]  # pads to 4
     engine._stacked_leaf_tensor("i", leaves, shards, pad_pow2=True)
     fld.set_bit(0, 12345)
-    stack = np.asarray(
+    stack = unfolded(
         engine._stacked_leaf_tensor("i", leaves, shards, pad_pow2=True))
     assert engine.counters["stack_delta_hits"] > 0
     assert stack.shape[0] == 4

@@ -36,7 +36,7 @@ from pilosa_tpu.parallel.engine import Leaf, ShardedQueryEngine
 from pilosa_tpu.pql.parser import parse
 from pilosa_tpu.translate import TranslateStore
 
-from .test_delta import MUTATIONS
+from .test_delta import MUTATIONS, unfolded
 from .test_misc import _FakeServer
 
 SHARDS = tuple(range(4))
@@ -629,7 +629,7 @@ def test_a_stale_stack_patches_the_named_cells_only(holder, engine, monkeypatch)
     monkeypatch.setattr(holder, "fragment",
                         lambda *a: touched.append(a[3]) or real(*a))
     c0 = dict(engine.counters)
-    got = np.asarray(engine._stacked_leaf_tensor(
+    got = unfolded(engine._stacked_leaf_tensor(
         "i", leaves, SHARDS, pad_pow2=True))
     assert sorted(touched) == [1, 3]
     want = np.stack([truth(holder, leaf) for leaf in leaves + leaves[:1]])
@@ -703,7 +703,7 @@ def test_where_the_journal_cannot_say_the_walk_does(
     assert grew(engine, c0, ("fp_walks", "leaf_republished")) == {
         "fp_walks": 1, "leaf_republished": 0}
     want = [truth(holder, leaf) for leaf in leaves]
-    stack = np.asarray(engine._stacked_leaf_tensor("i", leaves, SHARDS))
+    stack = unfolded(engine._stacked_leaf_tensor("i", leaves, SHARDS))
     np.testing.assert_array_equal(stack[:, :len(SHARDS)], np.stack(want))
     bits = [int(np.bitwise_count(w).sum()) for w in want]
     assert engine.count("i", call, SHARDS) == bits[0] > 0
@@ -727,7 +727,7 @@ def test_a_stack_whose_journal_cannot_say_is_built_again(
     _overflow(holder, fld)
     fld.set_bit(1, 99)
     c0 = dict(engine.counters)
-    got = np.asarray(engine._stacked_leaf_tensor("i", leaves, SHARDS))
+    got = unfolded(engine._stacked_leaf_tensor("i", leaves, SHARDS))
     np.testing.assert_array_equal(
         got[:, :len(SHARDS)], np.stack([truth(holder, l) for l in leaves]))
     # Its three member planes walk, each for itself; the stack does not.
@@ -819,7 +819,7 @@ def test_journal_and_forced_walk_agree_under_writers_and_readers(holder):
             plane = served(by_journal, leaves[row])
             check(plane, row, floor, set(tried))
             floor = set(acked)
-            stack = np.asarray(by_journal._stacked_leaf_tensor(
+            stack = unfolded(by_journal._stacked_leaf_tensor(
                 "i", leaves, SHARDS))[:, :len(SHARDS)]
             ceiling = set(tried)
             for r in rows:
@@ -868,7 +868,7 @@ def test_journal_and_forced_walk_agree_under_writers_and_readers(holder):
                 np.testing.assert_array_equal(served(by_walk, leaf), want)
             want = np.stack([truth(holder, leaf) for leaf in leaves])
             for eng in (by_journal, by_walk):
-                got = np.asarray(eng._stacked_leaf_tensor("i", leaves, SHARDS))
+                got = unfolded(eng._stacked_leaf_tensor("i", leaves, SHARDS))
                 np.testing.assert_array_equal(got[:, :len(SHARDS)], want)
             a = by_journal.topn_shard_counts("i", "f", rows, SHARDS, src)
             b = by_walk.topn_shard_counts("i", "f", rows, SHARDS, src)

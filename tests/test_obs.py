@@ -932,7 +932,8 @@ def test_coalesced_counts_show_the_leaders_launch(one_node):
     assert launch["parent"] == find_span(lead, "device.dispatch")["id"]
     stack = find_span(lead, "engine.stack")
     assert stack["parent"] == launch["id"]
-    assert stack["tags"] == {"planes": n, "kind": "restack"}
+    # One shard a device: the stack is kept folded onto the sublanes.
+    assert stack["tags"] == {"planes": n, "kind": "restack", "fold": 8}
     assert find_span(lead, "engine.device_wait")["parent"] == launch["id"]
     assert find_span(lead, "engine.fn_build")["tags"]["kind"] == \
         "count_batch_setops"
