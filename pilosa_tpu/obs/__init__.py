@@ -7,10 +7,11 @@ handler ingress (or is adopted from the X-Pilosa-Trace header a
 coordinator stamped), accumulates named stage spans — request, parse,
 sched.wait, batch.hold, executor.fanout, gather, device.dispatch,
 tier.promote, remote:<peer>, reduce — as a tree (each span names its
-parent and carries its self time) and lands in a bounded ring served by
-GET /debug/traces. Remote hops return the peer's own stage summary in a
-size-bounded X-Pilosa-Trace-Summary response header, spliced as child
-spans so a fan-out query yields ONE tree across nodes.
+parent and carries its self time and its thread's CPU time) and lands in
+a bounded ring served by GET /debug/traces. Remote hops return the peer's
+own stage summary in a size-bounded X-Pilosa-Trace-Summary response
+header, spliced as child spans so a fan-out query yields ONE tree across
+nodes.
 
 On top of the recorder: a slow-query log (over-threshold queries logged
 once with their full stage breakdown), per-stage log-bucketed latency

@@ -21,6 +21,16 @@ a second contextvar. A landed trace reckons each span's `self_ms` when it
 is first read: its length less what its direct children cover, so a
 layer's own cost can be told from what it waited for below it.
 
+CPU time: a span also reads its thread's CPU clock where it begins and
+ends, so `cpu_ms` says how much of `dur_ms` its thread ran and
+`self_ms - self_cpu_ms` how long it stood: parked, or runnable without
+the interpreter lock (docs/observability.md, "What a span's CPU says").
+That clock is a system call, and on some hosts a slow one (5.8 us a read
+on the TPU hosts of PERF.md's runs, 0.25 us elsewhere), so the recorder
+times it once and reads it in one trace of every `cpu_period`, all of
+that trace's spans or none: the reads then cost a span CPU_READ_BUDGET_S
+on average whatever the host.
+
 The profiler's clock: while a /debug/profile capture runs (`capturing`),
 every span also opens an annotation of its name in the profiler's trace,
 through the factory the server handed in at start-up (obs/ stays jax-free).
@@ -53,6 +63,29 @@ _open: contextvars.ContextVar[Optional["Span"]] = contextvars.ContextVar(
 _annotation = None
 # True while a /debug/profile capture runs: the one flag a span reads.
 capturing = False
+# The calling thread's CPU time in ns; None on a platform without one.
+THREAD_CPU_NS = getattr(time, "thread_time_ns", None)
+_thread_ident = threading.get_ident
+# What a span's two reads of the CPU clock may cost on average, in
+# seconds, against some 3 us to record the span (docs/observability.md,
+# "Overhead"): where two reads cost more, fewer traces read the clock.
+CPU_READ_BUDGET_S = 1e-6
+
+
+def cpu_sample_period(cpu_clock, clock) -> int:
+    """In one trace of how many the CPU clock is read, so that a span's
+    two reads cost CPU_READ_BUDGET_S on average: 1 (every trace) where
+    the clock is cheap. The cost is the least of a few short runs: a run
+    that another thread cut into says too much, never too little."""
+    if cpu_clock is None:
+        return 1
+    reads, cost = 16, float("inf")
+    for _ in range(5):
+        t0 = clock()
+        for _ in range(reads):
+            cpu_clock()
+        cost = min(cost, (clock() - t0) / reads)
+    return max(1, int(2.0 * cost / CPU_READ_BUDGET_S))
 # Spans that only park their thread. In the profiler's trace their name
 # ends in `wait`, which is how a reader of idle gaps tells a thread that
 # waited from the one that worked (`engine.device_wait` says so itself).
@@ -168,7 +201,7 @@ class Span:
 
     __slots__ = ("_trace", "name", "start_ms", "dur_ms", "tags", "children",
                  "_t0", "id", "parent", "self_ms", "amount", "_above",
-                 "_ann", "_closed")
+                 "_ann", "_closed", "cpu_ms", "self_cpu_ms", "_c0", "_tid")
 
     def __init__(self, trace: "Trace", name: str,
                  tags: Optional[Dict[str, Any]] = None,
@@ -180,12 +213,18 @@ class Span:
         self.start_ms = 0.0
         self.dur_ms = 0.0
         self.self_ms = 0.0
+        # CPU time of the thread that ran the span, between its two ends;
+        # None where they lie on two threads or there is no such clock.
+        self.cpu_ms: Optional[float] = None
+        self.self_cpu_ms: Optional[float] = None
         self.id = next(trace._ids)
         self.parent = parent.id if parent is not None else None
         # True for a span whose dur_ms is an amount and no interval
         # (`qos.charge`, a bill): it has no self time and covers nothing.
         self.amount = False
         self._t0 = None
+        self._c0 = None
+        self._tid = None
         self._above = None
         self._ann = None
         self._closed = False
@@ -205,7 +244,13 @@ class Span:
             self._ann = _annotation(name, trace=self._trace.trace_id,
                                     span=self.id)
             self._ann.__enter__()
-        self._t0 = self._trace._clock()
+        t = self._trace
+        self._t0 = t._clock()
+        # Read inside the monotonic interval, so cpu_ms <= dur_ms.
+        cpu = t._cpu_clock
+        if cpu is not None:
+            self._tid = _thread_ident()
+            self._c0 = cpu()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
@@ -213,6 +258,8 @@ class Span:
             return False
         self._closed = True
         t = self._trace
+        if self._c0 is not None and self._tid == _thread_ident():
+            self.cpu_ms = (t._cpu_clock() - self._c0) / 1e6
         now = t._clock()
         t0 = self._t0 if self._t0 is not None else now
         self.start_ms = (t0 - t._start) * 1000.0
@@ -282,6 +329,10 @@ class Span:
             "dur_ms": round(self.dur_ms, 3),
             "self_ms": round(self.self_ms, 3),
         }
+        if self.cpu_ms is not None:
+            out["cpu_ms"] = round(self.cpu_ms, 3)
+        if self.self_cpu_ms is not None:
+            out["self_cpu_ms"] = round(self.self_cpu_ms, 3)
         if self.tags:
             out["tags"] = dict(self.tags)
         if self.children:
@@ -298,17 +349,19 @@ class Trace:
     recorded from any thread (state is lock-protected)."""
 
     __slots__ = ("trace_id", "index", "pql", "adopted", "start_wall",
-                 "_start", "_clock", "spans", "duration_ms", "status",
-                 "finished", "spans_dropped", "tags", "_lock", "_ids",
-                 "_reckoned")
+                 "_start", "_clock", "_cpu_clock", "spans", "duration_ms",
+                 "status", "finished", "spans_dropped", "tags", "_lock",
+                 "_ids", "_reckoned")
 
     def __init__(self, trace_id: str, index: str = "", pql: str = "",
-                 adopted: bool = False, clock=time.monotonic):
+                 adopted: bool = False, clock=time.monotonic,
+                 cpu_clock=THREAD_CPU_NS):
         self.trace_id = trace_id
         self.index = index
         self.pql = pql
         self.adopted = adopted
         self._clock = clock
+        self._cpu_clock = cpu_clock
         self._start = clock()
         self.start_wall = time.time()
         self.spans: List[Span] = []
@@ -343,13 +396,15 @@ class Trace:
                amount: bool = False, **tags) -> None:
         """Append a pre-measured span ending now, under the span open on
         this context (or `parent`, for a caller on another thread).
-        `amount` marks a dur_ms that is a quantity and no interval."""
+        `amount` marks a dur_ms that is a quantity and no interval. Such a
+        span is a wait or an amount by construction: its cpu_ms is 0."""
         if parent is None:
             parent = _open.get()
             if parent is not None and parent._trace is not self:
                 parent = None
         sp = Span(self, name, tags or None, parent)
         sp.amount = amount
+        sp.cpu_ms = 0.0
         now = self._clock()
         sp.dur_ms = float(dur_ms)
         sp.start_ms = max(0.0, (now - self._start) * 1000.0 - sp.dur_ms)
@@ -449,12 +504,22 @@ def reckon_self_times(spans: List[Span]) -> None:
     """Set each span's self_ms: its length less the union of its direct
     children's intervals, each clipped to it. Where every span ran on the
     request's context the children of one parent do not overlap, and the
-    self times of a trace add up to its root's length."""
+    self times of a trace add up to its root's length.
+
+    And its self_cpu_ms: its cpu_ms less that of the direct children that
+    ran on its thread (one thread runs one span at a time, so nothing
+    overlaps); a child on another thread spent a CPU time of its own.
+    The self CPU times of a thread's spans add up to its outermost's."""
     kids: Dict[int, List[Span]] = {}
     for s in spans:
         if s.parent is not None and not s.amount:
             kids.setdefault(s.parent, []).append(s)
     for s in spans:
+        if s.cpu_ms is not None:
+            below = sum(k.cpu_ms for k in kids.get(s.id, ())
+                        if k.cpu_ms is not None and k._tid == s._tid)
+            # (max: the floats of three differences need not add up.)
+            s.self_cpu_ms = max(0.0, s.cpu_ms - below)
         if s.amount:
             s.self_ms = 0.0
             continue
@@ -474,13 +539,16 @@ class TraceRecorder:
     histograms + slow-query log. One per server process."""
 
     def __init__(self, config=None, stats=None, logger=None,
-                 clock=time.monotonic, seed: Optional[int] = None):
+                 clock=time.monotonic, seed: Optional[int] = None,
+                 cpu_clock=THREAD_CPU_NS):
         from . import ObsConfig
 
         self.config = config or ObsConfig()
         self.stats = stats
         self.logger = logger
         self.clock = clock
+        self.cpu_clock = cpu_clock
+        self.cpu_period = cpu_sample_period(cpu_clock, clock)
         self._lock = threading.Lock()
         # Seeded sampler: chaos runs pin the seed so the sampled
         # set replays bit-identically.
@@ -509,7 +577,17 @@ class TraceRecorder:
                 return None
             trace_id = f"{self._rng.getrandbits(64):016x}"
             self.counters["traces_started"] += 1
-        return Trace(trace_id, index=index, pql=pql, clock=self.clock)
+            cpu_clock = self._cpu_clock_for_next()
+        return Trace(trace_id, index=index, pql=pql, clock=self.clock,
+                     cpu_clock=cpu_clock)
+
+    def _cpu_clock_for_next(self):
+        """The CPU clock for one trace in every `cpu_period`, drawn (a
+        fixed stride could fall in step with a client's cycle of
+        queries); None for the others. Under the recorder's lock."""
+        if self.cpu_period > 1 and self._rng.randrange(self.cpu_period):
+            return None
+        return self.cpu_clock
 
     def adopt(self, header: str, index: str = "", pql: str = "",
               ) -> Optional[Trace]:
@@ -527,8 +605,9 @@ class TraceRecorder:
             return None
         with self._lock:
             self.counters["traces_adopted"] += 1
+            cpu_clock = self._cpu_clock_for_next()
         return Trace(trace_id, index=index, pql=pql, adopted=True,
-                     clock=self.clock)
+                     clock=self.clock, cpu_clock=cpu_clock)
 
     def finish(self, trace: Optional[Trace], status: str = "ok") -> None:
         """Land a completed trace: ring, per-stage histograms, slow-query
@@ -565,8 +644,12 @@ class TraceRecorder:
             if self.stats is not None:
                 self.stats.count("SlowQueries", 1)
             if self.logger is not None:
+                # Each stage's length and, beside it, the CPU time of its
+                # thread in it: whether a slow stage worked or waited.
                 breakdown = "; ".join(
-                    f"{s.name}={s.dur_ms:.1f}ms" for s in spans)
+                    f"{s.name}={s.dur_ms:.1f}ms" + (
+                        "" if s.cpu_ms is None else f" cpu={s.cpu_ms:.1f}")
+                    for s in spans)
                 self.logger.info(
                     "[obs] slow query %.1fms > slow-query-ms %.1f "
                     "trace=%s index=%s pql=%s stages: %s",
@@ -602,5 +685,6 @@ class TraceRecorder:
             out = dict(self.counters)
             out["ring"] = len(self._ring)
         out["sample_rate"] = self.config.sample_rate
+        out["cpu_period"] = self.cpu_period
         out["slow_query_ms"] = self.config.slow_query_ms
         return out

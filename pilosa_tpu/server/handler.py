@@ -956,6 +956,12 @@ class Handler:
         from .. import native as _native
 
         out["native"] = {"loaded": _native.available()}
+        # What the whole process spent (obs/host.py): CPU seconds of every
+        # thread, the collector's runs and seconds. Beside
+        # `scheduler.admitted` they are CPU and collector time an answer.
+        host_meter = getattr(self.api.server, "host_meter", None)
+        if host_meter is not None:
+            out["host"] = host_meter.snapshot()
         # Scheduler lifecycle metrics: queue depth, admit/shed/deadline
         # counts, and the micro-batcher's launch/coalesce counters (wait
         # time and batch-size histograms live in the stats timings above).

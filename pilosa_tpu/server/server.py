@@ -327,6 +327,7 @@ class Server:
         # starts/adopts traces; everything downstream records via the
         # obs contextvar.
         from ..obs import ObsConfig, TraceRecorder, trace as obs_trace
+        from ..obs.host import HostMeter
 
         # obs/ imports no jax: the profiler's annotation class is handed
         # in here, for the spans of a request under a /debug/profile
@@ -338,6 +339,8 @@ class Server:
         self.trace_recorder = TraceRecorder(
             self.obs_config, stats=self.stats, logger=self.logger,
         )
+        # The process's CPU and collector seconds (`host` in /debug/vars).
+        self.host_meter = HostMeter()
         self.api = API(self)
         # Geo replication (geo/, docs/geo-replication.md): follower
         # clusters tail this (or another) cluster's CDC stream. Built
@@ -591,6 +594,7 @@ class Server:
             # node.uri, which is final only post-bind) and the holder is
             # open (the tailer applies into live fragments).
             self.geo.start()
+        self.host_meter.start()
         self.opened = True
         if self.join_addr:
             self._join_cluster()
@@ -801,6 +805,7 @@ class Server:
 
     def close(self) -> None:
         self._stop.set()
+        self.host_meter.close()
         if self.cdc is not None:
             # Unpark /cdc/stream long-poll waiters BEFORE the HTTP
             # shutdown: a handler thread blocked in a stream wait would

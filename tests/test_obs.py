@@ -1178,3 +1178,335 @@ def test_debug_profile_holds_the_requests_spans_and_refuses_a_second(
     for name in ("request", "device.dispatch", "engine.device_wait"):
         assert seen[name][0]["trace"] == tr["id"]
         assert seen[name][0]["span"] == find_span(tr, name)["id"]
+
+
+# ---------------------------- a span's CPU time beside its length (PR 40)
+
+
+class FakeCpu:
+    """The calling thread's CPU clock, in ns as time.thread_time_ns gives
+    it: moves only when a test says the thread worked."""
+
+    def __init__(self):
+        self.ns = 5_000_000_000
+
+    def __call__(self):
+        return self.ns
+
+    def work(self, ms):
+        self.ns += round(ms * 1e6)
+
+
+@pytest.fixture
+def clocks(fake_clock):
+    """A recorder on two fake clocks. `spend(wall_ms, cpu_ms)`: so long
+    on the monotonic clock, of which the thread ran so long."""
+    cpu = FakeCpu()
+    rec = TraceRecorder(ObsConfig(sample_rate=1.0, slow_query_ms=20.0),
+                        logger=BufferLogger(), clock=fake_clock,
+                        cpu_clock=cpu, seed=40)
+
+    def spend(wall_ms, cpu_ms=0.0):
+        fake_clock.advance(wall_ms / 1000.0)
+        cpu.work(cpu_ms)
+
+    return rec, spend
+
+
+def _a_count(rec, spend):
+    """A request shaped like a served Count: work in the root, a wait
+    recorded (1.5 of the root's first 2 ms), a gather that worked 2.1 of
+    its 30 ms."""
+    t = rec.maybe_start("i", "Count(Row(f=1))")
+    token = obs.activate(t)
+    try:
+        with obs.span("request"):
+            spend(2.0, 0.45)
+            obs.record("sched.wait", 1.5, cls="interactive")
+            with obs.span("device.dispatch"):
+                spend(1.0, 0.25)
+                with obs.span("gather", kind="cold"):
+                    spend(30.0, 2.1)
+                obs.record("batch.hold", 0.0, held=0)
+            spend(1.0, 0.5)
+    finally:
+        obs.deactivate(token)
+    return t
+
+
+def test_cpu_ms_is_the_cpu_clocks_difference(clocks):
+    rec, spend = clocks
+    spans = _finished(rec, _a_count(rec, spend))
+    assert spans["gather"]["dur_ms"] == pytest.approx(30.0)
+    assert spans["gather"]["cpu_ms"] == pytest.approx(2.1)
+    assert spans["device.dispatch"]["cpu_ms"] == pytest.approx(2.35)
+    assert spans["request"]["cpu_ms"] == pytest.approx(3.3)
+    assert spans["request"]["dur_ms"] == pytest.approx(34.0)
+
+
+def test_self_cpu_ms_of_a_threads_spans_sum_to_the_roots_cpu_ms(clocks):
+    rec, spend = clocks
+    spans = _finished(rec, _a_count(rec, spend))
+    assert spans["request"]["self_cpu_ms"] == pytest.approx(0.95)
+    assert spans["device.dispatch"]["self_cpu_ms"] == pytest.approx(0.25)
+    assert spans["gather"]["self_cpu_ms"] == pytest.approx(2.1)
+    assert sum(s["self_cpu_ms"] for s in spans.values()) == pytest.approx(
+        spans["request"]["cpu_ms"])
+    # What a span's thread did not run of its own time: the gather stood
+    # 27.9 of its 30 ms, the root 0.55 of the 1.5 no stage accounts for.
+    assert spans["gather"]["self_ms"] - spans["gather"]["self_cpu_ms"] \
+        == pytest.approx(27.9)
+    assert spans["request"]["self_ms"] - spans["request"]["self_cpu_ms"] \
+        == pytest.approx(0.55)
+
+
+@pytest.mark.parametrize("name", ["sched.wait", "batch.hold", "qos.charge"])
+def test_a_recorded_span_has_no_cpu_of_its_own(name, clocks):
+    """Pre-measured spans are waits or amounts by construction."""
+    rec, spend = clocks
+    t = rec.maybe_start("i", "q")
+    with t.span("request") as root:
+        spend(5.0, 4.0)
+        t.record(name, 3.0, parent=root, amount=name == "qos.charge")
+    spans = _finished(rec, t)
+    assert spans[name]["cpu_ms"] == spans[name]["self_cpu_ms"] == 0.0
+    assert spans["request"]["self_cpu_ms"] == pytest.approx(4.0)
+
+
+def _in_a_thread(fn):
+    import threading
+
+    th = threading.Thread(target=fn)
+    th.start()
+    th.join(timeout=30)
+    assert not th.is_alive()
+
+
+def test_a_span_closed_on_another_thread_has_no_cpu_ms(clocks):
+    """The CPU clock is a thread's own: a difference across two threads
+    is no time at all, so such a span says nothing, and its parent takes
+    nothing off for it."""
+    rec, spend = clocks
+    t = rec.maybe_start("i", "q")
+    with t.span("executor.fanout") as fan:
+        spend(1.0, 1.0)
+        hop = t.span("remote:peer", parent=fan)
+        hop.__enter__()
+        spend(4.0, 3.0)
+        _in_a_thread(hop.close)
+    spans = _finished(rec, t)
+    assert "cpu_ms" not in spans["remote:peer"]
+    assert "self_cpu_ms" not in spans["remote:peer"]
+    assert spans["remote:peer"]["dur_ms"] == pytest.approx(4.0)
+    assert spans["executor.fanout"]["cpu_ms"] == pytest.approx(4.0)
+    assert spans["executor.fanout"]["self_cpu_ms"] == pytest.approx(4.0)
+
+
+def test_a_child_that_ran_on_another_thread_keeps_its_cpu_to_itself(clocks):
+    """A hedged leg runs whole on a pool thread: it has a cpu_ms, of that
+    thread, and the span it was opened under spent none of it."""
+    rec, spend = clocks
+    t = rec.maybe_start("i", "q")
+    with t.span("executor.fanout") as fan:
+        spend(1.0, 1.0)
+
+        def leg():
+            with t.span("remote:peer", parent=fan):
+                with t.span("gather"):
+                    spend(2.0, 0.5)
+                spend(1.0, 0.25)
+
+        _in_a_thread(leg)
+    spans = _finished(rec, t)
+    assert spans["remote:peer"]["cpu_ms"] == pytest.approx(0.75)
+    assert spans["remote:peer"]["self_cpu_ms"] == pytest.approx(0.25)
+    # (The fake clock is one for all threads, so the fan-out's own reading
+    # holds the leg's 0.75; what is held here is that nothing came off.)
+    assert spans["executor.fanout"]["self_cpu_ms"] == pytest.approx(
+        spans["executor.fanout"]["cpu_ms"])
+
+
+def test_to_dict_leaves_the_cpu_fields_out_without_a_cpu_clock(fake_clock):
+    rec = TraceRecorder(ObsConfig(sample_rate=1.0), clock=fake_clock,
+                        cpu_clock=None, seed=41)
+    t = rec.maybe_start("i", "q")
+    with t.span("request") as root:
+        fake_clock.advance(0.002)
+        with t.span("gather"):
+            fake_clock.advance(0.001)
+        t.record("sched.wait", 1.0, parent=root)
+    spans = _finished(rec, t)
+    for name in ("request", "gather"):
+        assert set(spans[name]) == {"name", "id", "parent", "start_ms",
+                                    "dur_ms", "self_ms"}
+    # A recorded span is a wait whatever the platform's clocks.
+    assert spans["sched.wait"]["cpu_ms"] == 0.0
+
+
+def test_the_summary_header_is_byte_for_byte_what_it_was(clocks):
+    """The header is size-bounded and the peer's splice reads it: the CPU
+    readings stay out of it. The literal is the parent commit's output
+    for the same spans on the same clock and seed."""
+    rec, spend = clocks
+    t = rec.maybe_start("i", "Count(Row(f=1))")
+    token = obs.activate(t)
+    try:
+        with obs.span("request"):
+            spend(2.0, 1.0)
+            obs.record("sched.wait", 1.5, cls="interactive")
+            with obs.span("gather", kind="cold"):
+                spend(30.0, 2.1)
+            spend(1.0, 1.0)
+    finally:
+        obs.deactivate(token)
+    rec.finish(t)
+    assert t.summary_header() == (
+        '{"id":"94594d8b75673fca","ms":33.0,"spans":[["sched.wait",0.5,1.5,'
+        '{"cls":"interactive"}],["gather",2.0,30.0,{"kind":"cold"}],'
+        '["request",0.0,33.0]]}')
+
+
+@pytest.mark.parametrize("read_us, period", [(0.25, 1), (0.6, 1),
+                                             (5.8, 11), (40.0, 80)])
+def test_a_slow_cpu_clock_is_read_in_one_trace_of_every_period(
+        read_us, period, fake_clock):
+    """The CPU clock is a system call, 5.8 us a read on some hosts where
+    it is 0.25 us on others: the recorder times it once and gives one
+    trace in every `cpu_period` the clock, all of its spans or none, so
+    that a span's two reads cost about a microsecond on average."""
+    cpu = FakeCpu()
+
+    def costly_cpu():
+        fake_clock.advance(read_us / 1e6)   # what a read costs, on `clock`
+        return cpu()
+
+    rec = TraceRecorder(ObsConfig(sample_rate=1.0, ring_size=2000),
+                        clock=fake_clock, cpu_clock=costly_cpu, seed=43)
+    assert rec.cpu_period == rec.snapshot()["cpu_period"] == period
+    n = 1200
+    for i in range(n):
+        # Adopted traces (a coordinator's sub-queries) are drawn alike.
+        t = rec.maybe_start("i", "q") if i % 3 else rec.adopt("ab12:1")
+        with t.span("request"):
+            with t.span("gather"):
+                pass
+        rec.finish(t)
+    with_cpu = 0
+    for tr in rec.traces(limit=n):
+        has = ["cpu_ms" in s for s in tr["spans"]]
+        assert all(has) or not any(has), tr
+        assert [("self_cpu_ms" in s) for s in tr["spans"]] == has
+        with_cpu += has[0]
+    if period == 1:
+        assert with_cpu == n
+    else:
+        assert 0.6 * n / period < with_cpu < 1.6 * n / period
+
+
+def test_slow_query_log_gives_each_stage_its_cpu_beside_its_length(clocks):
+    rec, spend = clocks
+    t = _a_count(rec, spend)
+    rec.finish(t)
+    (line,) = [l[1] for l in rec.logger.lines if "[obs] slow query" in l[1]]
+    assert "gather=30.0ms cpu=2.1;" in line
+    assert "sched.wait=1.5ms cpu=0.0;" in line
+    assert line.endswith("request=34.0ms cpu=3.3")
+    # A span that has no reading says nothing of CPU.
+    rec.cpu_clock = None
+    bare = rec.maybe_start("i", "q")
+    with bare.span("gather"):
+        spend(30.0)
+    rec.finish(bare)
+    lines = [l[1] for l in rec.logger.lines if "[obs] slow query" in l[1]]
+    assert lines[1].endswith("stages: gather=30.0ms")
+
+
+def test_real_clocks_a_spinning_span_shares_the_lock_a_sleeping_one_idles():
+    """On the real clocks, only the direction that load on the machine
+    cannot break: two threads that spin in Python take turns at the
+    interpreter lock, so neither can have run for most of its span
+    (another process's load takes CPU away and never adds any); and a
+    span that sleeps has next to no CPU time."""
+    import threading
+
+    rec = TraceRecorder(ObsConfig(sample_rate=1.0), seed=42)
+    t = rec.maybe_start("i", "q")
+    both_in = threading.Barrier(2, timeout=30)
+    stop = threading.Event()
+
+    def spin():
+        with t.span("topn.rank"):
+            both_in.wait()
+            until = time.monotonic() + 0.4
+            n = 0
+            while time.monotonic() < until and not stop.is_set():
+                n += 1
+
+    threads = [threading.Thread(target=spin) for _ in range(2)]
+    for th in threads:
+        th.start()
+    try:
+        for th in threads:
+            th.join(timeout=30)
+    finally:
+        stop.set()
+    assert not any(th.is_alive() for th in threads)
+    with t.span("batch.hold"):
+        time.sleep(0.3)
+    spans = [s.to_dict() for s in t.spans]
+    spun = [s for s in spans if s["name"] == "topn.rank"]
+    assert len(spun) == 2
+    for s in spun:
+        assert s["dur_ms"] >= 390.0
+        assert 0.0 < s["cpu_ms"] <= 0.8 * s["dur_ms"], s
+    # (Room for a CPU clock that ticks: 10 ms a tick on some hosts.)
+    slept = spans[-1]
+    assert slept["dur_ms"] >= 300.0 and slept["cpu_ms"] < 50.0, slept
+
+
+def test_debug_vars_has_the_host_group_and_a_collection_grows_it(one_node):
+    import gc
+
+    h = f"localhost:{one_node.port}"
+    keys = {"cpu_s", "gc_collections", "gc_full_collections", "gc_s"}
+    first = _get_json(h, "/debug/vars")["host"]
+    assert set(first) == keys
+    assert first["cpu_s"] > 0
+    InternalClient().query(h, "t", "Count(Row(f=0))")
+    second = _get_json(h, "/debug/vars")["host"]
+    gc.collect()
+    third = _get_json(h, "/debug/vars")["host"]
+    for before, after in ((first, second), (second, third)):
+        assert all(after[k] >= before[k] for k in keys), (before, after)
+    assert third["gc_full_collections"] > second["gc_full_collections"]
+    assert third["gc_collections"] > second["gc_collections"]
+    assert third["gc_s"] > second["gc_s"]
+    # /metrics renders every /debug/vars group: no exporter code.
+    with urllib.request.urlopen(f"http://{h}/metrics") as r:
+        text = r.read().decode()
+    for k in keys:
+        assert f"\npilosa_host_{k} " in text
+    # A closed server counts no more: its callback is gone.
+    one_node.close()
+    assert one_node.host_meter._on_gc not in gc.callbacks
+
+
+def test_served_spans_carry_cpu_that_sums_to_the_roots(one_node):
+    h = f"localhost:{one_node.port}"
+    c = InternalClient()
+    for pql in ("Count(Row(f=0))", "Set(5, f=0)", "Count(Row(f=0))"):
+        c.query(h, "t", pql)
+    traces = _get_json(h, "/debug/traces")["traces"]
+    assert len(traces) == 3
+    for tr in traces:
+        spans = tr["spans"]
+        root = next(s for s in spans if s["parent"] is None)
+        assert all("cpu_ms" in s and "self_cpu_ms" in s for s in spans)
+        # (Not held: cpu_ms <= dur_ms. The CPU clock is read inside the
+        # monotonic interval, but on a host whose CPU clock ticks a span of
+        # 0.1 ms can be charged a whole tick of 10.)
+        for s in spans:
+            assert 0.0 <= s["self_cpu_ms"] <= s["cpu_ms"] + 1e-9, s
+        # One node, one thread a request: every span is the root's thread's.
+        assert sum(s["self_cpu_ms"] for s in spans) == pytest.approx(
+            root["cpu_ms"], abs=0.001 * len(spans))
