@@ -44,21 +44,25 @@ DEFAULT_MIN_THRESHOLD = 1
 
 
 def _topn_chunk(n_shards: int) -> int:
-    """Candidate rows per TopN device program, bounded by BYTES not rows:
-    a fixed 512-row chunk is 512 MiB at 8 shards but 16 GiB at 256 shards
-    (each row costs n_shards * 128 KiB in the stacked tensor). The byte
-    budget (PILOSA_TOPN_CHUNK_BYTES, default 2 GiB) trades dispatches per
-    TopN against stacked-tensor working set; row counts pad to pow2 in the
-    engine so varied chunk sizes reuse compiled programs. The floor is ONE
-    row (not a fixed 16): at extreme shard counts even 16 rows overruns
-    the budget (16 rows x 4096 shards x 128 KiB = 8 GiB), and a single
-    row per program is the smallest dispatch that still makes progress."""
+    """Candidate rows per TopN device program, bounded by BYTES alone: each
+    row costs n_shards * 128 KiB in the stacked tensor, so the byte budget
+    (PILOSA_TOPN_CHUNK_BYTES, default 2 GiB) gives 16,384 rows at one
+    shard, 256 at 64 and 64 at 256. It trades launches per TopN against
+    the stack's working set: every launch pays the host's whole per-launch
+    cost (probes, fingerprints, a wait for the device), so a fixed row cap
+    under the budget only multiplied launches (17 for 8,208 rows at one
+    shard under a cap of 512, PERF.md PR 41). Row counts pad in the engine
+    (parallel/engine.py padded_rows) so varied chunk sizes reuse compiled
+    programs. The floor is ONE row: at extreme shard counts even 16 rows
+    overrun the budget (16 rows x 4096 shards x 128 KiB = 8 GiB), and a
+    single row per program is the smallest dispatch that still makes
+    progress."""
     import os
 
     from .constants import WORDS_PER_ROW
 
     budget = int(os.environ.get("PILOSA_TOPN_CHUNK_BYTES", 2 << 30))
-    return max(1, min(512, budget // max(1, n_shards * WORDS_PER_ROW * 4)))
+    return max(1, budget // max(1, n_shards * WORDS_PER_ROW * 4))
 
 
 def _rank_matrix(rankings):

@@ -77,6 +77,31 @@ def _pop_elems(a: np.ndarray) -> np.ndarray:
     return np.bitwise_count(a.view(np.uint16))
 
 
+# Members of a restack's piece: a stack of more is built as pieces of this
+# many joined by one concatenate, and a padded stack of more pads to a
+# multiple of it (padded_rows).
+STACK_PIECE = 512
+
+
+def padded_rows(n: int) -> int:
+    """Rows a padded stack of n members holds, so that nearby member counts
+    share one compiled program: the next power of two up to STACK_PIECE,
+    the next multiple of STACK_PIECE above. A power of two above it would
+    pad TopN's 8,208 candidate rows to 16,384 (2 GiB at one shard, half of
+    it copies of leaf 0); a multiple pads them to 8,704 (1.06 GiB)."""
+    if n <= STACK_PIECE:
+        return 1 << (n - 1).bit_length() if n else 0
+    return -(-n // STACK_PIECE) * STACK_PIECE
+
+
+# Fewest entries a delta scatter is padded to (_pad_updates): the usual
+# refresh, a write or a few, then takes one program a cached shape, where
+# each power of two under 64 was a program of its own and the rarer ones
+# were first met inside a measured window (PERF.md, PR 41). A scatter
+# copies its whole plane or stack, so 64 entries cost it nothing more.
+DELTA_MIN_UPDATES = 64
+
+
 # The kinds of device program the engine builds: the first element of a
 # program-cache signature. Each has a flat `fn_builds_<kind>` counter beside
 # `fn_cache_builds` (registered at 0, so that a reader of counter growth
@@ -354,6 +379,7 @@ class ShardedQueryEngine:
             "PILOSA_STACK_CACHE_BYTES", config.stack_cache_bytes,
             default_budget)
         self._stack_jit: Optional[Callable] = None
+        self._join_jit: Optional[Callable] = None
         self._count_fns: Dict[Tuple, Callable] = {}
         self._bitmap_fns: Dict[Tuple, Callable] = {}
         # Compiled-program caches are LRU-bounded by entry count: each entry
@@ -1238,11 +1264,12 @@ class ShardedQueryEngine:
 
     @staticmethod
     def _pad_updates(arrays):
-        """Pad parallel index/value arrays to a pow2 length by repeating
-        entry 0 (a duplicate scatter of the SAME value is deterministic),
-        so varying delta sizes reuse a handful of compiled programs."""
+        """Pad parallel index/value arrays to a pow2 length of at least
+        DELTA_MIN_UPDATES by repeating entry 0 (a duplicate scatter of the
+        SAME value is deterministic), so varying delta sizes reuse a
+        handful of compiled programs."""
         n = len(arrays[0])
-        npad = 1 << (n - 1).bit_length()
+        npad = max(DELTA_MIN_UPDATES, 1 << (n - 1).bit_length())
         if npad == n:
             return arrays
         return [np.concatenate([a, np.repeat(a[:1], npad - n)]) for a in arrays]
@@ -1336,7 +1363,7 @@ class ShardedQueryEngine:
             arr.size)
         if updates is None:
             return None
-        # pow2 padding rows duplicate leaf 0; today no compiled program
+        # Padding rows duplicate leaf 0; today no compiled program
         # reads them, but the full-rebuild invariant is pad == leaf 0's
         # CURRENT plane, so replicate leaf-0 updates onto every pad row
         # rather than trusting a comment to keep them unread forever.
@@ -1386,7 +1413,7 @@ class ShardedQueryEngine:
 
     def _stacked_leaf_tensor(
         self, index: str, leaves: List[Leaf], shards: Tuple[int, ...],
-        pad_pow2: bool = False,
+        pad: bool = False,
     ) -> jax.Array:
         """One resident (U, S, W) device tensor for a leaf list, rebuilt only
         when a member fragment's generation changes. Where a device holds
@@ -1402,11 +1429,11 @@ class ShardedQueryEngine:
         work, not device FLOPs: passing one argument per leaf (dozens of
         arrays) and restacking them inside the program costs far more than
         the popcounts. Keeping the stack resident shrinks every query
-        dispatch to (stacked tensor, small index vectors). `pad_pow2` pads
-        the leading axis with duplicate rows so nearby leaf-set sizes reuse
-        one compiled program."""
+        dispatch to (stacked tensor, small index vectors). `pad` pads the
+        leading axis with copies of leaf 0 to padded_rows(n) so nearby
+        leaf-set sizes reuse one compiled program."""
         n = len(leaves)
-        np2 = (1 << (n - 1).bit_length()) if (pad_pow2 and n) else n
+        np2 = padded_rows(n) if pad else n
         # Get-or-build as one span; its `kind` says which of the three it
         # was: `hit` (resident and fresh), `delta` (a stale stack refreshed
         # by one scatter) or `restack` (members gathered and copied anew).
@@ -1477,14 +1504,28 @@ class ShardedQueryEngine:
                     def restack_planes(xs, fold):
                         return bp.fold_planes(jnp.stack(xs), fold)
 
+                    # One program of 8,704 parameters took 820 s to
+                    # compile for v5e, one of 512 3.4 s (PERF.md, PR 41):
+                    # a stack of more than STACK_PIECE members is pieces
+                    # of the program above, joined by one of a few.
+                    def join_pieces(pieces):
+                        return jnp.concatenate(pieces)
+
+                    out = shard_sharding(self.mesh, 3, axis=1)
                     self._stack_jit = jax.jit(
-                        restack_planes, static_argnums=1,
-                        out_shardings=shard_sharding(self.mesh, 3, axis=1),
-                    )
-                stack_jit = self._stack_jit
+                        restack_planes, static_argnums=1, out_shardings=out)
+                    self._join_jit = jax.jit(join_pieces, out_shardings=out)
+                stack_jit, join_jit = self._stack_jit, self._join_jit
             fold = stack_fold(len(shards), self.n_devices)
-            stacked = self._oom_guard(
-                None, lambda: stack_jit(tuple(arrs), fold))
+
+            def restack():
+                if np2 <= STACK_PIECE:
+                    return stack_jit(tuple(arrs), fold)
+                return join_jit(tuple(
+                    stack_jit(tuple(arrs[i:i + STACK_PIECE]), fold)
+                    for i in range(0, np2, STACK_PIECE)))
+
+            stacked = self._oom_guard(None, restack)
             # {view: {row: [(u,), ...]}}: where each row of each view lies
             # in the stack. Kept with the entry: only a stale stack asks,
             # and one of hundreds of rows is stale after every write to
@@ -2007,7 +2048,7 @@ class ShardedQueryEngine:
         """Returns the unmaterialized (Qp,) device counts, Qp ≥ q."""
         slots, idxs, inverse, q, qp = self._batch_slot_gather(comps, q)
         stacked = self._stacked_leaf_tensor(index, list(slots), shards,
-                                            pad_pow2=True)
+                                            pad=True)
         up = stacked.shape[0]
 
         # The memoized expansion rides inside the same program (a take on
@@ -2163,7 +2204,7 @@ class ShardedQueryEngine:
         n_calls = len(calls)
         slots, idxs, inverse, _, qp = self._batch_slot_gather(comps, n_calls)
         stacked = self._stacked_leaf_tensor(index, list(slots), shards,
-                                            pad_pow2=True)
+                                            pad=True)
         up = stacked.shape[0]
         hsig = comps[0][0].plan.sig_tuple
         sig = ("bitmap_batch", hsig, len(shards), qp, up)
@@ -2276,11 +2317,11 @@ class ShardedQueryEngine:
         # every subsequent query runs only the fused AND+popcount program
         # below. Without this split each new src re-read the full candidate
         # stack twice (r04: topn_qps 2.69 vs sum_qps 199 at the same shape).
-        # pad_pow2: phase-2 candidate counts vary per query (each query's
-        # winner set differs), so the row axis pads to a power of two to
-        # keep the compiled-program population at a handful of sizes.
+        # pad: phase-2 candidate counts vary per query (each query's
+        # winner set differs), so the row axis pads (padded_rows) to keep
+        # the compiled-program population at a handful of sizes.
         rows_tensor = self._stacked_leaf_tensor(index, leaves, shards,
-                                                pad_pow2=True)  # (Rp, S, W)
+                                                pad=True)  # (Rp, S, W)
         r_real = len(canon_rows)
         row_counts = None
         if need_row_counts:
@@ -2331,16 +2372,16 @@ class ShardedQueryEngine:
                     inter = bp.shard_sums(jnp.sum(
                         jax.lax.population_count(masked).astype(jnp.int32), axis=2
                     ), k)
-                    return inter, src_counts
+                    return jnp.concatenate([inter, src_counts[None, :]])
 
                 return topn_shard_src_counts
 
             fn = self._fn_build(self._count_fns, sig, build)
 
             def run():
-                inter, src_counts = fn(rows_tensor, src_leaves)
-                return (np.asarray(inter)[:r_real, :s_real],
-                        np.asarray(src_counts)[:s_real])
+                # One fetch: the src counts ride as the last row.
+                packed = np.asarray(fn(rows_tensor, src_leaves))
+                return packed[:r_real, :s_real], packed[-1, :s_real]
 
             self._note_launch((rows_tensor, src_leaves), stack=rows_tensor)
             with obs_span("engine.device_wait"):
@@ -2373,9 +2414,9 @@ class ShardedQueryEngine:
         hit = self._aux_probe(mkey, fp)
         if hit is not None:
             return hit[sel]
-        # pad_pow2: candidate-id counts vary per query; see topn_shard_counts.
+        # pad: candidate-id counts vary per query; see topn_shard_counts.
         rows_tensor = self._stacked_leaf_tensor(index, leaves, shards,
-                                                pad_pow2=True)  # (Rp, S, W)
+                                                pad=True)  # (Rp, S, W)
         r_real = len(row_ids)
         if src_call is not None:
             comp, expr = comp0, expr0  # compiled once above for the memo key

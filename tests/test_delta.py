@@ -19,7 +19,8 @@ from pilosa_tpu.core.fragment import (
     ALL_ROWS, ChangeJournal, Fragment, WriteEpoch)
 from pilosa_tpu.core.holder import Holder
 from pilosa_tpu.parallel import EngineConfig
-from pilosa_tpu.parallel.engine import Leaf, ShardedQueryEngine
+from pilosa_tpu.parallel.engine import (
+    DELTA_MIN_UPDATES, Leaf, ShardedQueryEngine)
 from pilosa_tpu.pql.parser import parse
 
 
@@ -382,7 +383,9 @@ def test_write_stream_moves_fewer_bytes_with_delta_on_than_off(holder):
     _, _, on = run(EngineConfig())
     got, cold_bytes, off = run(EngineConfig(delta_max_fraction=0.0))
     assert on["full_refresh_bytes"] == 0 and on["stack_delta_hits"] > 0
-    assert 0 < on["delta_bytes"] <= batches * writes * 64
+    # A refresh is one scatter of (u, shard, col, value) int32 quadruples,
+    # padded to at least DELTA_MIN_UPDATES of them.
+    assert 0 < on["delta_bytes"] <= batches * DELTA_MIN_UPDATES * 4 * 4
     assert off["delta_bytes"] == 0 and off["stack_delta_hits"] == 0
     # A write to `f` stales every resident leaf of `f`, and the view's
     # journal says which rows: with no delta every batch walks and uploads
@@ -433,7 +436,7 @@ def test_property_random_writes_delta_equals_full(holder):
         mutate_once()
         # Delta-maintained tensors...
         stack = unfolded(
-            engine._stacked_leaf_tensor("i", leaves, shards, pad_pow2=True))
+            engine._stacked_leaf_tensor("i", leaves, shards, pad=True))
         plane = np.asarray(engine._gather_leaf("i", leaves[0], shards))
         # ...must equal a cold rebuild straight from storage.
         for u, leaf in enumerate(leaves):
@@ -517,10 +520,10 @@ def test_stack_delta_keeps_pad_rows_in_sync(holder):
     engine = ShardedQueryEngine(holder)
     shards = (0, 1)
     leaves = [Leaf("f", "standard", r) for r in range(3)]  # pads to 4
-    engine._stacked_leaf_tensor("i", leaves, shards, pad_pow2=True)
+    engine._stacked_leaf_tensor("i", leaves, shards, pad=True)
     fld.set_bit(0, 12345)
     stack = unfolded(
-        engine._stacked_leaf_tensor("i", leaves, shards, pad_pow2=True))
+        engine._stacked_leaf_tensor("i", leaves, shards, pad=True))
     assert engine.counters["stack_delta_hits"] > 0
     assert stack.shape[0] == 4
     np.testing.assert_array_equal(stack[3], stack[0])
@@ -590,7 +593,7 @@ class TestByteCacheAccounting:
         shards = tuple(range(4))
         leaves = [Leaf("f", "standard", r) for r in range(2)]
         for k in range(6):
-            engine._stacked_leaf_tensor("i", leaves, shards, pad_pow2=True)
+            engine._stacked_leaf_tensor("i", leaves, shards, pad=True)
             engine._gather_leaf("i", leaves[0], shards)
             fld.set_bit(k % 2, k * 64)
         with engine._lock:

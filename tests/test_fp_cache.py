@@ -32,7 +32,8 @@ from pilosa_tpu.core.fragment import ALL_ROWS, ChangeJournal, Fragment
 from pilosa_tpu.core.holder import Holder
 from pilosa_tpu.executor import Executor
 from pilosa_tpu.obs import trace as obs_trace
-from pilosa_tpu.parallel.engine import Leaf, ShardedQueryEngine
+from pilosa_tpu.parallel.engine import (
+    DELTA_MIN_UPDATES, Leaf, ShardedQueryEngine)
 from pilosa_tpu.pql.parser import parse
 from pilosa_tpu.translate import TranslateStore
 
@@ -594,22 +595,24 @@ def test_a_named_cell_is_the_only_fragment_touched(holder, engine, monkeypatch):
                              "leaf_republished", "full_refresh_bytes")) == {
         "leaf_delta_hits": 1, "fp_walks": 0, "leaf_republished": 0,
         "full_refresh_bytes": 0}
-    # Two words, as (row, col, value) int32 triples padded to a power of 2.
-    assert engine.counters["delta_bytes"] - c0["delta_bytes"] == 4 * 3 * 4
+    # Two words, as (row, col, value) int32 triples padded to a power of 2
+    # of at least DELTA_MIN_UPDATES.
+    assert engine.counters["delta_bytes"] - c0["delta_bytes"] == (
+        DELTA_MIN_UPDATES * 3 * 4)
 
 
 def test_a_republished_stack_touches_no_fragment(holder, engine, monkeypatch):
     fld = plant(holder)
     leaves = [Leaf("f", "standard", r) for r in range(3)]
-    stack = engine._stacked_leaf_tensor("i", leaves, SHARDS, pad_pow2=True)
+    stack = engine._stacked_leaf_tensor("i", leaves, SHARDS, pad=True)
     assert fld.set_bit(7, 3 * SHARD_WIDTH + 2)  # no row of the stack
     touched = []
     monkeypatch.setattr(holder, "fragment", lambda *a: touched.append(a))
     c0 = dict(engine.counters)
     names = spans_of(lambda: engine._stacked_leaf_tensor(
-        "i", leaves, SHARDS, pad_pow2=True))
+        "i", leaves, SHARDS, pad=True))
     assert engine._stacked_leaf_tensor(
-        "i", leaves, SHARDS, pad_pow2=True) is stack
+        "i", leaves, SHARDS, pad=True) is stack
     assert touched == [] and names == ["engine.stack"]
     assert grew(engine, c0, ("stack_republished", "stack_delta_hits",
                              "stack_hits", "fp_walks")) == {
@@ -620,7 +623,7 @@ def test_a_republished_stack_touches_no_fragment(holder, engine, monkeypatch):
 def test_a_stale_stack_patches_the_named_cells_only(holder, engine, monkeypatch):
     fld = plant(holder)
     leaves = [Leaf("f", "standard", r) for r in range(3)]
-    engine._stacked_leaf_tensor("i", leaves, SHARDS, pad_pow2=True)
+    engine._stacked_leaf_tensor("i", leaves, SHARDS, pad=True)
     assert fld.set_bit(1, 3 * SHARD_WIDTH + 2)
     assert fld.set_bit(0, SHARD_WIDTH + 65)  # leaf 0: the pad row follows
     assert fld.set_bit(9, 5)
@@ -630,7 +633,7 @@ def test_a_stale_stack_patches_the_named_cells_only(holder, engine, monkeypatch)
                         lambda *a: touched.append(a[3]) or real(*a))
     c0 = dict(engine.counters)
     got = unfolded(engine._stacked_leaf_tensor(
-        "i", leaves, SHARDS, pad_pow2=True))
+        "i", leaves, SHARDS, pad=True))
     assert sorted(touched) == [1, 3]
     want = np.stack([truth(holder, leaf) for leaf in leaves + leaves[:1]])
     np.testing.assert_array_equal(got[:, :len(SHARDS)], want)

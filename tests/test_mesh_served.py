@@ -31,6 +31,7 @@ from pilosa_tpu.executor import Executor
 from pilosa_tpu.obs import ObsConfig, TraceRecorder
 from pilosa_tpu.obs import trace as obs_trace
 from pilosa_tpu.parallel import EngineConfig
+from pilosa_tpu.parallel.engine import DELTA_MIN_UPDATES
 from pilosa_tpu.pql.parser import parse
 
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), os.pardir))
@@ -303,11 +304,13 @@ def test_engine_place_is_a_child_of_gather_on_a_refresh_only(holder):
         hit = traced("Count(Union(Row(f=1), Row(g=1)))")
         assert not [s for s in hit
                     if s["name"] in ("engine.place", "gather")]
-        # A write to the row, then the refresh: a scatter's few bytes.
+        # A write to the row, then the refresh: a scatter's few bytes, its
+        # (row, col, value) int32 triples padded to DELTA_MIN_UPDATES.
         ex.execute(CFG["index"], "Set(12, f=1)")
         delta = traced("Count(Xor(Row(f=1), Row(g=1)))")
         places = [s for s in delta if s["name"] == "engine.place"]
-        assert len(places) == 1 and 0 < places[0]["tags"]["bytes"] < 100
+        assert len(places) == 1
+        assert 0 < places[0]["tags"]["bytes"] <= DELTA_MIN_UPDATES * 3 * 4
     finally:
         ex.close()
 

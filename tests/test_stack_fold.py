@@ -299,7 +299,7 @@ def test_a_set_on_a_ranked_row_is_scattered_into_the_folded_stack(
         # And what is resident is what a rebuild would give.
         leaves = [Leaf("f", "standard", r) for r in range(F_ROWS)]
         stack = ex.engine._stacked_leaf_tensor(
-            "i", leaves, tuple(range(n_shards)), pad_pow2=True)
+            "i", leaves, tuple(range(n_shards)), pad=True)
         got = unfolded(stack)
         np.testing.assert_array_equal(got[:F_ROWS, :n_shards], F)
         np.testing.assert_array_equal(got[F_ROWS:], got[:1].repeat(
@@ -496,7 +496,7 @@ def test_a_stack_keeps_where_its_rows_lie(tmp_path):
             + [Leaf("g", "standard", 1)]
         key = ("i", tuple(leaves), shards, 4)
         get = lambda: engine._stacked_leaf_tensor(
-            "i", leaves, shards, pad_pow2=True)
+            "i", leaves, shards, pad=True)
         get()
         by_view = engine._stack_cache[key][2]
         assert by_view == {("f", "standard"): {0: [(0,)], 1: [(1,)],

@@ -1,10 +1,15 @@
-"""A filtered TopN over more candidate rows than one device program takes
-(executor.py `_topn_chunk`: 512 at one shard), through the normal path.
+"""A filtered TopN over a field of many rows, through the normal path, at
+two byte budgets of the candidate phase (executor.py `_topn_chunk`): one of
+512 rows at one shard (`PILOSA_TOPN_CHUNK_BYTES`), so that the chunk loop
+and the deadline between its chunks are run, and the default, under which
+the same rows are one program.
 
-One shard, 1,100 + 16 rows from a seeded numpy draw, so three chunks (512,
-512, 92). Every answer is held to a plain numpy reference written here: for
-every row the popcount of row AND filter, sorted, cut at n. And what the
-chunk loop says of itself: the counters `topn_queries`, `topn_chunks` and
+One shard, 1,100 + 16 rows from a seeded numpy draw: three chunks (512,
+512, 92) under the small budget, one of 1,116 (a stack padded to 1,536
+rows, parallel/engine.py padded_rows) under the default. Every answer is
+held to a plain numpy reference written here: for every row the popcount
+of row AND filter, sorted, cut at n. And what the chunk loop says of
+itself: the counters `topn_queries`, `topn_chunks` and
 `topn_candidate_rows` (`/debug/vars` group `executor`) and the spans
 `topn.rank`, `topn.chunk` (one a program) and `topn.replay`.
 """
@@ -19,17 +24,22 @@ import numpy as np
 import pytest
 
 from pilosa_tpu.core.holder import Holder
-from pilosa_tpu.executor import Executor, _topn_chunk
+from pilosa_tpu.constants import WORDS_PER_ROW
+from pilosa_tpu.executor import ExecOptions, Executor, _topn_chunk
 from pilosa_tpu.obs import ObsConfig, TraceRecorder
 from pilosa_tpu.obs import trace as obs_trace
 from pilosa_tpu.parallel import EngineConfig
+from pilosa_tpu.sched.deadline import Deadline, DeadlineExceededError
 
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), os.pardir))
 BENCH = os.path.join(REPO, "benchmark")
 INDEX = "i"
 READ_ROWS, WRITER_ROWS = 1100, 16
 ROWS = READ_ROWS + WRITER_ROWS
-CHUNKS = [512, 512, 92]
+# The chunks of the candidate phase at each byte budget: one of 512 rows'
+# planes at one shard, and the default (2 GiB: 16,384 rows at one shard).
+BUDGETS = {"512rows": 512 * WORDS_PER_ROW * 4, "default": None}
+CHUNKS = {"512rows": [512, 512, 92], "default": [ROWS]}
 COLS = 4096     # the columns the draw uses, of the shard's 2^20
 G_ROWS = 3
 FILTERS = {"row": "Row(g=0)", "tree": "Intersect(Row(g=0), Row(g=1))"}
@@ -83,23 +93,45 @@ def ask(ex, which, n):
 
 
 @pytest.fixture(scope="module")
-def served():
-    """One holder and one engine for the module's in-process cases, built
-    here (before conftest's per-test tracker could close the engine under
-    the next case). Cases that write set the same bit in `f`."""
-    f, g = draw(3801)
-    holder = Holder(None)
-    holder.open()
-    fill(holder, f, g)
-    ex = executor(holder)
-    yield ex, f, g
-    ex.close()
-    holder.close()
+def worlds():
+    """A holder and an engine a budget for the module's in-process cases,
+    built here (before conftest's per-test tracker could close an engine
+    under the next case). Cases that write set bits in their budget's
+    `f`."""
+    made = {}
+    for budget in BUDGETS:
+        f, g = draw(3801)
+        holder = Holder(None)
+        holder.open()
+        fill(holder, f, g)
+        made[budget] = holder, (executor(holder), f, g)
+    yield {budget: world for budget, (_, world) in made.items()}
+    for holder, (ex, _, _) in made.values():
+        ex.close()
+        holder.close()
 
 
-def test_the_shape_is_three_chunks():
-    assert _topn_chunk(1) == 512 and sum(CHUNKS) == ROWS
-    assert [min(512, ROWS - i) for i in range(0, ROWS, 512)] == CHUNKS
+@pytest.fixture(params=list(BUDGETS))
+def budget(request, monkeypatch):
+    """The candidate phase's byte budget, by its name in BUDGETS."""
+    if BUDGETS[request.param] is None:
+        monkeypatch.delenv("PILOSA_TOPN_CHUNK_BYTES", raising=False)
+    else:
+        monkeypatch.setenv("PILOSA_TOPN_CHUNK_BYTES",
+                           str(BUDGETS[request.param]))
+    return request.param
+
+
+@pytest.fixture
+def served(worlds, budget):
+    return worlds[budget]
+
+
+def test_the_shape_of_the_chunks(budget):
+    chunk = _topn_chunk(1)
+    assert chunk == {"512rows": 512, "default": 16384}[budget]
+    assert [min(chunk, ROWS - i)
+            for i in range(0, ROWS, chunk)] == CHUNKS[budget]
 
 
 @pytest.mark.parametrize("n", [1, 10, 2000])
@@ -136,7 +168,7 @@ def test_topn_after_sets_on_a_ranked_row(served, which, row):
 
 
 @pytest.mark.parametrize("which", list(FILTERS))
-def test_topn_after_a_restart_of_the_holder(tmp_path, which):
+def test_topn_after_a_restart_of_the_holder(tmp_path, budget, which):
     f, g = draw(3802)
     holder = Holder(str(tmp_path / "data"))
     holder.open()
@@ -162,8 +194,9 @@ def test_topn_after_a_restart_of_the_holder(tmp_path, which):
 
 
 @pytest.mark.parametrize("which", list(FILTERS))
-def test_the_counters_count_the_candidate_phase(served, which):
+def test_the_counters_count_the_candidate_phase(served, budget, which):
     ex, f, g = served
+    chunks = len(CHUNKS[budget])
     was = (ex.topn_queries, ex.topn_chunks, ex.topn_candidate_rows,
            ex.topn_array_walks)
     for k in (1, 2):
@@ -173,10 +206,35 @@ def test_the_counters_count_the_candidate_phase(served, which):
         # answered its chunks.
         assert (ex.topn_queries, ex.topn_chunks, ex.topn_candidate_rows,
                 ex.topn_array_walks) == (
-            was[0] + k, was[1] + 3 * k, was[2] + ROWS * k, was[3] + 2 * k)
+            was[0] + k, was[1] + chunks * k, was[2] + ROWS * k,
+            was[3] + 2 * k)
     # No filter: the host's rank cache answers, no runner, no chunk.
     ex.execute(INDEX, "TopN(f, n=10)")
-    assert (ex.topn_queries, ex.topn_chunks) == (was[0] + 2, was[1] + 6)
+    assert (ex.topn_queries, ex.topn_chunks) == (
+        was[0] + 2, was[1] + 2 * chunks)
+
+
+# Where a budget spent by the first program of the candidate phase stops
+# the query: between its chunks, or with one chunk, before the refetch.
+STOPPED_AT = {"512rows": "between TopN chunks",
+              "default": "between TopN phases"}
+
+
+def test_a_spent_deadline_stops_the_candidate_phase(served, budget,
+                                                    monkeypatch):
+    ex, _, _ = served
+    now = [0.0]
+    real = ex._topn_counts_laddered
+
+    def spending(*a):
+        out = real(*a)
+        now[0] = 100.0
+        return out
+
+    monkeypatch.setattr(ex, "_topn_counts_laddered", spending)
+    opt = ExecOptions(deadline=Deadline(5.0, clock=lambda: now[0]))
+    with pytest.raises(DeadlineExceededError, match=STOPPED_AT[budget]):
+        ex.execute(INDEX, "TopN(f, Row(g=1), n=10)", opt=opt)
 
 
 def spans_of(ex, pql):
@@ -192,7 +250,7 @@ def spans_of(ex, pql):
 
 
 @pytest.mark.parametrize("which", list(FILTERS))
-def test_the_spans_of_a_chunked_topn(served, which):
+def test_the_spans_of_a_chunked_topn(served, budget, which):
     ex, _, _ = served
     spans = spans_of(ex, f"TopN(f, {FILTERS[which]}, n=10)")
     by_name = {}
@@ -204,7 +262,7 @@ def test_the_spans_of_a_chunked_topn(served, which):
     assert rank["tags"] == {"shards": 1, "rows": ROWS}
     chunks = sorted(by_name["topn.chunk"], key=lambda s: s["start_ms"])
     assert [s["tags"] for s in chunks] == [
-        {"rows": r, "shards": 1} for r in CHUNKS]
+        {"rows": r, "shards": 1} for r in CHUNKS[budget]]
     first, second = sorted(by_name["topn.replay"],
                            key=lambda s: s["start_ms"])
     assert first["tags"] == {"rows": ROWS, "shards": 1}
@@ -212,12 +270,13 @@ def test_the_spans_of_a_chunked_topn(served, which):
     for s in [rank, first, second] + chunks:
         assert s["parent"] in fanouts, s["name"]
     # Every device program of the candidate phase runs under its chunk;
-    # the refetch's one is the second fan-out's own child.
+    # the refetch's one is the second fan-out's own child: four under the
+    # small budget, two under the default.
     dispatches = by_name["device.dispatch"]
-    assert len(dispatches) == 4
-    assert sorted(s["parent"] for s in dispatches[:3]) == sorted(
+    assert len(dispatches) == len(chunks) + 1
+    assert sorted(s["parent"] for s in dispatches[:-1]) == sorted(
         s["id"] for s in chunks)
-    assert dispatches[3]["parent"] == second["parent"]
+    assert dispatches[-1]["parent"] == second["parent"]
     # A Count opens none of them.
     names = {s["name"] for s in spans_of(
         ex, "Count(Intersect(Row(f=1), Row(g=0)))")}
@@ -243,7 +302,8 @@ def bench():
     return run
 
 
-def test_a_live_server_answers_and_counts_the_chunks(bench, tmp_path):
+def test_a_live_server_answers_and_counts_the_chunks(bench, budget,
+                                                     tmp_path):
     f, g = draw(3803)
     cfg = {"index": INDEX, "fields": [{"name": "f"}, {"name": "g"}]}
     data = types.SimpleNamespace(shards=1, cols={
@@ -299,7 +359,8 @@ def test_a_live_server_answers_and_counts_the_chunks(bench, tmp_path):
     assert answers["all"] == top(f, g[0], 2000)
     assert {k: v["executor"][k] for k in (
         "topn_queries", "topn_chunks", "topn_candidate_rows")} == {
-        "topn_queries": 4, "topn_chunks": 12, "topn_candidate_rows": 4 * ROWS}
+        "topn_queries": 4, "topn_chunks": 4 * len(CHUNKS[budget]),
+        "topn_candidate_rows": 4 * ROWS}
     assert bench.client.ladder_nonzero(v["engine_cache"]) == {}
     topns = [t for t in traces if t.get("pql", "").startswith("TopN(")]
     assert len(topns) == 4
@@ -310,7 +371,7 @@ def test_a_live_server_answers_and_counts_the_chunks(bench, tmp_path):
             continue
         names = [s["name"] for s in t["spans"]]
         assert (names.count("topn.rank"), names.count("topn.chunk"),
-                names.count("topn.replay")) == (1, 3, 2)
+                names.count("topn.replay")) == (1, len(CHUNKS[budget]), 2)
         # The self times still add up to the request (each is rounded to
         # a microsecond).
         root, = [s for s in t["spans"] if s["name"] == "request"]
