@@ -5,7 +5,7 @@ each is drawn; a mix (`traffic/<name>.json`) lists weighted PQL templates
 and how each placeholder is drawn. Nothing here knows a configuration or a
 mix by name: a later PR adds a cell by adding data files.
 
-Field draws:
+Field draws (a field's `draw` names one; its other keys are the draw's):
   zipf_bits   upstream pilosa/tools' `bench zipf`: `bits` times one bit at
               (row, column), each id drawn by a Zipf-Mandelbrot law,
               P(k) ~ (v + k)^-exponent over k = 0..range-1 with v such
@@ -13,6 +13,21 @@ Field draws:
               Rows keep their order (row 0 is the likeliest), so that a
               mix can name rows by rank; columns go through one seeded
               affine permutation, the same for every field.
+              Takes `rows`, `bits`, `row_exponent`, `row_ratio`,
+              `column_exponent`, `column_ratio`.
+  <name>      any other draw is the file draws/<name>.py, whose
+              `draw(data, field, rng)` fills `data.cols[field["name"]]` (a
+              set field) or `data.values[field["name"]]` (an int field).
+              Fields are drawn in the file's order, so a draw may read the
+              fields drawn before it. The files shipped:
+    int_uniform          `columns`, `min`, `max`: columns 0..columns-1 each
+                         hold one value, uniform over [min, max]
+    one_row_per_column   `columns`, `rows`, optionally `row_exponent` and
+                         `row_ratio`: columns 0..columns-1 each lie in one
+                         of `rows` rows, uniform or by the zipf law above
+
+A field may also carry `options`, posted as they are when the field is
+created (`{"type": "int", "min": 0, "max": 10}` makes an int field).
 
 Placeholder draws:
   {"choice": [...]}     uniform over the list
@@ -22,12 +37,15 @@ Placeholder draws:
 """
 
 import functools
+import importlib.util
 import math
+import os
 import random
 
 import numpy as np
 
 SHARD_WIDTH = 1 << 20
+DRAWS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "draws")
 
 
 def zipf_offset(n, exponent, ratio):
@@ -46,15 +64,29 @@ def zipf_ranks(rng, count, n, exponent, ratio):
     return np.minimum(x.astype(np.int64), n - 1)
 
 
+def load_draw(name):
+    """The `draw` function of draws/<name>.py; ValueError where there is
+    no such file."""
+    path = os.path.join(DRAWS, name + ".py")
+    if not os.path.isfile(path):
+        raise ValueError(f"unknown draw {name!r}")
+    spec = importlib.util.spec_from_file_location("draw_" + name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.draw
+
+
 class Data:
     """The index as drawn from the seed: cols[field][row] holds the row's
-    sorted, distinct uint32 columns."""
+    sorted, distinct uint32 columns; values[field] = (columns, values) of
+    an int field, sorted distinct uint32 columns and their int64 values."""
 
     def __init__(self, cfg, seed):
         self.cfg = cfg
         self.shards = cfg["shards"]
         self.n = self.shards * SHARD_WIDTH
         self.cols = {}
+        self.values = {}
         # rank -> column, the same for every field (a column that is
         # likely in one field is likely in all): a * rank + b mod n, a
         # coprime with n.
@@ -67,8 +99,9 @@ class Data:
             rng = np.random.default_rng([seed, k])
             draw = getattr(self, "_draw_" + field["draw"], None)
             if draw is None:
-                raise ValueError(f"unknown draw {field['draw']!r}")
-            draw(field, rng)
+                load_draw(field["draw"])(self, field, rng)
+            else:
+                draw(field, rng)
 
     def _draw_zipf_bits(self, field, rng):
         bits, rows = field["bits"], field["rows"]

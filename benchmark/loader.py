@@ -5,8 +5,16 @@ node receives a fragment on resize or restore) with a roaring file as the
 body. A fragment position
 is row * 2^20 + column-in-shard and a container's key is position >> 16,
 so row r owns keys 16r..16r+15.
+
+A set field's fragment is its view `standard`. An int field (options
+`{"type": "int", "min": .., "max": ..}`) is one fragment a shard of the
+view `bsig_<field>`, laid out as upstream's bsiGroup lays it: with `depth`
+the smallest d for which max - min < 2^d, row i holds bit i of
+value - min for i < depth, and row `depth` holds every column that has a
+value (not null).
 """
 
+import json
 import struct
 
 import numpy as np
@@ -58,25 +66,54 @@ def fragment_positions(data, name, shard):
     return np.concatenate(parts)
 
 
+def bsi_depth(options):
+    """Bit planes of an int field's values, as upstream's bsiGroup reckons
+    them: the smallest d with max - min < 2^d."""
+    return (options["max"] - options["min"]).bit_length()
+
+
+def bsi_positions(data, field, shard):
+    """Sorted fragment positions of one int field in one shard."""
+    cols, values = data.values[field["name"]]
+    lo, hi = shard * SHARD_WIDTH, (shard + 1) * SHARD_WIDTH
+    a, b = np.searchsorted(cols, [lo, hi])
+    col = (cols[a:b] - np.uint32(lo)).astype(np.uint64)
+    opts, mine = field["options"], values[a:b]
+    if mine.size and (mine.min() < opts["min"] or mine.max() > opts["max"]):
+        raise ValueError(f"{field['name']}: a value outside its options")
+    offset = mine - opts["min"]
+    depth = bsi_depth(opts)
+    parts = [(np.uint64(i) << np.uint64(20)) | col[(offset >> i) & 1 == 1]
+             for i in range(depth)]
+    parts.append((np.uint64(depth) << np.uint64(20)) | col)
+    return np.concatenate(parts)
+
+
 def create_schema(srv, cfg):
     index = cfg["index"]
     srv.request("POST", f"/index/{index}", "{}")
     for f in cfg["fields"]:
-        srv.request("POST", f"/index/{index}/field/{f['name']}", "{}")
+        body = json.dumps({"options": f["options"]}) if "options" in f \
+            else "{}"
+        srv.request("POST", f"/index/{index}/field/{f['name']}", body)
 
 
 def load(srv, cfg, data, threads=4):
     """Post every fragment; returns the bytes sent."""
     index = cfg["index"]
-    jobs = [(f["name"], s) for s in range(data.shards)
-            for f in cfg["fields"]]
+    jobs = [(f, s) for s in range(data.shards) for f in cfg["fields"]]
 
     def send(job):
-        name, s = job
-        body = roaring_body(fragment_positions(data, name, s))
+        f, s = job
+        name = f["name"]
+        if f.get("options", {}).get("type") == "int":
+            pos, view = bsi_positions(data, f, s), "bsig_" + name
+        else:
+            pos, view = fragment_positions(data, name, s), "standard"
+        body = roaring_body(pos)
         srv.request(
             "POST", f"/internal/fragment/data?index={index}&field={name}"
-            f"&view=standard&shard={s}", body)
+            f"&view={view}&shard={s}", body)
         return len(body)
 
     return sum(in_threads(threads, send, jobs))

@@ -8,13 +8,30 @@ and not 8 MiB.
 The harness (`run.judge`) asks `answer` for each client's requests in the
 order it sent them: rows named in the mix's `writer_rows` are written by
 one client only, so what that client reads back is exact.
+
+Int fields, as upstream's BSI calls read them:
+  Range(f op v)         op one of < <= > >= == !=; Range(f >< [a, b]) is
+                        a <= value <= b. Only columns that hold a value
+                        match: `f != v` is every column with a value other
+                        than v (upstream's not-null minus equal), never a
+                        column without one.
+  Sum(filter?, field=f) {"value": the exact sum, "count": the columns
+                        with a value} over the filter's columns
+  Min(...), Max(...)    {"value": the least (greatest) value, "count": how
+                        many of those columns hold it}
+With no column to read, each is {"value": 0, "count": 0}, as the server
+answers it.
 """
 
+import operator
 import re
 
 import numpy as np
 
-_TOKEN = re.compile(r"\s*([A-Za-z_][A-Za-z_0-9]*|-?\d+|[(),=])")
+_TOKEN = re.compile(
+    r"\s*([A-Za-z_][A-Za-z_0-9]*|-?\d+|><|[<>=!]=|[<>(),=\[\]])")
+_COMPARE = {"<": operator.lt, "<=": operator.le, ">": operator.gt,
+            ">=": operator.ge, "==": operator.eq, "!=": operator.ne}
 
 
 def parse(pql):
@@ -28,7 +45,13 @@ def parse(pql):
     return call
 
 
+def _int(tok):
+    return int(tok) if tok.lstrip("-").isdigit() else tok
+
+
 def _call(toks, i):
+    """One call from toks[i]; a condition `f op v` is the keyword
+    f: (op, v), with v a tuple for `><`."""
     name = toks[i]
     if toks[i + 1] != "(":
         raise ValueError(f"expected ( after {name}")
@@ -41,14 +64,35 @@ def _call(toks, i):
             sub, i = _call(toks, i)
             pos.append(sub)
         elif toks[i + 1] == "=":
-            val = toks[i + 2]
-            kw[toks[i]] = int(val) if val.lstrip("-").isdigit() else val
+            kw[toks[i]] = _int(toks[i + 2])
+            i += 3
+        elif toks[i + 1] == "><":
+            if toks[i + 2] != "[" or toks[i + 4] != "," or toks[i + 6] != "]":
+                raise ValueError(f"expected [a, b] after {toks[i]} ><")
+            kw[toks[i]] = ("><", (int(toks[i + 3]), int(toks[i + 5])))
+            i += 7
+        elif toks[i + 1] in _COMPARE:
+            kw[toks[i]] = (toks[i + 1], int(toks[i + 2]))
             i += 3
         else:
-            tok = toks[i]
-            pos.append(int(tok) if tok.lstrip("-").isdigit() else tok)
+            pos.append(_int(toks[i]))
             i += 1
     return (name, pos, kw), i + 1
+
+
+def _matches(values, op, v):
+    """Which of an int field's values a Range condition takes."""
+    if op == "><":
+        return (values >= v[0]) & (values <= v[1])
+    return _COMPARE[op](values, v)
+
+
+def exact_sum(values):
+    """The sum of int64 values as a Python integer, with no overflow."""
+    top = max(abs(int(values.min())), abs(int(values.max())))
+    if top * len(values) < 1 << 63:
+        return int(values.sum(dtype=np.int64))
+    return sum(values.tolist())
 
 
 class Reference:
@@ -60,6 +104,8 @@ class Reference:
         for rows in data.cols.values():
             for c in rows:
                 present[c] = True
+        for c, _ in data.values.values():
+            present[c] = True
         # rank[c]: the place of column c among the columns that hold a bit.
         rank = np.cumsum(present, dtype=np.uint32)
         rank -= np.uint32(1)
@@ -68,8 +114,12 @@ class Reference:
         self.rows = {(name, r): self._pack(rank[c])
                      for name, rows in data.cols.items()
                      for r, c in enumerate(rows)}
+        # An int field as (places of its columns, their values).
+        self.ints = {name: (rank[c].astype(np.intp), v)
+                     for name, (c, v) in data.values.items()}
         del rank
         self.memo = {}
+        self.ranges = {}        # (field, op, v) -> packed bitset
         # (field, row) -> [count, set of columns added], for rows that one
         # client alone writes.
         self.written = {}
@@ -129,13 +179,35 @@ class Reference:
             slack = [self.sets_on.get((pos[0], r), 0)
                      for r in range(len(counts))]
             return ("topn", counts, kw.get("n", 0), slack)
+        if name in ("Sum", "Min", "Max"):
+            return self.val_count(name, kw["field"],
+                                  self.bitmap(pos[0]) if pos else None)
         raise ValueError(f"the reference does not answer {name}")
+
+    def val_count(self, name, field, filt):
+        places, values = self.ints[field]
+        if filt is not None:
+            held = np.unpackbits(filt.view(np.uint8), bitorder="little")
+            values = values[held[places] == 1]
+        if not len(values):
+            return {"value": 0, "count": 0}
+        if name == "Sum":
+            return {"value": exact_sum(values), "count": len(values)}
+        v = values.min() if name == "Min" else values.max()
+        return {"value": int(v), "count": int(np.count_nonzero(values == v))}
 
     def bitmap(self, call):
         name, pos, kw = call
         if name == "Row":
             (field, row), = kw.items()
             return self.rows[field, row]
+        if name == "Range":
+            (field, (op, v)), = kw.items()
+            key = field, op, v
+            if key not in self.ranges:
+                places, values = self.ints[field]
+                self.ranges[key] = self._pack(places[_matches(values, op, v)])
+            return self.ranges[key]
         kids = [self.bitmap(c) for c in pos]
         out = kids[0]
         for k in kids[1:]:

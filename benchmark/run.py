@@ -16,7 +16,9 @@ prints the result as the last line of stdout. There is no CPU fallback: a server
 no result. Alone in a directory (no pilosa_tpu beside benchmark/): 2.
 
 Everything a cell is made of is found by name: configs/<config>.json,
-traffic/<mix>.json, layers/<metric>.py. See PERF.md, "Adding a cell".
+traffic/<mix>.json, layers/<metric>.py, and draws/<draw>.py for a field
+drawn by a law that generate.py does not have. See PERF.md, "Adding a
+cell".
 """
 
 import time

@@ -88,7 +88,7 @@ def control_run(cfg, mix, seed, control, groups=120):
 
 
 def _tiny(workload):
-    manifest = os.path.join(HERE, "data", "BENCHMARK.tiny.json")
+    manifest = os.path.join(HERE, "data", "BENCHMARK.tiny40.json")
     _, _, cfg, mix = run.find_cell(workload, manifest)
     return cfg, mix
 

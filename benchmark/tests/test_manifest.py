@@ -109,15 +109,55 @@ def test_every_named_file_exists(manifest):
             assert re.match(r"^[A-Za-z0-9_.\-]+$", n), os.path.join(dirpath, n)
 
 
-def test_the_tests_manifest_differs_only_in_its_configuration_files(manifest):
-    tiny = run.read_json(BENCH, "tests", "data", "BENCHMARK.tiny.json")
+TINY = os.path.join(BENCH, "tests", "data", "BENCHMARK.tiny40.json")
+# The tiny configurations' shards; each cuts rows and bits, nothing else.
+TINY_SHARDS = {"zipf-64.adhoc": 2, "zipf-4x64.adhoc": 8, "zipf-1x8k.topn": 1}
+
+
+@pytest.mark.parametrize("cell", sorted(TINY_SHARDS))
+def test_the_tiny_manifest_is_the_manifest_with_tiny_files(manifest, cell):
+    """The one mirror every rehearsal reads: BENCHMARK.json whole, but for
+    each configuration's `file` and a mix that lies beside the tiny
+    configurations (named from traffic/ by a relative path). Each cell's
+    tiny configuration is its own with fewer shards, rows and bits, and its
+    mix has the same templates, weights and PQL."""
+    tiny = run.read_json(TINY)
+    assert set(tiny) == set(manifest)
     for key in manifest:
         if key == "configs":
-            strip = lambda cs: [{k: v for k, v in c.items() if k != "file"}  # noqa: E731
-                                for c in cs]
-            assert strip(tiny[key]) == strip(manifest[key])
+            assert [{k: v for k, v in c.items() if k != "file"}
+                    for c in tiny[key]] == [
+                {k: v for k, v in c.items() if k != "file"}
+                for c in manifest[key]]
+        elif key == "workloads":
+            assert [{k: v for k, v in w.items() if k != "traffic"}
+                    for w in tiny[key]] == [
+                {k: v for k, v in w.items() if k != "traffic"}
+                for w in manifest[key]]
+            for w, full in zip(tiny[key], manifest[key]):
+                assert w["traffic"] in (full["traffic"], "../tests/data/tiny-"
+                                        + full["traffic"])
         else:
             assert tiny[key] == manifest[key], key
+    _, small_cell, cfg, mix = run.find_cell(cell, TINY)
+    _, full_cell, full, full_mix = run.find_cell(cell)
+    assert small_cell["chips"] == full_cell["chips"]
+    assert cfg["shards"] == TINY_SHARDS[cell] <= full["shards"]
+    assert {k: v for k, v in cfg.items() if k not in ("shards", "fields")} \
+        == {k: v for k, v in full.items() if k not in ("shards", "fields")}
+    sized = ("rows", "bits")
+    assert [{k: v for k, v in f.items() if k not in sized}
+            for f in cfg["fields"]] == [
+        {k: v for k, v in f.items() if k not in sized} for f in full["fields"]]
+    assert all(f[k] <= g[k] for f, g in zip(cfg["fields"], full["fields"])
+               for k in sized)
+    assert [(t["name"], t["weight"], t["pql"]) for t in mix["templates"]] \
+        == [(t["name"], t["weight"], t["pql"]) for t in full_mix["templates"]]
+    # The writer-owned rows are the last of their field, there as here.
+    for small, big in ((cfg, mix), (full, full_mix)):
+        rows = {f["name"]: f["rows"] for f in small["fields"]}
+        for field, (lo, hi) in big["writer_rows"].items():
+            assert hi == rows[field] - 1 and hi - lo + 1 == big["clients"]
 
 
 def test_peaks_table():

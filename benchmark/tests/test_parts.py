@@ -1,7 +1,10 @@
 """The yardstick's parts, each against something independent of it: the
 loader's roaring file against the program's own reader, the generator's
 law against its formula, the reference against plain Python sets, the traffic generator against its
-mix file, the readers against a hand-made context."""
+mix file, the readers against a hand-made context. And the parts that
+integer fields brought: the draw files' laws, a draw found by name, field
+options in the schema, a BSI fragment read back to its values, and the
+reference's Range, Sum, Min and Max against plain Python."""
 
 import collections
 import json
@@ -34,8 +37,25 @@ def zipf():
     return cfg, generate.Data(cfg, 11)
 
 
+@pytest.fixture(scope="module")
+def ints():
+    cfg = tiny("tiny-int.json")
+    return cfg, generate.Data(cfg, 2**31 + 42)
+
+
 def columns_of(data, field, row):
     return set(data.cols[field][row].tolist())
+
+
+def field_of(cfg, name):
+    return next(f for f in cfg["fields"] if f["name"] == name)
+
+
+def chi_square_999(df):
+    """The 99.9th percentile of chi-square with df degrees of freedom, by
+    Wilson and Hilferty's cube-root approximation."""
+    z, a = 3.0902, 2.0 / (9.0 * df)
+    return df * (1.0 - a + z * a ** 0.5) ** 3
 
 
 def test_zipf_bits_follow_the_generators_law(zipf):
@@ -247,3 +267,169 @@ def test_readers_on_a_hand_made_context():
     # shards x 128 KiB = 4 GiB; at 819 GB/s 5.24 ms.
     assert read("kernel.count_roofline") == pytest.approx(
         100 * (8 * 64 * 64 * 131072 / 819e9) / 0.02)
+
+
+@pytest.mark.parametrize("name", ["discount", "quantity"])
+def test_int_uniform_holds_one_value_a_column_uniform_over_its_range(
+        ints, name):
+    cfg, data = ints
+    f = field_of(cfg, name)
+    cols, values = data.values[name]
+    assert cols.dtype == np.uint32 and values.dtype == np.int64
+    # The first `columns` columns, each once: a fact table's records.
+    assert np.array_equal(cols, np.arange(f["columns"], dtype=np.uint32))
+    assert values.min() == f["min"] and values.max() == f["max"]
+    seen = np.bincount(values - f["min"])
+    k = f["max"] - f["min"] + 1
+    assert len(seen) == k
+    want = len(values) / k
+    assert ((seen - want) ** 2 / want).sum() < chi_square_999(k - 1)
+    again = generate.Data(cfg, 2**31 + 42)
+    assert np.array_equal(again.values[name][1], values)
+    assert not np.array_equal(generate.Data(cfg, 7).values[name][1], values)
+
+
+def test_one_row_per_column_puts_each_column_in_one_row(ints):
+    cfg, data = ints
+    f = field_of(cfg, "year")
+    rows = data.cols["year"]
+    assert len(rows) == f["rows"] == 7
+    every = np.concatenate(rows)
+    assert np.array_equal(np.sort(every),
+                          np.arange(f["columns"], dtype=np.uint32))
+    assert all(np.all(np.diff(c.astype(np.int64)) > 0) for c in rows)
+    sizes = np.array([len(c) for c in rows])
+    want = f["columns"] / f["rows"]
+    assert ((sizes - want) ** 2 / want).sum() < chi_square_999(f["rows"] - 1)
+    # By the zipf law where the field gives one, floored as `zipf_ranks`
+    # floors it: row r has the mass of [r, r + 1).
+    skewed = dict(f, row_exponent=1.01, row_ratio=0.25)
+    data2 = generate.Data(dict(cfg, fields=[skewed]), 3)
+    sizes = np.array([len(c) for c in data2.cols["year"]])
+    v = generate.zipf_offset(f["rows"], 1.01, 0.25)
+    edge = np.array([(v + r) ** -0.01 for r in range(f["rows"] + 1)])
+    want = f["columns"] * -np.diff(edge) / (edge[0] - edge[-1])
+    assert ((sizes - want) ** 2 / want).sum() < chi_square_999(f["rows"] - 1)
+    assert sizes.sum() == f["columns"] and sizes[0] > 1.2 * sizes[-1]
+
+
+def test_a_draw_is_found_by_name_and_an_unknown_one_refused(
+        tmp_path, monkeypatch):
+    assert generate.load_draw("int_uniform").__module__ == "draw_int_uniform"
+    with pytest.raises(ValueError, match="unknown draw 'no_such_draw'"):
+        generate.Data({"shards": 1, "fields": [
+            {"name": "x", "draw": "no_such_draw"}]}, 1)
+    # A draw may read the fields drawn before it, in the file's order.
+    (tmp_path / "month_of.py").write_text(
+        "def draw(data, field, rng):\n"
+        "    src = data.cols[field['from']]\n"
+        "    data.cols[field['name']] = [c for c in src for _ in range(12)]\n")
+    monkeypatch.setattr(generate, "DRAWS", str(tmp_path))
+    cfg = {"shards": 1, "fields": [
+        {"name": "year", "draw": "zipf_bits", "rows": 3, "bits": 500,
+         "row_exponent": 1.01, "row_ratio": 0.25, "column_exponent": 1.01,
+         "column_ratio": 0.25},
+        {"name": "month", "draw": "month_of", "from": "year"}]}
+    data = generate.Data(cfg, 9)
+    assert len(data.cols["month"]) == 36
+    assert data.cols["month"][12] is data.cols["year"][1]
+
+
+class Recorder:
+    def __init__(self):
+        self.sent = []
+
+    def request(self, method, path, body=None):
+        self.sent.append((method, path, body))
+
+
+def test_field_options_are_posted_and_a_field_without_them_posts_nothing(
+        ints):
+    cfg, data = ints
+    srv = Recorder()
+    loader.create_schema(srv, cfg)
+    bodies = {path: body for _, path, body in srv.sent}
+    assert bodies["/index/tinyint"] == "{}"
+    assert bodies["/index/tinyint/field/f"] == "{}"
+    assert bodies["/index/tinyint/field/year"] == "{}"
+    assert json.loads(bodies["/index/tinyint/field/quantity"]) == {
+        "options": {"type": "int", "min": 1, "max": 50}}
+    srv = Recorder()
+    loader.load(srv, cfg, data)
+    views = collections.Counter(
+        path.split("&view=")[1].split("&")[0] for _, path, _ in srv.sent)
+    assert views == {"standard": 4, "bsig_discount": 2, "bsig_quantity": 2}
+
+
+@pytest.mark.parametrize("lo, hi, depth", [(0, 10, 4), (1, 50, 6), (0, 0, 0),
+                                           (0, 1, 1), (-8, 7, 4), (0, 16, 5)])
+def test_bsi_depth_is_upstreams(lo, hi, depth):
+    assert loader.bsi_depth({"min": lo, "max": hi}) == depth
+    assert hi - lo < 1 << depth and (depth == 0 or hi - lo >= 1 << depth - 1)
+
+
+@pytest.mark.parametrize("name", ["discount", "quantity"])
+def test_bsi_fragment_reads_back_to_the_drawn_values(ints, name):
+    from pilosa_tpu.storage.bitmap import Bitmap
+
+    cfg, data = ints
+    f = field_of(cfg, name)
+    depth = loader.bsi_depth(f["options"])
+    cols, values = data.values[name]
+    for shard in range(cfg["shards"]):
+        body = loader.roaring_body(loader.bsi_positions(data, f, shard))
+        pos = np.asarray(Bitmap.from_bytes(body[8:]).slice(), dtype=np.uint64)
+        row, col = pos >> np.uint64(20), pos & np.uint64(0xFFFFF)
+        planes = np.zeros((depth + 1, generate.SHARD_WIDTH), dtype=np.int64)
+        planes[row.astype(np.int64), col.astype(np.int64)] = 1
+        mine = (cols >= shard * generate.SHARD_WIDTH) \
+            & (cols < (shard + 1) * generate.SHARD_WIDTH)
+        here = (cols[mine] - shard * generate.SHARD_WIDTH).astype(np.int64)
+        assert np.array_equal(np.flatnonzero(planes[depth]), here)
+        got = (planes[:depth] << np.arange(depth)[:, None]).sum(axis=0)
+        assert np.array_equal(got[here] + f["options"]["min"], values[mine])
+    with pytest.raises(ValueError, match="outside"):
+        loader.bsi_positions(data, dict(f, options=dict(f["options"],
+                                                        max=5)), 0)
+
+
+def test_range_sum_min_max_against_plain_python(ints):
+    cfg, data = ints
+    ref = reference.build(data, {})
+    d = dict(zip(*(a.tolist() for a in data.values["discount"])))
+    q = dict(zip(*(a.tolist() for a in data.values["quantity"])))
+    year2 = columns_of(data, "year", 2)
+    f1 = columns_of(data, "f", 1)
+    for op, test in (("<", lambda v, c: v < c), ("<=", lambda v, c: v <= c),
+                     (">", lambda v, c: v > c), (">=", lambda v, c: v >= c),
+                     ("==", lambda v, c: v == c), ("!=", lambda v, c: v != c)):
+        for c in (-1, 0, 4, 10, 11):
+            want = sum(test(v, c) for v in d.values())
+            assert ref.answer(f"Count(Range(discount {op} {c}))") == want
+    # A column with no value never matches, `!=` included: the rows of f
+    # reach past the last record.
+    assert max(f1) >= len(q)
+    assert ref.answer("Count(Intersect(Row(f=1), Range(quantity != 7)))") \
+        == sum(1 for c in f1 if c in q and q[c] != 7)
+    assert ref.answer("Count(Range(quantity >< [10, 12]))") \
+        == sum(10 <= v <= 12 for v in q.values())
+    sel = [c for c in year2 if 1 <= d[c] <= 3 and q[c] < 25]
+    pql = ("{}(Intersect(Row(year=2), Range(discount >< [1, 3]), "
+           "Range(quantity < 25)), field=quantity)")
+    assert ref.answer(pql.format("Sum")) == {
+        "value": sum(q[c] for c in sel), "count": len(sel)}
+    low = min(q[c] for c in sel)
+    assert ref.answer(pql.format("Min")) == {
+        "value": low, "count": sum(q[c] == low for c in sel)}
+    high = max(q[c] for c in sel)
+    assert ref.answer(pql.format("Max")) == {
+        "value": high, "count": sum(q[c] == high for c in sel)}
+    assert ref.answer("Sum(field=discount)") == {
+        "value": sum(d.values()), "count": len(d)}
+    for call in ("Sum", "Min", "Max"):
+        assert ref.answer(f"{call}(Range(quantity > 50), field=quantity)") \
+            == {"value": 0, "count": 0}
+    big = np.array([2**62, 2**62, -5], dtype=np.int64)
+    assert reference.exact_sum(big) == 2**63 - 5
+    with pytest.raises(ValueError):
+        reference.parse("Count(Range(quantity >< 3))")

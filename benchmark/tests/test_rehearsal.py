@@ -17,7 +17,7 @@ from conftest import HERE
 
 import run
 
-TINY = os.path.join(HERE, "data", "BENCHMARK.tiny.json")
+TINY = os.path.join(HERE, "data", "BENCHMARK.tiny40.json")
 
 
 def args(workload, trace=0, seconds=2.0):

@@ -13,53 +13,30 @@ from conftest import HERE
 
 import run
 
-TINY4 = os.path.join(HERE, "data", "BENCHMARK.tiny4.json")
+TINY = os.path.join(HERE, "data", "BENCHMARK.tiny40.json")
 CELL = "zipf-4x64.adhoc"
 FOUR_DEVICES = {"JAX_PLATFORMS": "cpu",
                 "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
-
-
-def test_the_tiny_manifest_is_the_manifest_with_tiny_files():
-    manifest = run.read_json(run.REPO, "BENCHMARK.json")
-    tiny = run.read_json(TINY4)
-    for key in manifest:
-        if key == "configs":
-            assert [{k: v for k, v in c.items() if k != "file"}
-                    for c in tiny[key]] == [
-                {k: v for k, v in c.items() if k != "file"}
-                for c in manifest[key]]
-        else:
-            assert tiny[key] == manifest[key], key
-    _, cell, cfg, _ = run.find_cell(CELL, TINY4)
-    _, _, full, _ = run.find_cell(CELL)
-    assert cell["chips"] == 4 and cfg["shards"] == 8
-    assert cfg["server_flags"] == full["server_flags"] == [
-        "--engine-mesh-devices", "4"]
-    small = {"shards", "fields"}
-    assert {k: v for k, v in cfg.items() if k not in small} == {
-        k: v for k, v in full.items() if k not in small}
-    for f, g in zip(cfg["fields"], full["fields"]):
-        assert {**f, "bits": g["bits"]} == g and f["bits"] * 32 == g["bits"]
 
 
 def test_the_mesh_cell_agrees_on_every_answer_and_is_no_measurement():
     result = run.run_cell(
         argparse.Namespace(workload=CELL, seed=2**31 + 30, seconds=2.0,
                            trace=1),
-        require_tpu=False, server_env=FOUR_DEVICES, manifest_path=TINY4)
+        require_tpu=False, server_env=FOUR_DEVICES, manifest_path=TINY)
     assert result["attempted"] > 50 and result["failed"] == 0
     failing = sorted(k for k, (got, limit) in result["checks"].items()
                      if got != limit)
     assert failing == ["not_on_tpu"] and result["correct"] is False
     assert result["device"]["platform"] == "cpu"
     assert result["device"]["count"] == 4
-    # The ten per-layer metrics of the cell, but for the device's own and
+    # The per-layer metrics of the cell, but for the device's own and
     # those read from its trace, which the CPU backend gives nothing for
     # (`mesh.hbm_balance` among them: its allocator reports no bytes).
-    manifest = run.read_json(TINY4)
+    manifest = run.read_json(TINY)
     listed = [m for m in manifest["per_layer"]
               if run.metric_applies(m, CELL)]
-    assert len(listed) == 10
+    assert len(listed) == 18
     assert set(result["metrics"]) == {
         m["name"] for m in listed
         if m["layer"] != "device" and m["source"] != "device_trace"}

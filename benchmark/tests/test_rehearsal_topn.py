@@ -1,5 +1,5 @@
 """The cell `zipf-1x8k.topn` rehearsed on the CPU at 600 + 16 rows (one
-shard, so two chunks a TopN) through the whole of a run, as
+shard, so one candidate program a TopN) through the whole of a run, as
 `test_rehearsal.py` rehearses `zipf-64.adhoc`: every answer agrees and the
 run is still no measurement; an answer altered underneath ends with
 `correct` false; and the four readers this cell brought return numbers,
@@ -14,7 +14,7 @@ from conftest import HERE
 
 import run
 
-TINY = os.path.join(HERE, "data", "BENCHMARK.tiny1x8k.json")
+TINY = os.path.join(HERE, "data", "BENCHMARK.tiny40.json")
 CELL = "zipf-1x8k.topn"
 ROWS = 616
 NEW = ("topn.chunks_per_op", "topn.candidate_rows_per_op",
@@ -24,42 +24,6 @@ NEW = ("topn.chunks_per_op", "topn.candidate_rows_per_op",
 def args(trace=0, seconds=2.0):
     return argparse.Namespace(workload=CELL, seed=2**31 + 38,
                               seconds=seconds, trace=trace)
-
-
-def test_the_tiny_manifest_is_the_manifest_with_tiny_files():
-    """BENCHMARK.json whole, but for each configuration's `file` and this
-    cell's mix, which `find_cell` looks for under traffic/: the tiny one
-    lies beside the tiny configurations and is named from there."""
-    manifest = run.read_json(run.REPO, "BENCHMARK.json")
-    tiny = run.read_json(TINY)
-    for key in manifest:
-        if key == "configs":
-            assert [{k: v for k, v in c.items() if k != "file"}
-                    for c in tiny[key]] == [
-                {k: v for k, v in c.items() if k != "file"}
-                for c in manifest[key]]
-        elif key == "workloads":
-            assert [dict(w, traffic="topn") if w["name"] == CELL else w
-                    for w in tiny[key]] == manifest[key]
-        else:
-            assert tiny[key] == manifest[key], key
-    _, cell, cfg, mix = run.find_cell(CELL, TINY)
-    _, _, full, full_mix = run.find_cell(CELL)
-    assert cell["chips"] == 1 and cfg["shards"] == full["shards"] == 1
-    assert cfg["server_flags"] == full["server_flags"] == []
-    assert {k: v for k, v in cfg.items() if k != "fields"} == {
-        k: v for k, v in full.items() if k != "fields"}
-    assert [f["rows"] for f in cfg["fields"]] == [ROWS, 32]
-    assert [f["rows"] for f in full["fields"]] == [8208, 32]
-    # The mix with its draws cut to the rows there are: the same
-    # templates, weights and PQL.
-    assert mix["writer_rows"] == {"f": [ROWS - 16, ROWS - 1]}
-    assert full_mix["writer_rows"] == {"f": [8192, 8207]}
-    assert mix["probe"]["leaves"] == ROWS + 1
-    assert full_mix["probe"]["leaves"] == 8208 + 1
-    assert [(t["name"], t["weight"], t["pql"]) for t in mix["templates"]] \
-        == [(t["name"], t["weight"], t["pql"])
-            for t in full_mix["templates"]]
 
 
 def test_cell_agrees_on_every_answer_and_is_no_measurement():
@@ -78,13 +42,13 @@ def test_cell_agrees_on_every_answer_and_is_no_measurement():
     manifest = run.read_json(TINY)
     listed = [m for m in manifest["per_layer"]
               if run.metric_applies(m, CELL)]
-    assert {m["name"] for m in listed} >= set(NEW) and len(listed) == 10
+    assert {m["name"] for m in listed} >= set(NEW) and len(listed) == 16
     assert set(result["metrics"]) == {
         m["name"] for m in listed
         if m["layer"] != "device" and m["source"] != "device_trace"}
     value = {k: v["value"] for k, v in result["metrics"].items()}
-    # 616 candidate rows a TopN, in two chunks (512 + 104).
-    assert value["topn.chunks_per_op"] == 2.0
+    # 616 candidate rows a TopN, in one program.
+    assert value["topn.chunks_per_op"] == 1.0
     assert value["topn.candidate_rows_per_op"] == float(ROWS)
     assert value["topn.host_self_ms"] > 0
 
