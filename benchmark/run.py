@@ -17,8 +17,8 @@ no result. Alone in a directory (no pilosa_tpu beside benchmark/): 2.
 
 Everything a cell is made of is found by name: configs/<config>.json,
 traffic/<mix>.json, layers/<metric>.py, and draws/<draw>.py for a field
-drawn by a law that generate.py does not have. See PERF.md, "Adding a
-cell".
+drawn by a law that generate.py does not have. See PERF.md, "How a cell
+is added".
 """
 
 import time
@@ -271,7 +271,7 @@ def read_back(srv, cfg, mix, sent, disk_fault=None):
     return client.in_threads(4, ask, asks)
 
 
-def grown(before, after, groups=("engine_cache", "batcher")):
+def grown(before, after, groups=("engine_cache", "batcher", "executor")):
     """The /debug/vars counters of those groups that moved over the
     window, each with by how much: what a slow run did that the others did
     not (a plane first touched, an eviction, a program built)."""
@@ -327,7 +327,14 @@ def kernel_probe(srv, cfg, mix, seed):
     every query, under a profiler capture. Whatever program serves a wave
     has to read each of its planes from HBM at least once; the reader
     divides that least time by the device's busy time. Before each wave
-    one Set on a writer-owned row makes every memo entry stale."""
+    one Set on a writer-owned row makes every memo entry stale.
+
+    The mix's `probe.pql` is formatted with `i` (the question's place in
+    its wave), `j` (a permutation of the places) and `w` (the wave's
+    number, from 0). A probe whose answers that Set cannot stale (a Sum
+    that reads no writer-owned row) must name `{w}`, so that no wave is
+    answered from the memo; a string that does not name it ignores it."""
+    # A probe over answers that its Set cannot stale names {w}.
     spec = mix.get("probe")
     if not spec:
         return None
@@ -346,7 +353,7 @@ def kernel_probe(srv, cfg, mix, seed):
         return pql, got["results"][0]
 
     t0 = time.monotonic()
-    for _ in range(waves):
+    for w in range(waves):
         if writer:
             answers.append(ask(
                 f"Set({rng.randrange(n_cols)}, {writer[0]}={writer[1][0]})"))
@@ -354,7 +361,7 @@ def kernel_probe(srv, cfg, mix, seed):
         rng.shuffle(perm)
         answers += client.in_threads(
             width, ask,
-            [spec["pql"].format(i=i, j=perm[i]) for i in range(width)])
+            [spec["pql"].format(i=i, j=perm[i], w=w) for i in range(width)])
         ends.append(time.monotonic())
     waves_s = time.monotonic() - t0
     side.join()

@@ -87,14 +87,8 @@ def control_run(cfg, mix, seed, control, groups=120):
     return judged["wrong_answers"] + judged["unanswered"], n
 
 
-def _tiny(workload):
-    manifest = os.path.join(HERE, "data", "BENCHMARK.tiny40.json")
-    _, _, cfg, mix = run.find_cell(workload, manifest)
-    return cfg, mix
-
-
-def test_controls_come_out_not_correct():
-    cfg, mix = _tiny("zipf-64.adhoc")
+def test_controls_come_out_not_correct(tiny_manifest):
+    _, _, cfg, mix = run.find_cell("zipf-64.adhoc", tiny_manifest)
     for control in CONTROLS:
         wrong, n = control_run(cfg, mix, 5, control, groups=60)
         assert n > 900

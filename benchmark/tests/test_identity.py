@@ -4,7 +4,9 @@ a digest of the drawn index (`Data.cols`), of the sweep and the first 200
 request groups of clients 0 and 15, of every request body the loader
 posts, and of the reference's answers to those groups. The digests were
 taken from the harness as it stood before field options, draw files, int
-fields and the BSI calls were added, and are pinned here.
+fields and the BSI calls were added, and are pinned here. Beside them, a
+digest of the kernel probe's requests across its waves, taken before a
+probe's question could name its wave (`{w}`).
 
     python3 benchmark/tests/test_identity.py     # prints the digests
 """
@@ -24,6 +26,7 @@ import generate  # noqa: E402
 import loader  # noqa: E402
 import reference  # noqa: E402
 import run  # noqa: E402
+from conftest import probe_requests  # noqa: E402
 
 SEED = 3000004201
 GROUPS = 200
@@ -102,7 +105,30 @@ def test_the_cell_draws_sends_loads_and_is_judged_as_before(cell):
     assert fingerprint(cell) == PINNED[cell]
 
 
+def probe_digest(cell):
+    _, _, cfg, mix = run.find_cell(cell)
+    return digest(probe_requests(cfg, mix, SEED))
+
+
+# Taken from the harness as it stood before a probe could name its wave.
+PINNED_PROBES = {
+    "zipf-64.adhoc":
+        "84c713aa7bd31b46d082f15344be8c47a9c91d3de0bc4b95812319be060edb42",
+    "zipf-4x64.adhoc":
+        "45dde87473e10cc49e47a4701b32f7a6206d18a2b0fc8e2359341384dd9d070c",
+    "zipf-1x8k.topn":
+        "d0f6ad4b821797bff2f902492ffde19b338f8933a027405c53871c67b80c32ba",
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PINNED_PROBES))
+def test_the_cells_probe_asks_as_before(cell):
+    assert probe_digest(cell) == PINNED_PROBES[cell]
+
+
 if __name__ == "__main__":
     manifest = run.read_json(run.REPO, "BENCHMARK.json")
     for w in manifest["workloads"]:
-        print(json.dumps({w["name"]: fingerprint(w["name"])}), flush=True)
+        print(json.dumps({w["name"]: dict(fingerprint(w["name"]),
+                                          probe=probe_digest(w["name"]))}),
+              flush=True)

@@ -109,19 +109,22 @@ def test_every_named_file_exists(manifest):
             assert re.match(r"^[A-Za-z0-9_.\-]+$", n), os.path.join(dirpath, n)
 
 
-TINY = os.path.join(BENCH, "tests", "data", "BENCHMARK.tiny40.json")
-# The tiny configurations' shards; each cuts rows and bits, nothing else.
-TINY_SHARDS = {"zipf-64.adhoc": 2, "zipf-4x64.adhoc": 8, "zipf-1x8k.topn": 1}
+def manifest_cells():
+    """The cells of BENCHMARK.json, read when the tests are collected."""
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        return sorted(w["name"] for w in json.load(f)["workloads"])
 
 
-@pytest.mark.parametrize("cell", sorted(TINY_SHARDS))
-def test_the_tiny_manifest_is_the_manifest_with_tiny_files(manifest, cell):
-    """The one mirror every rehearsal reads: BENCHMARK.json whole, but for
-    each configuration's `file` and a mix that lies beside the tiny
-    configurations (named from traffic/ by a relative path). Each cell's
-    tiny configuration is its own with fewer shards, rows and bits, and its
-    mix has the same templates, weights and PQL."""
-    tiny = run.read_json(TINY)
+@pytest.mark.parametrize("cell", manifest_cells())
+def test_the_tiny_manifest_is_the_manifest_with_tiny_files(
+        manifest, cell, tiny_manifest):
+    """The mirror every rehearsal reads (`tiny.py`): BENCHMARK.json whole,
+    but for each configuration's `file` and a mix that has a tiny twin
+    (named from traffic/ by a relative path). Each cell's
+    tiny configuration is its own with at most min(8, its) shards, fewer
+    rows, bits and columns, and its mix has the same templates, weights
+    and PQL."""
+    tiny = run.read_json(tiny_manifest)
     assert set(tiny) == set(manifest)
     for key in manifest:
         if key == "configs":
@@ -135,27 +138,33 @@ def test_the_tiny_manifest_is_the_manifest_with_tiny_files(manifest, cell):
                 {k: v for k, v in w.items() if k != "traffic"}
                 for w in manifest[key]]
             for w, full in zip(tiny[key], manifest[key]):
-                assert w["traffic"] in (full["traffic"], "../tests/data/tiny-"
+                assert w["traffic"] in (full["traffic"],
+                                        "../tests/data/traffic/"
                                         + full["traffic"])
         else:
             assert tiny[key] == manifest[key], key
-    _, small_cell, cfg, mix = run.find_cell(cell, TINY)
+    _, small_cell, cfg, mix = run.find_cell(cell, tiny_manifest)
     _, full_cell, full, full_mix = run.find_cell(cell)
+    name = full_cell["config"]
+    small_file = next(c["file"] for c in tiny["configs"] if c["name"] == name)
+    assert small_file.startswith("benchmark/tests/data/"), (
+        f"configuration {name!r} has no tiny twin "
+        f"benchmark/tests/data/configs/{name}.json")
     assert small_cell["chips"] == full_cell["chips"]
-    assert cfg["shards"] == TINY_SHARDS[cell] <= full["shards"]
+    assert cfg["shards"] <= min(8, full["shards"])
     assert {k: v for k, v in cfg.items() if k not in ("shards", "fields")} \
         == {k: v for k, v in full.items() if k not in ("shards", "fields")}
-    sized = ("rows", "bits")
+    sized = ("rows", "bits", "columns")
     assert [{k: v for k, v in f.items() if k not in sized}
             for f in cfg["fields"]] == [
         {k: v for k, v in f.items() if k not in sized} for f in full["fields"]]
-    assert all(f[k] <= g[k] for f, g in zip(cfg["fields"], full["fields"])
-               for k in sized)
+    assert all(set(f) == set(g) and all(f[k] <= g[k] for k in sized if k in g)
+               for f, g in zip(cfg["fields"], full["fields"]))
     assert [(t["name"], t["weight"], t["pql"]) for t in mix["templates"]] \
         == [(t["name"], t["weight"], t["pql"]) for t in full_mix["templates"]]
     # The writer-owned rows are the last of their field, there as here.
     for small, big in ((cfg, mix), (full, full_mix)):
-        rows = {f["name"]: f["rows"] for f in small["fields"]}
+        rows = {f["name"]: f["rows"] for f in small["fields"] if "rows" in f}
         for field, (lo, hi) in big["writer_rows"].items():
             assert hi == rows[field] - 1 and hi - lo + 1 == big["clients"]
 

@@ -13,7 +13,7 @@ import os
 import numpy as np
 import pytest
 
-from conftest import BENCH, HERE
+from conftest import BENCH, HERE, probe_requests
 
 import generate
 import loader
@@ -33,7 +33,7 @@ def mix_of(name):
 
 @pytest.fixture(scope="module")
 def zipf():
-    cfg = tiny("tiny-zipf.json")
+    cfg = tiny("configs/zipf-64.json")
     return cfg, generate.Data(cfg, 11)
 
 
@@ -162,7 +162,7 @@ def test_agrees_holds_topn_to_pairs():
 
 
 def test_traffic_follows_its_mix_file():
-    cfg, mix = tiny("tiny-zipf.json"), mix_of("adhoc")
+    cfg, mix = tiny("configs/zipf-64.json"), mix_of("adhoc")
     a = generate.Requests(mix, cfg, 2**31 + 12345, 3)
     b = generate.Requests(mix, cfg, 2**31 + 12345, 3)
     seen = collections.Counter()
@@ -188,7 +188,7 @@ def test_traffic_follows_its_mix_file():
 
 
 def test_the_sweep_names_every_row_a_template_can_name():
-    cfg, mix = tiny("tiny-zipf.json"), mix_of("adhoc")
+    cfg, mix = tiny("configs/zipf-64.json"), mix_of("adhoc")
     clients = mix["clients"]
     sweeps = [generate.Requests(mix, cfg, 7, k).sweep(clients)
               for k in range(clients)]
@@ -226,6 +226,25 @@ def test_readers_return_nothing_where_there_is_nothing_to_read():
         manifest = json.load(f)
     for m in manifest["per_layer"]:
         assert run.load_layer(m["name"]).read(ctx) is None, m["name"]
+
+
+def test_a_probe_that_names_its_wave_asks_no_question_twice():
+    cfg = tiny("configs/zipf-64.json")
+    mix = mix_of("adhoc")
+
+    def waves(pql):
+        mix["probe"] = {"pql": pql, "width": 4, "waves": 8, "leaves": 2}
+        sent = probe_requests(cfg, mix, 2**31 + 43)
+        assert all(p.startswith("Set(") for p in sent[::5])
+        return [sent[k + 1:k + 5] for k in range(0, len(sent), 5)]
+
+    named = waves("Count(Intersect(Row(f={i}), Row(g={w})))")
+    asked = [q for wave in named for q in wave]
+    assert len(asked) == 32 and len(set(asked)) == 32
+    assert named[3][2] == "Count(Intersect(Row(f=2), Row(g=3)))"
+    # Without {w} (and {j}) every wave asks the same questions.
+    same = waves("Count(Intersect(Row(f={i}), Row(g=1)))")
+    assert all(wave == same[0] for wave in same)
 
 
 def test_readers_on_a_hand_made_context():

@@ -8,34 +8,33 @@ constants drawn from `choice` lists).
 First its parts alone: a CPU server loaded with the drawn fields answers
 every Range operation, Sum, Min and Max as the reference does. Then the whole
 of a run through `run.run_cell`, on a manifest made in a temporary directory
-from the one tiny mirror with this configuration and cell added: every
+from the tiny mirror (`tiny.py`) with this configuration and cell added: every
 answer agrees, and one Sum altered where it is produced ends it `correct`
 false."""
 
 import argparse
-import json
 import os
 import shutil
 import tempfile
 
 import pytest
 
-from conftest import HERE, REPO
+from conftest import REPO
 
 import client
 import generate
 import loader
 import reference
 import run
+import tiny
 
-TINY = os.path.join(HERE, "data", "BENCHMARK.tiny40.json")
 CONFIG = "benchmark/tests/data/tiny-int.json"
 CELL = "tiny-int.sum"
 SEED = 2**31 + 4242
 
 
 def manifest_with_the_int_cell(directory):
-    manifest = run.read_json(TINY)
+    manifest = tiny.build()
     manifest["configs"].append({
         "name": "tiny-int", "source": "benchmark/tests/data/tiny-int.json",
         "file": CONFIG, "reduced": [],
@@ -43,10 +42,7 @@ def manifest_with_the_int_cell(directory):
     manifest["workloads"].append({
         "name": CELL, "config": "tiny-int", "traffic": "../tests/data/tiny-sum",
         "chips": 1, "why": "Sum, Min, Max and Count over Ranges, and writes"})
-    path = os.path.join(directory, "BENCHMARK.json")
-    with open(path, "w") as f:
-        json.dump(manifest, f)
-    return path
+    return tiny.manifest_path(directory, manifest)
 
 
 @pytest.fixture(scope="module")
