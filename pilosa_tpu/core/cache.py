@@ -1,13 +1,15 @@
 """Per-fragment TopN row-count caches.
 
-Behavioral port of the reference's cache.go: rankCache (sorted, trimmed,
-throttled invalidation), lruCache, nopCache, plus the Pair/Pairs merge math
-used by the cross-shard TopN reduce (cache.go:315-427).
+Behavioral port of the reference's cache.go: rankCache (sorted, trimmed),
+lruCache, nopCache, plus the Pair/Pairs merge math used by the cross-shard
+TopN reduce (cache.go:315-427). Where the reference re-sorts a rank cache
+at most every 10 seconds, this one re-ranks after every write: a ranking
+older than a write would be a different answer.
 """
 
 from __future__ import annotations
 
-import time
+import threading
 from collections import OrderedDict
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
@@ -15,10 +17,6 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from ..constants import DEFAULT_CACHE_SIZE
-
-# Throttle for rank-cache re-sorting (reference cache.go:44 invalidate at most
-# every 10 seconds).
-RANK_CACHE_INVALIDATE_SECONDS = 10.0
 
 
 @dataclass(frozen=True)
@@ -52,32 +50,60 @@ def sort_pairs(pairs: List[Pair]) -> List[Pair]:
 RankArrays = Tuple[np.ndarray, np.ndarray]  # (ids, counts), int64, rank order
 
 
-def rank_arrays(ranked: List[Pair]) -> RankArrays:
-    """A ranking as two int64 arrays, for the executor's batched TopN
-    runners, which work on the shard axis and read no Pair."""
-    return (np.fromiter((p.id for p in ranked), np.int64, len(ranked)),
-            np.fromiter((p.count for p in ranked), np.int64, len(ranked)))
+def rank_entries(entries: Dict[int, int]) -> RankArrays:
+    """{row: count} as a ranking in sort_pairs' order (count down, then id
+    up), for the executor's batched TopN runners, which work on the shard
+    axis and read no Pair. Built in numpy, with no Pair and no tuple a
+    row. `entries` is read in one C-level copy, so a writer that inserts
+    mid-rank (holding the fragment mutex, not ours) cannot make it
+    raise."""
+    snap = entries.copy()
+    ids = np.fromiter(snap.keys(), np.int64, len(snap))
+    counts = np.fromiter(snap.values(), np.int64, len(snap))
+    order = np.lexsort((ids, -counts))
+    return ids[order], counts[order]
 
 
-_NO_RANKING: RankArrays = rank_arrays([])
+def pairs_of(arrays: RankArrays) -> List[Pair]:
+    ids, counts = arrays
+    return [Pair(id=i, count=c) for i, c in zip(ids.tolist(), counts.tolist())]
+
+
+_NO_RANKING: RankArrays = rank_entries({})
+
+# Rank-cache rebuilds and the rows they ranked, process-wide (/debug/vars
+# `executor`), and each thread's own rebuilds (topn.rank's `rebuilt` tag).
+rank_rebuilds = 0
+rank_rows_sorted = 0
+_this_thread = threading.local()
+
+
+def thread_rank_rebuilds() -> int:
+    return getattr(_this_thread, "rebuilds", 0)
 
 
 class RankCache:
-    """Keeps the top `max_entries` (row, count) pairs, sorted lazily."""
+    """Keeps the top `max_entries` (row, count) pairs, ranked lazily.
+
+    Lock-free: a write bumps `_gen` and drops the ranking, and a rebuild
+    publishes only if no write came after its snapshot, so a reader sees
+    the ranking of the last write, or ranks the rows itself."""
 
     def __init__(self, max_entries: int = DEFAULT_CACHE_SIZE):
         self.max_entries = max_entries
         self.entries: Dict[int, int] = {}
-        self._sorted: Optional[List[Pair]] = None
-        self._arrays: Optional[RankArrays] = None  # _sorted, as arrays
-        self._last_invalidate = 0.0
+        self._gen = 0
+        self._arrays: Optional[RankArrays] = None
+        # (the arrays they were made from, top()'s Pairs)
+        self._pairs: Optional[Tuple[RankArrays, List[Pair]]] = None
 
     def add(self, row_id: int, n: int) -> None:
         if n == 0:
             self.entries.pop(row_id, None)
         else:
             self.entries[row_id] = n
-        self._sorted = self._arrays = None
+        self._gen += 1  # after the write: a rebuild of this generation saw it
+        self._arrays = self._pairs = None
 
     bulk_add = add
 
@@ -90,43 +116,44 @@ class RankCache:
     def __len__(self) -> int:
         return len(self.entries)
 
-    def invalidate(self, force: bool = False) -> None:
-        now = time.monotonic()
-        if not force and self._sorted is not None and (
-            now - self._last_invalidate < RANK_CACHE_INVALIDATE_SECONDS
-        ):
-            return
-        # list() snapshots entries in one C-level call: TopN reads are
-        # lock-free and must not raise if a fragment writer (who holds the
-        # fragment mutex, not ours) inserts mid-iteration.
-        ranked = sort_pairs(
-            [Pair(id=i, count=c) for i, c in list(self.entries.items())]
-        )
-        if len(ranked) > self.max_entries:
-            ranked = ranked[: self.max_entries]
-            self.entries = {p.id: p.count for p in ranked}
-        self._arrays = rank_arrays(ranked)
-        self._sorted = ranked
-        self._last_invalidate = now
+    def invalidate(self) -> RankArrays:
+        """Rank the rows now, trimmed to `max_entries`, and return the
+        ranking. It is kept for later readers only if no write came
+        between the snapshot and here; the check and the stores below make
+        no call, so no other thread runs between them."""
+        global rank_rebuilds, rank_rows_sorted
+        gen = self._gen
+        ids, counts = rank_entries(self.entries)
+        rank_rebuilds += 1
+        rank_rows_sorted += len(ids)
+        _this_thread.rebuilds = thread_rank_rebuilds() + 1
+        kept = None
+        if len(ids) > self.max_entries:
+            ids, counts = ids[: self.max_entries], counts[: self.max_entries]
+            kept = dict(zip(ids.tolist(), counts.tolist()))
+        arrays = (ids, counts)
+        if self._gen == gen:
+            if kept is not None:
+                self.entries = kept
+            self._arrays = arrays
+        return arrays
 
     def top(self) -> List[Pair]:
-        if self._sorted is None:
-            self.invalidate(force=True)
-        return list(self._sorted or [])
+        arrays = self.top_arrays()
+        pairs = self._pairs
+        if pairs is None or pairs[0] is not arrays:
+            pairs = self._pairs = (arrays, pairs_of(arrays))
+        return list(pairs[1])
 
     def top_arrays(self) -> RankArrays:
-        """top() as (ids, counts) arrays, shared and not to be written.
-        Lock-free like top(): a reader racing a writer sees the ranking
-        from before the write, or rebuilds."""
+        """top() as (ids, counts) arrays, shared and not to be written."""
         arrays = self._arrays
-        if arrays is None:
-            self.invalidate(force=True)
-            arrays = self._arrays
-        return arrays if arrays is not None else _NO_RANKING
+        return arrays if arrays is not None else self.invalidate()
 
     def clear(self) -> None:
         self.entries.clear()
-        self._sorted = self._arrays = None
+        self._gen += 1
+        self._arrays = self._pairs = None
 
 
 class LRUCache:
@@ -157,16 +184,14 @@ class LRUCache:
     def __len__(self) -> int:
         return len(self.entries)
 
-    def invalidate(self, force: bool = False) -> None:
+    def invalidate(self) -> None:
         pass
 
     def top(self) -> List[Pair]:
-        return sort_pairs(
-            [Pair(id=i, count=c) for i, c in list(self.entries.items())]
-        )
+        return pairs_of(self.top_arrays())
 
     def top_arrays(self) -> RankArrays:
-        return rank_arrays(self.top())
+        return rank_entries(self.entries)
 
     def clear(self) -> None:
         self.entries.clear()
@@ -187,7 +212,7 @@ class NopCache:
     def __len__(self) -> int:
         return 0
 
-    def invalidate(self, force: bool = False) -> None:
+    def invalidate(self) -> None:
         pass
 
     def top(self) -> List[Pair]:

@@ -1103,15 +1103,13 @@ class Fragment:
     def top_arrays(self):
         """This shard's ranking as (ids, counts) arrays in rank order, for
         the executor's batched TopN: what _top_pairs([]) gives top(), with
-        one throttle check, no Pair and no lock."""
-        self.cache.invalidate()
+        no Pair and no lock."""
         return self.cache.top_arrays()
 
     def _top_pairs(self, row_ids: List[int]) -> List[Pair]:
         if self.cache_type == CACHE_TYPE_NONE and not row_ids:
             return []
         if not row_ids:
-            self.cache.invalidate()
             return self.cache.top()
         pairs = []
         for row_id in row_ids:
@@ -1315,7 +1313,7 @@ class Fragment:
                      if journal else None)
             self._invalidate_row(int(row_id), words)
             self.cache.bulk_add(int(row_id), int(counts[i]))
-        self.cache.invalidate(force=True)
+        self.cache.invalidate()
 
     def bulk_import(self, row_ids: np.ndarray, column_ids: np.ndarray) -> None:
         """Set many bits at once (reference fragment.go:1298), amortized:
@@ -1606,7 +1604,7 @@ class Fragment:
             ids = np.frombuffer(data, dtype="<u8", count=n, offset=4)
         for row_id in ids:
             self.cache.bulk_add(int(row_id), self.row_count(int(row_id)))
-        self.cache.invalidate(force=True)
+        self.cache.invalidate()
 
     def flush_cache(self) -> None:
         with self._mu:  # cache.ids() must not race writers' cache.add
@@ -1658,7 +1656,7 @@ class Fragment:
             self._invalidate_all()
             for row_id in self.rows():
                 self.cache.bulk_add(row_id, self.row_count(row_id))
-            self.cache.invalidate(force=True)
+            self.cache.invalidate()
             if self.path:
                 self.snapshot()
 
@@ -1710,6 +1708,6 @@ class Fragment:
             self.cache.clear()
             for row_id in self.rows():
                 self.cache.bulk_add(row_id, self.row_count(row_id))
-            self.cache.invalidate(force=True)
+            self.cache.invalidate()
         if self.path:
             self.snapshot()

@@ -18,7 +18,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import numpy as np
 
 from .constants import MAX_WRITES_PER_REQUEST, SHARD_WIDTH, VIEW_BSI_GROUP_PREFIX, VIEW_STANDARD
-from .core.cache import Pair, add_pairs, sort_pairs
+from .core.cache import Pair, add_pairs, sort_pairs, thread_rank_rebuilds
 from .core.fragment import Fragment, TopOptions
 from .core.holder import Holder
 from .core.row import Row
@@ -1607,6 +1607,7 @@ class Executor:
 
             def local_runner(local_shards):
                 with obs_span("topn.rank", shards=len(local_shards)) as sp:
+                    rebuilt = thread_rank_rebuilds()
                     shard_list, rankings = [], []
                     for s in local_shards:
                         frag = self._fragment(
@@ -1623,7 +1624,8 @@ class Executor:
                     if attr_name and attr_values:
                         union = np.asarray(attr_rows(union.tolist()), np.int64)
                         cand &= np.isin(rank_ids, union)
-                    sp.tag(rows=len(union))
+                    sp.tag(rows=len(union),
+                           rebuilt=thread_rank_rebuilds() - rebuilt)
                 if not len(union):
                     return []
                 chunks = []

@@ -659,7 +659,7 @@ class API:
             for field in index.fields.values():
                 for view in field.views.values():
                     for frag in view.fragments.values():
-                        frag.cache.invalidate(force=True)
+                        frag.cache.invalidate()
         self.server.broadcast_message({"type": "recalculate-caches"})
 
     def max_inverse_shards(self):  # parity stub: inverse views removed upstream

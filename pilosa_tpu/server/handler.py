@@ -18,6 +18,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Callable, Dict, List, Optional, Tuple
 from urllib.parse import parse_qs, urlparse
 
+from ..core import cache as cache_mod
 from ..core.cache import Pair
 from ..core.row import Row
 from ..errors import PilosaError
@@ -1024,6 +1025,10 @@ class Handler:
                 "topn_queries": executor.topn_queries,
                 "topn_chunks": executor.topn_chunks,
                 "topn_candidate_rows": executor.topn_candidate_rows,
+                # Rank-cache rebuilds (a write drops a fragment's ranking;
+                # the next reader ranks it again) and the rows they ranked.
+                "rank_rebuilds": cache_mod.rank_rebuilds,
+                "rank_rows_sorted": cache_mod.rank_rows_sorted,
             }
         # Ingest health (docs/ingest.md): un-snapshotted WAL bytes across
         # fragments, background-snapshot counters and queue depth, and how

@@ -1310,7 +1310,7 @@ class Server:
                 for field in index.fields.values():
                     for view in field.views.values():
                         for frag in view.fragments.values():
-                            frag.cache.invalidate(force=True)
+                            frag.cache.invalidate()
         elif typ == "resize-instruction":
             from ..cluster.resize import follow_resize_instruction
 

@@ -4,8 +4,10 @@ i.e. `Fragment.top` with `opt.src`) is the reference they are held to, for
 every option, shard by shard and pair by pair."""
 
 import itertools
+import json
 import sys
 import threading
+import urllib.request
 
 import numpy as np
 import pytest
@@ -13,8 +15,9 @@ import pytest
 from pilosa_tpu import executor as ex_mod
 from pilosa_tpu import failpoints
 from pilosa_tpu.constants import SHARD_WIDTH
+from pilosa_tpu.core import cache as cache_mod
 from pilosa_tpu.core.cache import (
-    LRUCache, NopCache, RankCache, add_pairs, sort_pairs,
+    LRUCache, NopCache, Pair, RankCache, add_pairs, sort_pairs,
 )
 from pilosa_tpu.core.field import FieldOptions
 from pilosa_tpu.core.holder import Holder
@@ -297,11 +300,153 @@ def test_rank_cache_arrays_are_dropped_with_the_sorted_list():
     assert cache.top_arrays()[0].tolist() == [2, 3]  # trimmed as top() is
     first = cache.top_arrays()
     assert cache.top_arrays() is first  # kept, not rebuilt per read
+    assert [(p.id, p.count) for p in cache.top()] == [(2, 7), (3, 6)]
+    assert cache._pairs[0] is first  # top()'s Pairs, made from the arrays
     cache.add(3, 9)
-    assert cache._sorted is None and cache._arrays is None
+    assert cache._pairs is None and cache._arrays is None
     assert cache.top_arrays()[0].tolist() == [3, 2]
     cache.add(3, 0)  # a row that emptied leaves the ranking
     assert cache.top_arrays()[0].tolist() == [2]
+
+
+def _tied_counts(n, seed):
+    """{row: count} of `n` rows whose counts tie in runs: a few distinct
+    counts, so most of the order is the tie-break on the id."""
+    rng = np.random.default_rng(seed)
+    ids = rng.choice(1 << 40, n, replace=False)
+    counts = rng.integers(1, max(2, n // 40), n)
+    return dict(zip(ids.tolist(), counts.tolist()))
+
+
+@pytest.mark.parametrize("n,max_entries", [
+    (48, 50000), (48, 20), (8208, 50000), (8208, 5000)])
+def test_the_numpy_ranking_keeps_sort_pairs_order_and_trim(n, max_entries):
+    entries = _tied_counts(n, seed=n + max_entries)
+    want = sort_pairs([Pair(id=i, count=c) for i, c in entries.items()])
+    want = [(p.id, p.count) for p in want[:max_entries]]
+    cache = RankCache(max_entries)
+    for i, c in entries.items():
+        cache.add(i, c)
+    ids, counts = cache.top_arrays()
+    assert list(zip(ids.tolist(), counts.tolist())) == want
+    assert [(p.id, p.count) for p in cache.top()] == want
+    assert cache.entries == dict(want)  # trimmed as the ranking is
+    lru = LRUCache(max(n, max_entries))
+    for i, c in entries.items():
+        lru.add(i, c)
+    assert [(p.id, p.count) for p in lru.top()][:max_entries] == want
+
+
+def test_a_batched_topn_after_a_set_makes_no_pair_in_the_rank_cache(
+        monkeypatch):
+    """The ranking a write dropped is rebuilt on arrays alone: of the
+    Pairs made in core/cache.py, none come from the rank cache."""
+    holder = Holder(None)
+    holder.open()
+    idx = holder.create_index("i")
+    f = idx.create_field("f")
+    idx.create_field("g")
+    ex = Executor(holder, translate_store=TranslateStore().open(), workers=0)
+    makers = []
+
+    def counting_pair(*args, **kw):
+        makers.append(sys._getframe(1).f_code.co_name)
+        return Pair(*args, **kw)
+
+    try:
+        rng = np.random.default_rng(44)
+        for row in range(30):
+            f.import_bits([row] * (row + 1),
+                          rng.choice(4096, row + 1, replace=False).tolist())
+        for col in (1, 7, 4000):
+            ex.execute("i", f"Set({col}, g=2)")
+        q = "TopN(f, Row(g=2), n=5)"
+        ex.execute("i", q)
+        monkeypatch.setattr(cache_mod, "Pair", counting_pair)
+        rebuilds, walks = cache_mod.rank_rebuilds, ex.topn_array_walks
+        ex.execute("i", "Set(1, f=3)")
+        got = [(p.id, p.count) for p in ex.execute("i", q)[0]]
+        assert ex.topn_array_walks == walks + 2  # both phases on arrays
+        assert cache_mod.rank_rebuilds == rebuilds + 1  # it did re-rank
+        assert set(makers) <= {"add_pairs"}, makers
+        monkeypatch.setattr(cache_mod, "Pair", Pair)
+        assert got == _rung(ex, parse(q).calls[0], [0]) and got
+    finally:
+        ex.close()
+        holder.close()
+
+
+def test_a_write_between_a_rebuilds_snapshot_and_its_publish(monkeypatch):
+    """The publish guard: a rebuild that a write overtook hands its
+    ranking to its own caller and keeps nothing, so the next reader ranks
+    the counts after the write, and a trim does not drop the write."""
+    cache = RankCache(3)
+    for r, c in ((1, 5), (2, 7), (3, 6), (4, 1)):
+        cache.add(r, c)
+    rank = cache_mod.rank_entries
+
+    def overtaken(entries):
+        got = rank(entries)
+        cache.add(5, 9)  # lands after the snapshot, before the publish
+        return got
+
+    monkeypatch.setattr(cache_mod, "rank_entries", overtaken)
+    ids, counts = cache.invalidate()
+    assert ids.tolist() == [2, 3, 1]  # the caller's: before the write
+    assert cache._arrays is None
+    assert cache.entries[5] == 9 and len(cache.entries) == 5  # not trimmed
+    monkeypatch.setattr(cache_mod, "rank_entries", rank)
+    ids, counts = cache.top_arrays()
+    assert list(zip(ids.tolist(), counts.tolist())) == [(5, 9), (2, 7), (3, 6)]
+    assert cache.top_arrays() is cache._arrays  # this one was kept
+    assert cache.entries == {5: 9, 2: 7, 3: 6}
+
+
+def test_rank_rebuilds_counts_a_rebuild_not_a_read():
+    cache = RankCache(10)
+    cache.add(1, 4)
+    cache.add(2, 4)
+    rebuilds, rows = cache_mod.rank_rebuilds, cache_mod.rank_rows_sorted
+    mine = cache_mod.thread_rank_rebuilds()
+    cache.add(3, 8)
+    cache.top_arrays()
+    cache.top_arrays()
+    assert cache_mod.rank_rebuilds == rebuilds + 1
+    assert cache_mod.rank_rows_sorted == rows + 3
+    assert cache_mod.thread_rank_rebuilds() == mine + 1
+
+
+def test_rank_rebuilds_reach_debug_vars_and_the_rank_span():
+    from pilosa_tpu.server.client import InternalClient
+    from pilosa_tpu.server.server import Server
+
+    srv = Server(cache_flush_interval=0, member_monitor_interval=0)
+    srv.open()
+    try:
+        host = f"localhost:{srv.port}"
+        client = InternalClient()
+        idx = srv.holder.create_index("t")
+        idx.create_field("f").import_bits([1, 1, 2], [0, 5, 9])
+        idx.create_field("g").import_bits([3, 3], [5, 9])
+
+        def executor_vars():
+            with urllib.request.urlopen(f"http://{host}/debug/vars") as r:
+                return json.load(r)["executor"]
+
+        was = executor_vars()
+        client.query(host, "t", "Set(9, f=1)")
+        got = client.query(host, "t", "TopN(f, Row(g=3), n=2)")["results"]
+        assert got == [[{"id": 1, "count": 2}, {"id": 2, "count": 1}]]
+        now = executor_vars()
+        assert now["rank_rebuilds"] == was["rank_rebuilds"] + 1
+        assert now["rank_rows_sorted"] == was["rank_rows_sorted"] + 2
+        with urllib.request.urlopen(
+                f"http://{host}/debug/traces?limit=1") as r:
+            spans = json.load(r)["traces"][0]["spans"]
+        rank = [sp for sp in spans if sp["name"] == "topn.rank"]
+        assert [sp["tags"]["rebuilt"] for sp in rank] == [1]
+    finally:
+        srv.close()
 
 
 def test_a_set_between_two_topns_shows_in_the_arrays():
@@ -370,7 +515,7 @@ def test_a_reader_of_the_arrays_never_raises_while_a_writer_adds():
         for i in range(20000):
             cache.add(int(rng.integers(0, 200)), int(rng.integers(0, 50)))
             if i % 500 == 0:
-                cache.invalidate(force=True)  # trims to 64, swaps entries
+                cache.invalidate()  # trims to 64, swaps entries
     finally:
         stop.set()
         for t in readers:

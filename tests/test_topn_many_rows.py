@@ -259,6 +259,8 @@ def test_the_spans_of_a_chunked_topn(served, budget, which):
     fanouts = {s["id"] for s in by_name["executor.fanout"]}
     assert len(fanouts) == 2    # the candidates, and the refetch
     rank, = by_name["topn.rank"]
+    # One shard: it re-ranked only if a write dropped its ranking.
+    assert rank["tags"].pop("rebuilt") in (0, 1)
     assert rank["tags"] == {"shards": 1, "rows": ROWS}
     chunks = sorted(by_name["topn.chunk"], key=lambda s: s["start_ms"])
     assert [s["tags"] for s in chunks] == [
