@@ -25,6 +25,13 @@ Field draws (a field's `draw` names one; its other keys are the draw's):
     one_row_per_column   `columns`, `rows`, optionally `row_exponent` and
                          `row_ratio`: columns 0..columns-1 each lie in one
                          of `rows` rows, uniform or by the zipf law above
+    fingerprint_families `rows`, `columns`, `family_size`, `scaffold_bits`,
+                         `keep`, `own_bits`, `column_exponent`,
+                         `column_ratio`, optionally `writer_rows`: each row
+                         a molecule's fingerprint over columns
+                         0..columns-1, drawn in families of analogues
+                         around a scaffold, column popularity by the zipf
+                         law above; the writer rows last
 
 A field may also carry `options`, posted as they are when the field is
 created (`{"type": "int", "min": 0, "max": 10}` makes an int field).

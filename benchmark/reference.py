@@ -21,6 +21,21 @@ Int fields, as upstream's BSI calls read them:
                         many of those columns hold it}
 With no column to read, each is {"value": 0, "count": 0}, as the server
 answers it.
+
+TopN(f, filter, tanimotoThreshold=t), upstream's similarity search: with
+a = |r| for a row r of f, b = |filter| and c = |r & filter|, r passes when
+c > 0 and 100 c > t (a + b - c), the whole-number form of upstream's
+ceil(100 c / (a + b - c)) > t; its bounds on a need no rule of their own,
+since a row outside them fails this test too. The answer is the tuple of
+any TopN, with c where a row passes and 0 where it fails, so `agrees`
+judges it as it judges the others. A row that clients write during the run
+may appear or not: where it does, it counts from 1 to its loaded c plus
+the run's Sets on it, so its count is 0 and its slack that much. Refused
+(ValueError), so that a mix cannot be judged against another question:
+t outside 1..100, a TopN without a filter, a filter that names a
+writer-owned row (b would move under the writes), and a field with more
+rows than its `cacheSize` (default 50,000), since upstream ranks only the
+rows its rank cache holds and this reference ranks every row.
 """
 
 import operator
@@ -32,6 +47,9 @@ _TOKEN = re.compile(
     r"\s*([A-Za-z_][A-Za-z_0-9]*|-?\d+|><|[<>=!]=|[<>(),=\[\]])")
 _COMPARE = {"<": operator.lt, "<=": operator.le, ">": operator.gt,
             ">=": operator.ge, "==": operator.eq, "!=": operator.ne}
+# Rows a field's rank cache holds where its options do not say (upstream's
+# field.go DefaultCacheSize).
+DEFAULT_CACHE_SIZE = 50000
 
 
 def parse(pql):
@@ -120,6 +138,7 @@ class Reference:
         del rank
         self.memo = {}
         self.ranges = {}        # (field, op, v) -> packed bitset
+        self.matrices = {}      # field -> its packed rows, stacked
         # (field, row) -> [count, set of columns added], for rows that one
         # client alone writes.
         self.written = {}
@@ -173,6 +192,8 @@ class Reference:
         name, pos, kw = call
         if name == "Count":
             return self.count(self.bitmap(pos[0]))
+        if name == "TopN" and "tanimotoThreshold" in kw:
+            return self._tanimoto_top(pos, kw)
         if name == "TopN":
             filt = self.bitmap(pos[1]) if len(pos) > 1 else None
             counts = self.row_counts(pos[0], filt)
@@ -183,6 +204,63 @@ class Reference:
             return self.val_count(name, kw["field"],
                                   self.bitmap(pos[0]) if pos else None)
         raise ValueError(f"the reference does not answer {name}")
+
+    def _tanimoto_top(self, pos, kw):
+        field, t = pos[0], kw["tanimotoThreshold"]
+        if not 1 <= t <= 100:
+            raise ValueError(f"tanimotoThreshold={t} is not from 1 to 100")
+        if len(pos) < 2:
+            raise ValueError("the reference answers tanimotoThreshold only "
+                             "under a filter")
+        named = [key for key in self._rows_named(pos[1])
+                 if key in self.written]
+        if named:
+            raise ValueError(f"a tanimoto filter names the writer-owned row "
+                             f"{named[0]}: draw the query from the read rows")
+        n_rows = len(self.data.cols[field])
+        cache = self.cache_size(field)
+        if n_rows > cache:
+            raise ValueError(f"field {field!r} holds {n_rows} rows and ranks "
+                             f"{cache} (cacheSize): upstream would rank "
+                             "fewer rows than the reference does")
+        filt = self.bitmap(pos[1])
+        rows = self.matrix(field)
+        a = np.bitwise_count(rows).sum(axis=1, dtype=np.int64)
+        c = np.bitwise_count(rows & filt).sum(axis=1, dtype=np.int64)
+        keep = self.tanimoto_passes(c, a, self.count(filt), t)
+        counts = np.where(keep, c, 0)
+        slack = np.zeros(n_rows, dtype=np.int64)
+        for f, r in self.written:
+            if f == field:
+                counts[r] = 0
+                slack[r] = c[r] + self.sets_on.get((f, r), 0)
+        return ("topn", counts.tolist(), kw.get("n", 0), slack.tolist())
+
+    @staticmethod
+    def tanimoto_passes(c, a, b, t):
+        """Which rows reach the threshold: c > 0 and 100 c > t (a + b - c),
+        element by element, in whole numbers."""
+        return (c > 0) & (100 * c > t * (a + b - c))
+
+    def _rows_named(self, call):
+        name, pos, kw = call
+        if name == "Row":
+            yield next(iter(kw.items()))
+        for p in pos:
+            if isinstance(p, tuple):
+                yield from self._rows_named(p)
+
+    def cache_size(self, field):
+        spec = next(f for f in self.data.cfg["fields"] if f["name"] == field)
+        return spec.get("options", {}).get("cacheSize", DEFAULT_CACHE_SIZE)
+
+    def matrix(self, field):
+        """A set field's packed rows as one (rows, words) array."""
+        if field not in self.matrices:
+            self.matrices[field] = np.stack(
+                [self.rows[field, r]
+                 for r in range(len(self.data.cols[field]))])
+        return self.matrices[field]
 
     def val_count(self, name, field, filt):
         places, values = self.ints[field]

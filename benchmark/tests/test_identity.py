@@ -6,9 +6,15 @@ posts, and of the reference's answers to those groups. The digests were
 taken from the harness as it stood before field options, draw files, int
 fields and the BSI calls were added, and are pinned here. Beside them, a
 digest of the kernel probe's requests across its waves, taken before a
-probe's question could name its wave (`{w}`).
+probe's question could name its wave (`{w}`). And, at the tiny twins'
+sizes, the reference's answers themselves to a sample of every cell's
+requests and of the int rehearsal's, recorded in `data/twin_answers.json`
+from the reference as it stood before a TopN could carry
+`tanimotoThreshold`.
 
     python3 benchmark/tests/test_identity.py     # prints the digests
+    python3 benchmark/tests/test_identity.py --record-twins  # rewrites
+                                                             # the file
 """
 
 import hashlib
@@ -26,6 +32,7 @@ import generate  # noqa: E402
 import loader  # noqa: E402
 import reference  # noqa: E402
 import run  # noqa: E402
+import tiny  # noqa: E402
 from conftest import probe_requests  # noqa: E402
 
 SEED = 3000004201
@@ -126,7 +133,67 @@ def test_the_cells_probe_asks_as_before(cell):
     assert probe_digest(cell) == PINNED_PROBES[cell]
 
 
-if __name__ == "__main__":
+TWIN_SEED = 2**31 + 4601
+TWIN_GROUPS = 40
+TWIN_ANSWERS = os.path.join(HERE, "data", "twin_answers.json")
+# The int rehearsal's configuration and mix, beside the manifest's cells.
+INT_REHEARSAL = ("tiny-int.json", "tiny-sum.json")
+
+
+def twin_samples(manifest_path):
+    """{name: (cfg, mix)}: each cell of the manifest at its tiny twin's
+    size, and the int rehearsal."""
+    out = {}
+    for w in run.read_json(manifest_path)["workloads"]:
+        _, _, cfg, mix = run.find_cell(w["name"], manifest_path)
+        out[w["name"]] = cfg, mix
+    cfg, mix = (run.read_json(HERE, "data", f) for f in INT_REHEARSAL)
+    out["tiny-int.sum"] = cfg, mix
+    return out
+
+
+def twin_answers(cfg, mix):
+    """[[pql, answer], ...] of the reference at TWIN_SEED: the sweep and
+    the first TWIN_GROUPS groups of clients 0 and 15, in the order judge
+    asks them, each answer as its JSON carries it."""
+    data = generate.Data(cfg, TWIN_SEED)
+    groups = []
+    for k in CLIENTS:
+        stream = generate.Requests(mix, cfg, TWIN_SEED, k)
+        groups += stream.sweep(mix["clients"]) + [
+            stream.next() for _ in range(TWIN_GROUPS)]
+    pqls = [pql for _, g in groups for pql in g]
+    ref = reference.build(data, mix)
+    ref.expect_sets(pqls)
+    return [[pql, json.loads(json.dumps(ref.answer(pql)))] for pql in pqls]
+
+
+@pytest.fixture(scope="module")
+def recorded_twins():
+    with open(TWIN_ANSWERS) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("cell", sorted(PINNED) + ["tiny-int.sum"])
+def test_the_twins_answers_are_as_recorded(cell, tiny_manifest,
+                                           recorded_twins):
+    cfg, mix = twin_samples(tiny_manifest)[cell]
+    want = recorded_twins[cell]
+    got = twin_answers(cfg, mix)
+    assert [p for p, _ in got] == [p for p, _ in want]
+    for (pql, a), (_, b) in zip(got, want):
+        assert json.dumps(a) == json.dumps(b), pql
+    assert any(p.startswith("TopN(") for p, _ in got) or cell == "tiny-int.sum"
+
+
+if __name__ == "__main__" and sys.argv[1:] == ["--record-twins"]:
+    import tempfile
+    with tempfile.TemporaryDirectory() as d:
+        samples = twin_samples(tiny.manifest_path(d))
+    with open(TWIN_ANSWERS, "w") as f:
+        json.dump({name: twin_answers(*s) for name, s in samples.items()}, f,
+                  separators=(",", ":"))
+elif __name__ == "__main__":
     manifest = run.read_json(run.REPO, "BENCHMARK.json")
     for w in manifest["workloads"]:
         print(json.dumps({w["name"]: dict(fingerprint(w["name"]),

@@ -4,10 +4,14 @@ law against its formula, the reference against plain Python sets, the traffic ge
 mix file, the readers against a hand-made context. And the parts that
 integer fields brought: the draw files' laws, a draw found by name, field
 options in the schema, a BSI fragment read back to its values, and the
-reference's Range, Sum, Min and Max against plain Python."""
+reference's Range, Sum, Min and Max against plain Python. And those of the
+similarity search: the fingerprint draw's families, and the reference's
+TopN under `tanimotoThreshold` against plain Python sets."""
 
 import collections
+import importlib.util
 import json
+import math
 import os
 
 import numpy as np
@@ -41,6 +45,12 @@ def zipf():
 def ints():
     cfg = tiny("tiny-int.json")
     return cfg, generate.Data(cfg, 2**31 + 42)
+
+
+@pytest.fixture(scope="module")
+def chem():
+    cfg = tiny("tiny-chem.json")
+    return cfg, generate.Data(cfg, 2**31 + 46)
 
 
 def columns_of(data, field, row):
@@ -452,3 +462,137 @@ def test_range_sum_min_max_against_plain_python(ints):
     assert reference.exact_sum(big) == 2**63 - 5
     with pytest.raises(ValueError):
         reference.parse("Count(Range(quantity >< 3))")
+
+
+def fingerprint_families():
+    spec = importlib.util.spec_from_file_location(
+        "fingerprint_families",
+        os.path.join(generate.DRAWS, "fingerprint_families.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def tanimoto_matrix(rows, columns):
+    held = np.zeros((len(rows), columns), dtype=np.float32)
+    for i, c in enumerate(rows):
+        held[i, c] = 1.0
+    inter = held @ held.T
+    size = held.sum(axis=1)
+    return inter / (size[:, None] + size[None, :] - inter)
+
+
+def test_fingerprints_are_drawn_from_the_seed_over_their_columns(chem):
+    cfg, data = chem
+    f = field_of(cfg, "m")
+    rows = data.cols["m"]
+    assert len(rows) == f["rows"]
+    assert all(len(c) and c.dtype == np.uint32 and c[-1] < f["columns"]
+               and np.all(np.diff(c.astype(np.int64)) > 0) for c in rows)
+    again = generate.Data(cfg, 2**31 + 46).cols["m"]
+    assert all(np.array_equal(a, b) for a, b in zip(rows, again))
+    other = generate.Data(cfg, 2**31 + 47).cols["m"]
+    assert not all(np.array_equal(a, b) for a, b in zip(rows, other))
+    sizes = [len(c) for c in rows]
+    assert abs(np.median(sizes) - f["scaffold_bits"]) \
+        <= 0.15 * f["scaffold_bits"]
+    # A few substructure bits lie in most molecules.
+    held = np.bincount(np.concatenate(rows), minlength=f["columns"])
+    assert np.sort(held)[-3] > 0.5 * len(rows)
+
+
+def test_fingerprints_come_in_families_of_analogues(chem):
+    cfg, data = chem
+    f = field_of(cfg, "m")
+    rows, family = fingerprint_families().families(
+        f, np.random.default_rng([2**31 + 46, 0]))
+    assert all(np.array_equal(a, b) for a, b in zip(rows, data.cols["m"]))
+    tan = tanimoto_matrix(rows, f["columns"])
+    same = family[:, None] == family[None, :]
+    np.fill_diagonal(same, False)
+    across = family[:, None] != family[None, :]
+    assert np.median(tan[same]) >= 0.6
+    assert np.median(tan[across]) <= 0.3
+    read = f["rows"] - f["writer_rows"]
+    sizes = np.bincount(family[:read])
+    lo, hi = f["family_size"]
+    assert np.all(np.diff(family[:read]) >= 0)      # families lie in blocks
+    assert sizes[:-1].min() >= lo and sizes.max() <= hi
+    # What a search finds at each published threshold, the query itself
+    # left out: analogues at the low end, fewer at the high end.
+    found = {t: np.median((tan[:read, :read] > t / 100).sum(axis=1) - 1)
+             for t in (50, 90)}
+    assert found[50] >= 10 and found[50] > found[90]
+
+
+def test_the_writer_rows_come_last_and_are_analogues(chem):
+    cfg, data = chem
+    f = field_of(cfg, "m")
+    mix = tiny("tiny-tanimoto.json")
+    lo, hi = mix["writer_rows"]["m"]
+    read = f["rows"] - f["writer_rows"]
+    # As tiny.py's twin rule asks: the clients' rows end the field.
+    assert (lo, hi) == (read, f["rows"] - 1)
+    assert hi - lo + 1 == mix["clients"] == f["writer_rows"]
+    rows, family = fingerprint_families().families(
+        f, np.random.default_rng([2**31 + 46, 0]))
+    assert set(family[read:]) <= set(family[:read])
+    tan = tanimoto_matrix(rows, f["columns"])
+    kin = family[read:, None] == family[None, :read]
+    assert np.median(np.where(kin, tan[read:, :read], 0).max(axis=1)) >= 0.6
+
+
+def test_tanimoto_topn_against_plain_python(chem):
+    cfg, data = chem
+    mix = tiny("tiny-tanimoto.json")
+    ref = reference.build(data, mix)
+    writers = range(mix["writer_rows"]["m"][0], mix["writer_rows"]["m"][1] + 1)
+    ref.expect_sets([f"Set(7, m={writers[0]})"] * 3)
+    rows = [columns_of(data, "m", r) for r in range(len(data.cols["m"]))]
+    for q in (0, 37, 1999):
+        for t in (50, 75, 90):
+            _, counts, n, slack = ref.answer(
+                f"TopN(m, Row(m={q}), n=5, tanimotoThreshold={t})")
+            assert n == 5
+            for r, cols in enumerate(rows):
+                c = len(cols & rows[q])
+                coeff = math.ceil(c * 100 / (len(cols) + len(rows[q]) - c))
+                if r in writers:
+                    extra = 3 if r == writers[0] else 0
+                    assert (counts[r], slack[r]) == (0, c + extra)
+                else:
+                    assert counts[r] == (c if c and coeff > t else 0)
+                    assert slack[r] == 0
+            assert counts[q] == len(rows[q])
+    # The plain TopN is answered as it was, writer rows at their loaded
+    # count with their Sets as slack.
+    _, counts, _, slack = ref.answer(f"TopN(m, Row(m={q}), n=5)")
+    assert counts == [len(c & rows[q]) for c in rows]
+    assert slack[writers[0]] == 3 and sum(slack) == 3
+
+
+@pytest.mark.parametrize("pql, match", [
+    ("TopN(m, Row(m=1), tanimotoThreshold=0)", "from 1 to 100"),
+    ("TopN(m, Row(m=1), tanimotoThreshold=101)", "from 1 to 100"),
+    ("TopN(m, tanimotoThreshold=50)", "under a filter"),
+    ("TopN(m, Row(m=2003), tanimotoThreshold=50)", "writer-owned"),
+    ("TopN(m, Intersect(Row(m=1), Row(m=2015)), tanimotoThreshold=50)",
+     "writer-owned"),
+])
+def test_tanimoto_questions_the_reference_refuses(chem, pql, match):
+    _, data = chem
+    ref = reference.build(data, tiny("tiny-tanimoto.json"))
+    with pytest.raises(ValueError, match=match):
+        ref.answer(pql)
+
+
+def test_a_field_ranked_past_its_cache_size_is_refused(chem):
+    cfg, _ = chem
+    small = json.loads(json.dumps(cfg))
+    small["fields"][0]["options"]["cacheSize"] = 2015
+    ref = reference.build(generate.Data(small, 2**31 + 46), {})
+    with pytest.raises(ValueError, match="'m' holds 2016 rows and ranks 2015"):
+        ref.answer("TopN(m, Row(m=1), tanimotoThreshold=50)")
+    del small["fields"][0]["options"]["cacheSize"]
+    assert reference.build(generate.Data(small, 2**31 + 46), {}).cache_size(
+        "m") == reference.DEFAULT_CACHE_SIZE == 50000
